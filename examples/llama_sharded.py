@@ -17,8 +17,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mxnet_tpu.runtime import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import jax.numpy as jnp
 

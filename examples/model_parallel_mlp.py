@@ -26,9 +26,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from mxnet_tpu.runtime import honor_jax_platforms_env
-honor_jax_platforms_env()
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

@@ -16,9 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as onp
 
-from mxnet_tpu.runtime import honor_jax_platforms_env
-honor_jax_platforms_env()
-
 import mxnet_tpu as mx
 from mxnet_tpu import autograd
 

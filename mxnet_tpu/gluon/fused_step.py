@@ -487,6 +487,19 @@ class FusedTrainStep:
         self._train_idx = [trainer._param2idx[p.name]
                            for p in self._train_params]
 
+        if self._mesh is None:
+            # jit keys its executable on which arguments are committed to a
+            # device, and the step's outputs always are. Parameters of a cpu
+            # context are initialised in place and never device_put, so they
+            # are uncommitted and the second call would compile the whole
+            # program again (an accelerator context commits them when it
+            # copies them over). Before the states are made, so that a state
+            # that aliases its weight still shares the array.
+            for p in all_params:
+                nd_arr = p.data(ctx)
+                nd_arr._write(jax.device_put(nd_arr._read(),
+                                             ctx.jax_device))
+
         # optimizer state, created by the optimizer itself (same shapes and
         # dtypes as the imperative Updater would make)
         self._states = [
@@ -696,9 +709,6 @@ class FusedTrainStep:
         fresh_program = prog is None
         pallas_before = None
         if prog is None:
-            _telem.inc("fused_step.compile")
-            _telem.note_compile(
-                "fused_step:%s" % getattr(self._net, "name", "net"))
             prog = self._make_program(in_fmt)
             self._programs[repr(in_fmt)] = prog
             if _telem.ENABLED:
@@ -772,8 +782,18 @@ class FusedTrainStep:
         aot = self._aot_progs.get(repr(in_fmt))
         if aot is not None and aot[1] == sig:
             outs = aot[0](*step_args)
+            builds = int(fresh_program)
         else:
+            # counted from jit's own cache, so the counter says what XLA
+            # built: one program object builds again when an argument's
+            # shape, dtype or committed-ness changes
+            built = jitted._cache_size()
             outs = jitted(*step_args)
+            builds = jitted._cache_size() - built
+        if builds:
+            _telem.inc("fused_step.compile", builds)
+            _telem.note_compile(
+                "fused_step:%s" % getattr(self._net, "name", "net"))
         if getattr(self, "_sentinel", False):
             new_train, new_states, aux_new, loss_mean, fin = outs
             from ..resilience import integrity as _integrity

@@ -219,10 +219,7 @@ class KVStoreDist(KVStoreLocal):
         worker mesh: ONE on-device psum_scatter inside a shard_map, each
         worker keeping only its contiguous (S,) shard of the sum — 1/world
         of the allreduce return traffic (the ZeRO gradient leg)."""
-        try:
-            from jax import shard_map  # jax >= 0.8
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P
         mesh = self._worker_mesh()
@@ -467,10 +464,7 @@ class KVStoreDist(KVStoreLocal):
         `psum_unique_rows` (unique-rows allgather + in-trace dedup),
         replicated result — the sparse analog of `_cross_worker`'s
         allreduce placement."""
-        try:
-            from jax import shard_map  # jax >= 0.8
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ..parallel.collectives import psum_unique_rows
         mesh = self._worker_mesh()
@@ -479,18 +473,12 @@ class KVStoreDist(KVStoreLocal):
                str(vals_p.dtype))
         fn = self._zero_fns.get(key)
         if fn is None:
-            # check_rep off: the dedup's sort/scatter obscures the (true)
+            # check_vma off: the dedup's sort/scatter obscures the (true)
             # replication of the allgathered slabs from the static checker
-            try:
-                sm = shard_map(
-                    lambda i, v: psum_unique_rows(i[0], v[0], "worker"),
-                    mesh=mesh, in_specs=(P("worker"), P("worker")),
-                    out_specs=(P(), P()), check_rep=False)
-            except TypeError:  # pragma: no cover - jax >= 0.8 renamed it
-                sm = shard_map(
-                    lambda i, v: psum_unique_rows(i[0], v[0], "worker"),
-                    mesh=mesh, in_specs=(P("worker"), P("worker")),
-                    out_specs=(P(), P()), check_vma=False)
+            sm = shard_map(
+                lambda i, v: psum_unique_rows(i[0], v[0], "worker"),
+                mesh=mesh, in_specs=(P("worker"), P("worker")),
+                out_specs=(P(), P()), check_vma=False)
             fn = jax.jit(sm)
             self._zero_fns[key] = fn
         dev = mesh.devices.ravel()[dist.rank()]

@@ -1,6 +1,6 @@
 """Pallas kernel-layer shared utilities: dispatch observability — the
 counters/spans that make the kernel layer auditable (ISSUE 10 tentpole
-part 3) — plus the version-tolerance shims every kernel module needs.
+part 3) — plus the Mosaic compiler params every kernel module needs.
 
 Every Pallas kernel call site in the ops layer reports through here:
 
@@ -29,20 +29,11 @@ __all__ = ["note_dispatch", "note_fallback", "kernel_span",
 
 
 def compiler_params(semantics):
-    """Version-tolerant Mosaic params: the class is `CompilerParams` on
-    current jax and `TPUCompilerParams` on the 0.4.3x line (the bare
-    AttributeError killed every interpret-mode kernel test on jaxlib
-    0.4.36); None when neither accepts dimension_semantics. Shared by
-    fused_conv, fused_optimizer, and parallel/flash_attention."""
+    """Mosaic compiler params carrying the grid's `dimension_semantics`.
+    Shared by fused_conv, fused_optimizer, sparse_ops and
+    parallel/flash_attention."""
     from jax.experimental.pallas import tpu as pltpu
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=semantics)
-            except TypeError:
-                return None
-    return None
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def note_dispatch(kernel):

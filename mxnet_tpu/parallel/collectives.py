@@ -16,10 +16,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["all_reduce", "all_gather", "reduce_scatter", "ppermute",

@@ -61,23 +61,8 @@ def _block_attend(q, k, v, mask, sm_scale):
 
 
 def _pvary(t, axis_name):
-    """Mark a constant as device-varying under shard_map. jax >= 0.9
-    renames lax.pvary to lax.pcast(..., to='varying')."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(t, (axis_name,), to="varying")
-    return lax.pvary(t, (axis_name,))
-
-def _axis_size(axis_name):
-    """Static mapped-axis size, version-tolerant: `lax.axis_size` only
-    exists on newer jax; the 0.4.x line exposes it through the axis
-    frame (an int on 0.4.37). The ring permutation schedule needs a
-    python int, so `lax.psum(1, ...)` (traced) is not a substitute."""
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    import jax.core as _core
-    frame = _core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
+    """Mark a constant as device-varying under shard_map."""
+    return lax.pcast(t, (axis_name,), to="varying")
 
 
 def _merge_blocks(o_run, lse_run, o_blk, lse_blk):
@@ -91,7 +76,7 @@ def _merge_blocks(o_run, lse_run, o_blk, lse_blk):
 
 
 def _ring_flash_fwd_impl(q, k, v, axis_name, causal, sm_scale):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, H, Sq, D = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -125,10 +110,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, sm_scale):
 
     o0 = jnp.zeros(q.shape, jnp.float32)
     lse0 = jnp.full((B, H, Sq), _NEG_INF, jnp.float32)
-    try:
-        o0, lse0 = (_pvary(t, axis_name) for t in (o0, lse0))
-    except AttributeError:
-        pass
+    o0, lse0 = (_pvary(t, axis_name) for t in (o0, lse0))
     (o, lse, _, _), _ = lax.scan(step, (o0, lse0, k, v), jnp.arange(n))
     return o.astype(q.dtype), lse
 
@@ -146,7 +128,7 @@ def _ring_flash_vjp_fwd(q, k, v, axis_name, causal, sm_scale):
 
 def _ring_flash_vjp_bwd(axis_name, causal, sm_scale, res, do):
     q, k, v, o, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -188,10 +170,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, sm_scale, res, do):
     dq0 = jnp.zeros(q.shape, jnp.float32)
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
-    try:
-        dq0, dk0, dv0 = (_pvary(t, axis_name) for t in (dq0, dk0, dv0))
-    except AttributeError:
-        pass
+    dq0, dk0, dv0 = (_pvary(t, axis_name) for t in (dq0, dk0, dv0))
     (dq, _, _, dk, dv), _ = lax.scan(
         step, (dq0, k, v, dk0, dv0), jnp.arange(n))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -214,7 +193,7 @@ def ring_attention(q, k, v, axis_name="seq", causal=False, sm_scale=None):
     if _fa_use_pallas(q, k) and q.shape[2] == k.shape[2]:
         return _ring_flash(q, k, v, axis_name, bool(causal),
                            float(sm_scale))
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
@@ -249,10 +228,7 @@ def ring_attention(q, k, v, axis_name="seq", causal=False, sm_scale=None):
     l0 = jnp.zeros((B, Hkv, g, Sq, 1), jnp.float32)
     # constants enter the scan carry device-varying (they become varying
     # through the masked block math) — mark them so under shard_map
-    try:
-        acc0, m0, l0 = (_pvary(t, axis_name) for t in (acc0, m0, l0))
-    except AttributeError:
-        pass
+    acc0, m0, l0 = (_pvary(t, axis_name) for t in (acc0, m0, l0))
     (acc, _, l, _, _), _ = lax.scan(
         step, (acc0, m0, l0, k, v), jnp.arange(n))
     l = jnp.where(l == 0.0, 1.0, l)

@@ -133,27 +133,6 @@ def default_array(source, ctx=None, dtype=None):
 import contextlib
 
 
-def _x64_scope():
-    """The x64 context manager under whichever name this jax ships it:
-    `jax.enable_x64` on newer releases, `jax.experimental.enable_x64`
-    before the promotion (0.4.x). Raises MXNetError with the probe result
-    if neither exists — large-tensor mode is then genuinely unavailable."""
-    import jax
-    cm = getattr(jax, "enable_x64", None)
-    if cm is not None:
-        return cm(True)
-    try:
-        from jax.experimental import enable_x64 as _cm
-    except ImportError:
-        from .base import MXNetError
-        raise MXNetError(
-            "large_tensor_scope: this jax (%s) exposes neither "
-            "jax.enable_x64 nor jax.experimental.enable_x64 — 64-bit "
-            "tensor indexing is unavailable"
-            % getattr(jax, "__version__", "?"))
-    return _cm(True)
-
-
 @contextlib.contextmanager
 def large_tensor_scope():
     """64-bit tensor indexing scope (reference: the
@@ -163,5 +142,6 @@ def large_tensor_scope():
     Kept scoped rather than global because x64 also flips jax's DEFAULT
     dtypes (python floats become float64), which the TPU-native bf16/f32
     path does not want."""
-    with _x64_scope():
+    import jax
+    with jax.enable_x64(True):
         yield

@@ -515,10 +515,7 @@ def test_psum_bucketed_inside_shard_map():
     from mxnet_tpu.parallel import collectives
     from mxnet_tpu.parallel.mesh import local_mesh
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = local_mesh()
     ax = mesh.axis_names[0]
     n = mesh.devices.size

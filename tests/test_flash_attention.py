@@ -123,10 +123,7 @@ def test_grad_under_jit_and_bf16():
                                           (4, 2, True)])
 def test_ring_flash_matches_full_attention(H, Hkv, causal):
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     ra = sys.modules["mxnet_tpu.parallel.ring_attention"]
 
     devs = jax.devices()[:4]
@@ -143,10 +140,7 @@ def test_ring_flash_matches_full_attention(H, Hkv, causal):
                                                 causal=causal)
     kw = dict(mesh=mesh, in_specs=(P(None, None, "seq", None),) * 3,
               out_specs=P(None, None, "seq", None))
-    try:
-        f = shard_map(body, check_vma=False, **kw)
-    except TypeError:   # the 0.4.x line names the flag check_rep
-        f = shard_map(body, check_rep=False, **kw)
+    f = shard_map(body, check_vma=False, **kw)
     o_ring = f(q, k, v)
     o_ref = fa._ref_attention(q, k, v, causal, sc)
     onp.testing.assert_allclose(o_ring, o_ref, atol=5e-4, rtol=1e-4)

@@ -286,3 +286,61 @@ def test_eager_tape_matches_fused_step_end_to_end():
         np.testing.assert_allclose(pe.data().asnumpy(),
                                    pf.data().asnumpy(), rtol=2e-4,
                                    atol=2e-6, err_msg=kn)
+
+
+def test_second_step_reuses_the_compiled_program(caplog):
+    """jit keys its executable on which arguments are committed to a device.
+    Parameters of a cpu context are uncommitted and the step's outputs are
+    committed, so the second call used to compile the whole program again
+    (on the cpu only: an accelerator context commits what it copies over)."""
+    import logging
+
+    import jax
+
+    mx.random.seed(0)
+    net = _mlp(0, bn=True)
+    net.cast("bfloat16")
+    net.hybridize(static_alloc=True)
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.randn(16, 12), dtype="bfloat16")
+    y = nd.array(rng.randint(0, 8, (16,)), dtype="float32")
+    net(x)
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    fused = gluon.FusedTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                 trainer)
+    fused(x, y).wait_to_read()
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING, "jax"):
+        fused(x, y).wait_to_read()
+        fused(x, y).wait_to_read()
+    compiled = [r.getMessage() for r in caplog.records
+                if "Compiling" in r.getMessage()]
+    assert not compiled, compiled
+
+
+def test_compile_counter_counts_what_xla_built():
+    """`fused_step.compile` is read from jit's own cache: steady steps add
+    nothing, and a new batch size builds (and counts) once more under the
+    same program object."""
+    from mxnet_tpu import telemetry
+
+    def compiles():
+        return telemetry.snapshot()["counters"].get("fused_step.compile", 0)
+
+    mx.random.seed(0)
+    net = _mlp(0, bn=True)
+    net.cast("bfloat16")
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.randn(16, 12), dtype="bfloat16")
+    y = nd.array(rng.randint(0, 8, (16,)), dtype="float32")
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    fused = gluon.FusedTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                 trainer)
+    start = compiles()
+    for _ in range(3):
+        fused(x, y).wait_to_read()
+    assert compiles() - start == 1
+    fused(x[:8], y[:8]).wait_to_read()
+    fused(x[:8], y[:8]).wait_to_read()
+    assert compiles() - start == 2

@@ -164,8 +164,6 @@ def test_server_role_never_runs_user_code():
     env = dict(os.environ, DMLC_ROLE="server", JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-c",
-         "from mxnet_tpu.runtime import honor_jax_platforms_env;"
-         "honor_jax_platforms_env();"
          "import mxnet_tpu; print('REACHED_USER_CODE')"],
         env=env, capture_output=True, text=True, timeout=300)
     assert r.returncode == 0

@@ -145,6 +145,33 @@ def test_dataloader_multiworker():
     assert sorted(seen) == list(range(32))
 
 
+class _PlatformProbe:
+    """Each sample is 1.0 where the process that serves it was started with
+    JAX_PLATFORMS=cpu."""
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, idx):
+        return np.float32(os.environ.get("JAX_PLATFORMS") == "cpu")
+
+
+@pytest.mark.parametrize("parent", [None, "tpu,cpu"])
+def test_dataloader_workers_start_cpu_only(monkeypatch, parent):
+    """The parent owns the chip: a spawned worker sees JAX_PLATFORMS=cpu
+    from its first instruction, and the parent's own value is put back."""
+    from mxnet_tpu.gluon.data import DataLoader
+    if parent is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", parent)
+    loader = DataLoader(_PlatformProbe(), batch_size=4, num_workers=1)
+    assert os.environ.get("JAX_PLATFORMS") == parent
+    (batch,) = list(loader)
+    assert batch.asnumpy().tolist() == [1.0] * 4
+    assert os.environ.get("JAX_PLATFORMS") == parent
+
+
 def test_dataset_transform_shard():
     from mxnet_tpu.gluon.data import SimpleDataset
     ds = SimpleDataset(list(range(10)))

@@ -997,10 +997,7 @@ def test_reduce_scatter_multi_rejects_zero_size_arrays():
 
 def test_reduce_scatter_all_gather_multi_roundtrip():
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel import collectives
     from mxnet_tpu.parallel.mesh import local_mesh
     mesh = local_mesh()
@@ -1021,7 +1018,7 @@ def test_reduce_scatter_all_gather_multi_roundtrip():
 
     before = _counters()
     out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(ax), out_specs=P(),
-                            check_rep=False))(*xs)
+                            check_vma=False))(*xs)
     after = _counters()
     for x, o in zip(xs, out):
         np.testing.assert_allclose(np.asarray(o), np.asarray(x).sum(0),

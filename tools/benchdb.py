@@ -35,8 +35,8 @@ __all__ = ["fingerprint", "fingerprint_id", "history_path", "append",
 
 def _dist_version(name):
     """Installed-distribution version without importing the package (an
-    `import jax` here would initialize the backend bench.py so carefully
-    probes around)."""
+    `import jax` here would be the first, in a tool that needs no
+    backend)."""
     try:
         from importlib import metadata
         return metadata.version(name)
@@ -47,9 +47,11 @@ def _dist_version(name):
 def fingerprint(backend=None, device_count=None, cpu_fallback=None):
     """The environment identity a bench row is only comparable within.
 
-    The caller (bench.py) passes what it already knows — the probed
-    backend platform, the device count, whether the accelerator probe
-    fell back to CPU — so this module never has to import jax itself.
+    The caller (bench.py) passes what it already knows — the backend
+    platform and the device count — so this module never has to import
+    jax itself. `cpu_fallback` has no caller since bench.py stopped
+    falling back; the key stays because it is part of every recorded
+    row's fingerprint id, and goes with bench.py (ROADMAP S0).
     """
     return {
         "backend": backend or "unknown",

@@ -9,7 +9,12 @@ processes — `-s` is accepted and ignored with a note (SPMD has no servers).
 
 Launchers:
   local  — all workers as subprocesses of this host (the reference's
-           `--launcher local`, used by tests/nightly dist tests).
+           `--launcher local`, used by tests/nightly dist tests). Every
+           worker gets the same environment, and a chip belongs to one
+           process, so on a host with chips the first worker takes them
+           all: this launcher is for CPU workers. The supported way to use
+           the four chips of one host is one process and a mesh
+           (`gluon.FusedTrainStep(mesh=create_mesh(data=4))`).
   ssh    — one worker per host from --hostfile via ssh (reference ssh.py).
   tpu    — emit the per-host env and command for TPU pods (one process per
            host; the operator's pod runner executes it on each host).
