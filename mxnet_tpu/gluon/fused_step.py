@@ -41,7 +41,6 @@ at the HBM level — the buffer-swap NDArray mutation model at full speed.
 from __future__ import annotations
 
 import math
-import time
 
 import numpy as _np
 
@@ -591,7 +590,11 @@ class FusedTrainStep:
                             p._data, p._base, p._idx = raw, None, None
                         _random.push_trace_key(rng_key)
                         try:
-                            with autograd.pause(train_mode=True):
+                            # inside what is differentiated: the backward
+                            # then reads transpose(jvp(forward)) in every
+                            # op_name, and a kernel keeps its own name
+                            with autograd.pause(train_mode=True), \
+                                    jax.named_scope("forward"):
                                 in_nds = [nd.from_jax(r, ctx=ctx)
                                           for r in data_raws]
                                 args = _regroup(in_nds, holder["in_fmt"])[0]
@@ -625,12 +628,13 @@ class FusedTrainStep:
                     grads = tuple(_engine.reassociate_bucketed(  # tpu-lint: disable=TPU001,TPU003
                         list(grads), self._bucket_mb))
                 new_train, new_states = [], []
-                for j in range(len(train_raws)):
-                    sc = {k: v[j] for k, v in scal.items()}
-                    w, s = dev_fn(opt, train_raws[j], grads[j], state_raws[j],
-                                  sc, rescale)
-                    new_train.append(w.astype(train_raws[j].dtype))
-                    new_states.append(_state_cast_like(s, state_raws[j]))
+                with jax.named_scope("optimizer"):
+                    for j in range(len(train_raws)):
+                        sc = {k: v[j] for k, v in scal.items()}
+                        w, s = dev_fn(opt, train_raws[j], grads[j],
+                                      state_raws[j], sc, rescale)
+                        new_train.append(w.astype(train_raws[j].dtype))
+                        new_states.append(_state_cast_like(s, state_raws[j]))
                 if sentinel:
                     # integrity sentinel (MXNET_TPU_INTEGRITY=1 at build
                     # time): one fused all-finite scalar over the raw
@@ -657,19 +661,8 @@ class FusedTrainStep:
     # ------------------------------------------------------------------
     def __call__(self, data, label):
         """Run one fused step; returns the mean loss as an NDArray."""
-        if not _telem.ENABLED:
+        with _telem.step_span("fused_step"):
             return self._step(data, label)
-        ts = _telem.span_clock()
-        t0 = time.perf_counter()
-        try:
-            return self._step(data, label)
-        finally:
-            dur = time.perf_counter() - t0
-            _telem.observe("fused_step.step_ms", dur * 1e3)
-            _telem.record_span("fused_step", "step", ts, dur)
-            _telem.maybe_sample_memory()
-            # telemetry v2: anomaly detection + crash flight recorder
-            _telem.step_event("fused_step", dur * 1e3)
 
     def _step(self, data, label):
         # injection-only resilience site (hang/preempt/latency testable on
@@ -740,35 +733,38 @@ class FusedTrainStep:
             self._scal_cache = cache
         scal_dev, rescale_dev = cache["dev"], cache["rescale_dev"]
 
-        train_raws = tuple(p._read() for p in self._train_nds)
-        other_raws = tuple(p._read() for p in self._other_nds)
-        state_raws = tuple(_state_raws(s) for s in self._states)
-        if self._donate:
-            # NDArray.copy() shares the immutable buffer (copy-on-write), so
-            # a state that starts as weight.copy() (DCASGD's prev_weight)
-            # aliases a donated weight buffer — XLA rejects donating one
-            # buffer twice. Break the alias with a real device copy.
-            seen = {id(r) for r in train_raws}
+        with _telem.span("fused_step.gather", "phase"):
+            train_raws = tuple(p._read() for p in self._train_nds)
+            other_raws = tuple(p._read() for p in self._other_nds)
+            state_raws = tuple(_state_raws(s) for s in self._states)
+            if self._donate:
+                # NDArray.copy() shares the immutable buffer (copy-on-write),
+                # so a state that starts as weight.copy() (DCASGD's
+                # prev_weight) aliases a donated weight buffer — XLA rejects
+                # donating one buffer twice. Break the alias with a real
+                # device copy.
+                seen = {id(r) for r in train_raws}
 
-            def _break_alias(x):
-                if x is None:
-                    return None
-                if isinstance(x, (tuple, list)):
-                    return tuple(_break_alias(e) for e in x)
-                if id(x) in seen:
-                    return jnp.copy(x)
-                seen.add(id(x))
-                return x
+                def _break_alias(x):
+                    if x is None:
+                        return None
+                    if isinstance(x, (tuple, list)):
+                        return tuple(_break_alias(e) for e in x)
+                    if id(x) in seen:
+                        return jnp.copy(x)
+                    seen.add(id(x))
+                    return x
 
-            state_raws = _break_alias(state_raws)
-        rng_key = _random.take_key(ctx)
-
-        data_raws = tuple(a._read() for a in flat_data)
-        label_raw = label._read()
-        if self._data_sharding is not None:  # stage the batch onto the mesh
-            data_raws = tuple(jax.device_put(r, self._data_sharding)
-                              for r in data_raws)
-            label_raw = jax.device_put(label_raw, self._label_sharding)
+                state_raws = _break_alias(state_raws)
+            rng_key = _random.take_key(ctx)
+            data_raws = tuple(a._read() for a in flat_data)
+            label_raw = label._read()
+        if self._data_sharding is not None:
+            with _telem.span("fused_step.stage", "phase"):
+                # stage the batch onto the mesh
+                data_raws = tuple(jax.device_put(r, self._data_sharding)
+                                  for r in data_raws)
+                label_raw = jax.device_put(label_raw, self._label_sharding)
 
         step_args = (train_raws, other_raws, state_raws,
                      scal_dev, rescale_dev,
@@ -780,45 +776,46 @@ class FusedTrainStep:
             # aux targets, which are process-local and unserializable)
             self._maybe_aot(jitted, step_args, sig, repr(in_fmt))
         aot = self._aot_progs.get(repr(in_fmt))
-        if aot is not None and aot[1] == sig:
-            outs = aot[0](*step_args)
-            builds = int(fresh_program)
-        else:
-            # counted from jit's own cache, so the counter says what XLA
-            # built: one program object builds again when an argument's
-            # shape, dtype or committed-ness changes
-            built = jitted._cache_size()
-            outs = jitted(*step_args)
-            builds = jitted._cache_size() - built
+        with _telem.span("fused_step.launch", "phase"):
+            if aot is not None and aot[1] == sig:
+                outs = aot[0](*step_args)
+                builds = int(fresh_program)
+            else:
+                # counted from jit's own cache, so the counter says what XLA
+                # built: one program object builds again when an argument's
+                # shape, dtype or committed-ness changes
+                built = jitted._cache_size()
+                outs = jitted(*step_args)
+                builds = jitted._cache_size() - built
         if builds:
             _telem.inc("fused_step.compile", builds)
             _telem.note_compile(
                 "fused_step:%s" % getattr(self._net, "name", "net"))
-        if getattr(self, "_sentinel", False):
-            new_train, new_states, aux_new, loss_mean, fin = outs
-            from ..resilience import integrity as _integrity
-            # raises DivergenceError BEFORE any write-back: a tripped
-            # step leaves params, states, and aux exactly as they were
-            _integrity.check_scalar(
-                fin, site="fused_step",
-                keys=[p.name for p in getattr(self, "_train_params", [])
-                      if hasattr(p, "name")])
-        else:
-            new_train, new_states, aux_new, loss_mean = outs
         if pallas_before is not None:
             # unconditionally: a recompile that fuses ZERO kernels (gate
             # turned off, shapes fell back) must not leave a stale count
             _telem.set_gauge(
                 "fused_step.pallas_kernels",
                 _telem.counter("ops.pallas.dispatch").value - pallas_before)
-
-        with autograd.pause():
-            for p_nd, raw in zip(self._train_nds, new_train):
-                p_nd._write(raw)
-            for s, raws in zip(self._states, new_states):
-                _state_write(s, raws)
-            for t, v in zip(holder.get("aux_targets", ()), aux_new):
-                t._write(v)
+        with _telem.span("fused_step.write_back", "phase"):
+            if getattr(self, "_sentinel", False):
+                new_train, new_states, aux_new, loss_mean, fin = outs
+                from ..resilience import integrity as _integrity
+                # raises DivergenceError BEFORE any write-back: a tripped
+                # step leaves params, states, and aux exactly as they were
+                _integrity.check_scalar(
+                    fin, site="fused_step",
+                    keys=[p.name for p in getattr(self, "_train_params", [])
+                          if hasattr(p, "name")])
+            else:
+                new_train, new_states, aux_new, loss_mean = outs
+            with autograd.pause():
+                for p_nd, raw in zip(self._train_nds, new_train):
+                    p_nd._write(raw)
+                for s, raws in zip(self._states, new_states):
+                    _state_write(s, raws)
+                for t, v in zip(holder.get("aux_targets", ()), aux_new):
+                    t._write(v)
         return nd.from_jax(loss_mean, ctx=ctx)
 
     def _maybe_aot(self, jitted, step_args, sig, fmt_key):

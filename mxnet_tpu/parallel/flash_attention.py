@@ -196,6 +196,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=128, block_k=128):
         interpret=_interpret(),
         compiler_params=compiler_params(("parallel", "parallel", "parallel",
                                          "arbitrary")),
+        name="flash_fwd",   # the HLO instruction, and so the device trace
     )
     o, lse = call(q, k, v)
     return o, lse.reshape(B, H, Sq)
@@ -348,6 +349,7 @@ def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
         compiler_params=cparams,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid transposed so the K-block is the parallel dim
@@ -378,6 +380,7 @@ def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
         ],
         interpret=_interpret(),
         compiler_params=cparams,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
 
     if group > 1:

@@ -17,7 +17,6 @@ shards with the parameters (ZeRO: state inherits the param's sharding — the
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -270,9 +269,17 @@ class ShardedTrainStep:
                       else _engine.bucket_bytes(bucket_mb))
 
         def step_fn(params, opt_state, batch, step_num):
+            def forward(params, batch):
+                # inside what is differentiated: the backward then reads
+                # transpose(jvp(forward)) in every op_name, and a kernel
+                # keeps its own name (`%flash_dq.1`, not `%jvp_flash_dq_`)
+                with jax.named_scope("forward"):
+                    return loss_fn(params, batch)
+
+            value_and_grad = jax.value_and_grad(forward)
             if accum > 1:
                 def micro(carry, mb):
-                    l, g = jax.value_and_grad(loss_fn)(params, mb)
+                    l, g = value_and_grad(params, mb)
                     return (carry[0] + l, _tmap(jnp.add, carry[1], g)), None
                 zero = _tmap(jnp.zeros_like, params)
                 mbatch = _tmap(
@@ -283,7 +290,7 @@ class ShardedTrainStep:
                 loss = loss / accum
                 grads = _tmap(lambda g: g / accum, grads)
             else:
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                loss, grads = value_and_grad(params, batch)
             if bucket_cap:
                 # bucket-wise grad regrouping (identity math): the lowered
                 # program carries one fused flat tensor per bucket, so the
@@ -295,8 +302,9 @@ class ShardedTrainStep:
                 leaves = _engine.reassociate_bucketed(leaves, bucket_mb)  # tpu-lint: disable=TPU001,TPU003
                 grads = jax.tree_util.tree_unflatten(tree, leaves)
             cur_lr = lr(step_num) if callable(lr) else lr
-            new_params, new_state = opt_update(
-                params, grads, opt_state, cur_lr, **opt_kwargs)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt_update(
+                    params, grads, opt_state, cur_lr, **opt_kwargs)
             return new_params, new_state, loss
 
         in_shardings = (
@@ -314,22 +322,8 @@ class ShardedTrainStep:
                        donate_argnums=(0, 1) if self.donate else ())
 
     def __call__(self, params, opt_state, batch, step_num=0):
-        if not _telem.ENABLED:
+        with _telem.step_span("train_step"):
             return self._step(params, opt_state, batch, step_num)
-        ts = _telem.span_clock()
-        t0 = time.perf_counter()
-        try:
-            return self._step(params, opt_state, batch, step_num)
-        finally:
-            # host-side dispatch wall time: under async dispatch the steady
-            # state measures enqueue latency; compile steps dominate their
-            # own entry (the first call also increments train_step.compile)
-            dur = time.perf_counter() - t0
-            _telem.observe("train_step.step_ms", dur * 1e3)
-            _telem.record_span("train_step", "step", ts, dur)
-            _telem.maybe_sample_memory()
-            # telemetry v2: anomaly detection + crash flight recorder
-            _telem.step_event("train_step", dur * 1e3)
 
     def _step(self, params, opt_state, batch, step_num):
         from ..resilience import faults as _faults
@@ -345,44 +339,47 @@ class ShardedTrainStep:
             self._sig_seen.add(sig)
             self._sig_last = sig
             if prev is not None:
-                _telem.inc("train_step.compile")  # jit retrace = recompile
                 _telem.inc("train_step.retrace")
-                _telem.note_compile("ShardedTrainStep(retrace)")
                 from ..analysis import guard as _guard
                 if _guard.ACTIVE:
                     from ..gluon.block import _retrace_reason
                     _guard.on_retrace(
                         "ShardedTrainStep", len(self._sig_seen),
                         _retrace_reason((True, sig), (True, prev)))
+        pallas_before = None
         if self._compiled is None:
-            _telem.inc("train_step.compile")
             self._batch_proto = batch
             self._compiled = self._build(params, opt_state)
             self._aot = self._maybe_aot(params, opt_state, batch, step_num,
                                         sig)
             if self._aot is not None:
-                return self._aot(params, opt_state, batch,
-                                 jnp.asarray(step_num, jnp.int32))
-            _telem.note_compile("ShardedTrainStep")
-            if _telem.ENABLED:
+                _telem.inc("train_step.compile")
+            elif _telem.ENABLED:
                 # ISSUE 10 dispatch observability: Pallas call sites count
                 # ops.pallas.dispatch while the first call TRACES this
                 # program — the delta is the number of kernels fused into
                 # the sharded step (mirrors fused_step.pallas_kernels)
-                before = _telem.counter("ops.pallas.dispatch").value
-                out = self._compiled(params, opt_state, batch,
-                                     jnp.asarray(step_num, jnp.int32))
-                # unconditional: a zero-kernel recompile must clear a
-                # stale count from an earlier gated-on program
-                _telem.set_gauge(
-                    "train_step.pallas_kernels",
-                    _telem.counter("ops.pallas.dispatch").value - before)
-                return out
-        if self._aot is not None and sig == self._aot_sig:
-            return self._aot(params, opt_state, batch,
-                             jnp.asarray(step_num, jnp.int32))
-        return self._compiled(params, opt_state, batch,
-                              jnp.asarray(step_num, jnp.int32))
+                pallas_before = _telem.counter("ops.pallas.dispatch").value
+        with _telem.span("train_step.launch", "phase"):
+            step_num = jnp.asarray(step_num, jnp.int32)
+            if self._aot is not None and sig == self._aot_sig:
+                return self._aot(params, opt_state, batch, step_num)
+            # counted from jit's own cache, so the counter says what XLA
+            # built: the program builds again when the batch's signature
+            # changes, and when a parameter's or a state's dtype does
+            built = self._compiled._cache_size()
+            out = self._compiled(params, opt_state, batch, step_num)
+            builds = self._compiled._cache_size() - built
+        if builds:
+            _telem.inc("train_step.compile", builds)
+            _telem.note_compile("ShardedTrainStep")
+        if pallas_before is not None:
+            # unconditional: a zero-kernel recompile must clear a stale
+            # count from an earlier gated-on program
+            _telem.set_gauge(
+                "train_step.pallas_kernels",
+                _telem.counter("ops.pallas.dispatch").value - pallas_before)
+        return out
 
     def _maybe_aot(self, params, opt_state, batch, step_num, sig):
         """Lower the first program and route its COMPILE through the

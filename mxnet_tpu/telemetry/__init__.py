@@ -21,9 +21,24 @@ reports through it). Instrumented hot paths:
 * dataloader — `dataloader.batchify.syncs_saved` (device→host syncs
   avoided by the batched collate);
 * train steps — `trainer.step_ms`, `fused_step.step_ms`,
-  `train_step.step_ms` histograms + compile counters;
+  `train_step.step_ms` histograms + compile counters. The last two time
+  the host's dispatch (the call returns while the device runs on), not a
+  step. One cat-`step` span per call (`fused_step`, `train_step`) and,
+  inside it, cat-`phase` spans: `fused_step.gather` / `.stage` (with a
+  mesh) / `.launch` / `.write_back`, `train_step.launch`; what the parent
+  holds beyond them is its self time;
+* jit — `jit.trace:<fn>` / `jit.lower:<fn>` / `jit.xla:<fn>` spans (cat
+  `jit`) from jax's own duration events, one set per program built;
+  `jit.xla` is a compile or a restore from the persistent cache;
 * memory — best-effort `memory.*.bytes_in_use` watermark gauges from the
   PjRt allocator (memory.py).
+
+One clock with the profiler: `span()` also enters a
+`jax.profiler.TraceAnnotation`, so under any profiler session
+(`capture_profile`, `mx.profiler`, xprof, a benchmark's `--trace 1`) the
+program's spans are host events of the same `.xplane.pb` as the device's
+operations. A span's parent is the innermost span that covers it on the
+same thread. `span_epoch()` puts ring times on `time.perf_counter()`.
 
 Gating: `MXNET_TPU_TELEMETRY=0` (env) or `telemetry.disable()` turns every
 instrumented path into a single global-bool check — no locks, no dict
@@ -83,15 +98,19 @@ import time
 import uuid
 from contextlib import contextmanager
 
+import jax.monitoring
+from jax.profiler import TraceAnnotation
+
 from .metrics import Counter, Gauge, Histogram, Registry
 from .trace import (TraceBuffer, write_chrome_trace,
                     write_merged_chrome_trace)
 from . import memory as _memory
 
 __all__ = ["enabled", "enable", "disable", "registry", "counter", "gauge",
-           "histogram", "inc", "set_gauge", "observe", "span", "record_span",
+           "histogram", "inc", "set_gauge", "observe", "span", "step_span",
+           "record_span",
            "snapshot", "compile_report", "reset", "dumps", "dump",
-           "dump_trace", "span_events",
+           "dump_trace", "span_events", "span_clock", "span_epoch",
            "aggregate_snapshot", "merge_snapshots", "aggregate_trace",
            "sample_memory", "maybe_sample_memory",
            "note_compile", "recent_compiles", "device_report",
@@ -120,6 +139,7 @@ def enable():
     switched on, not never."""
     global ENABLED
     ENABLED = True
+    _listen_to_jit()
     from . import export as _export
     _export.maybe_start_from_env()
 
@@ -176,24 +196,59 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "_t0")
+    """One span on both clocks: a `TraceAnnotation` for its lifetime, so a
+    profiler session that is running sees it among the host's events, and a
+    tuple in the ring when it ends. `dur` is its length in seconds once it
+    has ended."""
+    __slots__ = ("name", "cat", "dur", "_t0", "_annotation")
 
     def __init__(self, name, cat):
         self.name = name
         self.cat = cat
-        self._t0 = None
+        self.dur = None
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = _trace.now()
         return self
 
     def __exit__(self, *exc):
-        _trace.add(self.name, self.cat, self._t0, _trace.now() - self._t0)
+        self.dur = _trace.now() - self._t0
+        self._annotation.__exit__(*exc)
+        _trace.add(self.name, self.cat, self._t0, self.dur)
         return False
 
 
+class _StepSpan(_Span):
+    """The span of one call of a train step, which feeds what reads a
+    step's length when it ends: the `<site>.step_ms` histogram, the memory
+    gauges, and `step_event` (anomaly detection, attribution, the flight
+    recorder)."""
+    __slots__ = ()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        ms = self.dur * 1e3
+        observe(self.name + ".step_ms", ms)
+        maybe_sample_memory()
+        step_event(self.name, ms)
+        return False
+
+
+def step_span(site):
+    """`span(site, "step")` around one call of a train step. Under async
+    dispatch it times the host's dispatch, not the step: the call returns
+    while the device runs on (a call that compiles holds the compile)."""
+    if not ENABLED:
+        return _NULL_SPAN
+    return _StepSpan(site, "step")
+
+
 def span(name, cat="host"):
-    """Context manager recording one chrome-trace span (ph:'X')."""
+    """Context manager recording one span (chrome-trace ph:'X') in the ring
+    and, under a profiler session, in the profiler's trace. A span's parent
+    is the innermost span that covers it on the same thread."""
     if not ENABLED:
         return _NULL_SPAN
     return _Span(name, cat)
@@ -214,6 +269,12 @@ def span_clock():
     return _trace.now()
 
 
+def span_epoch():
+    """The `time.perf_counter()` value of the ring's zero: a span's `ts_s`
+    plus this is on the clock a caller's own `perf_counter` reads are on."""
+    return _trace.epoch
+
+
 def span_events(limit=None):
     """Recorded spans as (name, cat, ts_s, dur_s, tid) tuples, oldest first;
     `limit` keeps only the newest N. The resilience watchdog embeds this
@@ -222,6 +283,38 @@ def span_events(limit=None):
     if limit is not None and len(events) > limit:
         events = events[-limit:]
     return events
+
+
+# ---------------------------------------------------------------- jit's times
+# jax times the three parts of every compile itself and reports each with the
+# function's name; as spans they say what a first call's seconds were, and
+# which program compiled where. `jit.xla` holds a restore from the persistent
+# cache as well as a compile.
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.xla",
+}
+
+
+def _on_jit_duration(event, duration, fun_name=None, **_kw):
+    name = _JIT_SPANS.get(event)
+    if name is None or not ENABLED:
+        return
+    if fun_name:
+        name = "%s:%s" % (name, fun_name)
+    _trace.add(name, "jit", _trace.now() - duration, duration)
+
+
+_jit_listening = False
+
+
+def _listen_to_jit():
+    """Register the listener, once in the life of the process."""
+    global _jit_listening
+    if not _jit_listening:
+        _jit_listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
 
 
 # ---------------------------------------------------------------- identity
@@ -510,3 +603,5 @@ def merge_snapshots(snaps):
 from . import export  # noqa: E402  (needs ENABLED/registry above)
 
 export.maybe_start_from_env()
+if ENABLED:
+    _listen_to_jit()

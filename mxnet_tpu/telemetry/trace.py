@@ -32,11 +32,11 @@ class TraceBuffer:
         # the wall-clock stamp of the SAME instant anchors this rank's spans
         # on the fleet-shared clock (merged multi-rank dumps shift each
         # rank's events by its epoch offset)
-        self._epoch = time.perf_counter()
+        self.epoch = time.perf_counter()
         self.epoch_unix = time.time()
 
     def now(self):
-        return time.perf_counter() - self._epoch
+        return time.perf_counter() - self.epoch
 
     def add(self, name, cat, ts_s, dur_s, tid=None):
         """Append one span. `tid` defaults to the recording thread's ident

@@ -153,3 +153,55 @@ def test_ring_flash_matches_full_attention(H, Hkv, causal):
     for got, want, name in zip(g_ring, g_ref, ["dq", "dk", "dv"]):
         onp.testing.assert_allclose(got, want, atol=5e-3, rtol=1e-3,
                                     err_msg=name)
+
+
+def _pallas_calls(jaxpr, found):
+    """Every `pallas_call` equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, found)
+    return found
+
+
+def test_the_three_kernels_carry_their_names():
+    """The names are what the device trace shows the kernels under
+    (`%flash_fwd.1` in the compiled program), and the benchmark's
+    `flash_*_ms_per_step` read them."""
+    q = _rand((1, 2, 128, 64), 0)
+    grad = jax.grad(lambda q, k, v: fa.flash_attention(q, k, v).sum(),
+                    argnums=(0, 1, 2))
+    calls = _pallas_calls(jax.make_jaxpr(grad)(q, q, q).jaxpr, [])
+    assert [c.params["name"] for c in calls] == [
+        "flash_fwd", "flash_dq", "flash_dkv"]
+
+
+def test_in_a_train_step_a_kernel_keeps_its_own_name():
+    """XLA names an instruction after the last part of its `op_name`. The
+    step's `forward` scope lies inside what is differentiated, so the part
+    that `jvp` and `transpose` wrap is the scope and the kernel's stays
+    plain: `transpose(jvp(forward))/flash_dq`, and `%flash_dq.1` on the
+    chip. With the scope around `value_and_grad`, or with none, it would be
+    `jvp(flash_dq)` and `%jvp_flash_dq_.1`."""
+    from mxnet_tpu import parallel as par
+    q = _rand((2, 2, 128, 64), 0)
+
+    def loss_fn(params, batch):
+        return fa.flash_attention(batch * params["w"], batch, batch).sum()
+
+    step = par.ShardedTrainStep(loss_fn, {"w": jnp.ones(())},
+                                par.local_mesh(1, axis="data"),
+                                optimizer="sgd", lr=0.1, donate=False)
+    params, state = step.init()
+    step(params, state, q, 0)
+    jaxpr = jax.make_jaxpr(step._compiled)(params, state, q, 0).jaxpr
+    stacks = [str(c.source_info.name_stack)
+              for c in _pallas_calls(jaxpr, [])]
+    assert stacks == ["jvp(forward)/flash_fwd",
+                      "transpose(jvp(forward))/flash_dq",
+                      "transpose(jvp(forward))/flash_dkv"]

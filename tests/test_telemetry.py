@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -357,3 +358,157 @@ def test_parse_log_telemetry_mode(tmp_path):
         capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stderr
     assert "cachedop.compile" in r.stdout
+
+
+# ------------------------------------------- spans on the profiler's clock
+PHASES = ("fused_step.gather", "fused_step.launch", "fused_step.write_back")
+
+
+def _toy_fused_step():
+    net = gluon.nn.Dense(2)
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    fused = gluon.FusedTrainStep(net, gluon.loss.L2Loss(), trainer)
+    x = nd.array(np.random.rand(4, 3).astype(np.float32))
+    y = nd.array(np.random.rand(4, 2).astype(np.float32))
+    return fused, x, y
+
+
+def _covered(parent, events):
+    """The (name, start, end) of `events` that lie inside `parent`."""
+    return [e for e in events if e is not parent
+            and parent[1] <= e[1] and e[2] <= parent[2]]
+
+
+def test_fused_step_phases_reach_the_profilers_trace(tmp_path):
+    import glob
+
+    import jax
+    fused, x, y = _toy_fused_step()
+    fused(x, y).asnumpy()           # compile outside the session
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for i in range(3):
+            with jax.profiler.StepTraceAnnotation("step", step_num=i):
+                fused(x, y)
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    # the thread's line is found by what it holds: it is named after the
+    # interpreter as it was started
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events] for line in host.lines]
+    (events,) = [evs for evs in lines
+                 if any(name == "fused_step" for name, _, _ in evs)]
+    steps = [e for e in events if e[0] == "fused_step"]
+    assert len(steps) == 3
+    for step in steps:
+        inside = [name for name, _, _ in _covered(step, events)]
+        assert [inside.count(p) for p in PHASES] == [1, 1, 1]
+        # and the program's span inside the caller's own annotation
+        assert sum(e[0] == "step" and e[1] <= step[1] and step[2] <= e[2]
+                   for e in events) == 1
+
+
+def test_fused_step_phases_in_the_ring():
+    from mxnet_tpu.telemetry import attribution
+    fused, x, y = _toy_fused_step()
+    for _ in range(3):
+        fused(x, y)
+    events = [(n, ts, ts + dur, cat, tid)
+              for n, cat, ts, dur, tid in telemetry.span_events()]
+    steps = [e for e in events if e[0] == "fused_step"]
+    assert len(steps) == 3 and all(e[3] == "step" for e in steps)
+    for step in steps:
+        inside = [e for e in _covered(step, events) if e[0] in PHASES]
+        assert sorted(e[0] for e in inside) == sorted(PHASES)
+        assert all(e[3] == "phase" and e[4] == step[4] for e in inside)
+        assert sum(e[2] - e[1] for e in inside) <= step[2] - step[1]
+    # a phase is neither communication nor host overhead: the window's
+    # attribution reads as it did without them
+    ring = telemetry.span_events()
+    _, _, ts, dur, _ = [e for e in ring if e[0] == "fused_step"][-1]
+    without = [e for e in ring if e[1] != "phase"]
+    assert (attribution.attribute_window(ring, ts, ts + dur)
+            == attribution.attribute_window(without, ts, ts + dur))
+    assert telemetry.span_epoch() == pytest.approx(
+        time.perf_counter() - telemetry.span_clock(), abs=0.05)
+
+
+def test_disabled_records_no_span_annotation_or_jit_time(monkeypatch):
+    import jax
+    made = []
+
+    class Counted(jax.profiler.TraceAnnotation):
+        def __init__(self, *args, **kwargs):
+            made.append(args)
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Counted)
+    with telemetry.span("seen.when.on"):
+        pass
+    assert made == [("seen.when.on",)]
+    telemetry.reset()
+    telemetry.disable()
+    fused, x, y = _toy_fused_step()
+    fused(x, y).asnumpy()           # traces, lowers and compiles
+    jax.jit(lambda a: a * 3)(np.ones(5, np.float32))
+    assert len(made) == 1
+    assert telemetry.span_events() == []
+    assert telemetry.snapshot() == {"counters": {}, "gauges": {},
+                                    "histograms": {}}
+
+
+def test_jit_times_are_spans_of_the_first_call_only():
+    import jax
+
+    def tripled_once(a):
+        return a * 3 + 1
+
+    jitted = jax.jit(tripled_once)
+    arg = np.ones(7, np.float32)
+    jitted(arg).block_until_ready()
+    first = [e for e in telemetry.span_events() if e[0].startswith("jit.")]
+    # jax names the function `tripled_once` when it traces it and
+    # `jit(tripled_once)` from then on
+    for part in ("jit.trace:", "jit.lower:", "jit.xla:"):
+        (span,) = [e for e in first
+                   if e[0].startswith(part) and "tripled_once" in e[0]]
+        assert span[1] == "jit" and span[3] > 0
+        assert span[2] + span[3] <= telemetry.span_clock()
+    jitted(arg).block_until_ready()
+    again = [e for e in telemetry.span_events() if e[0].startswith("jit.")]
+    assert again == first
+
+
+def test_sharded_train_step_counts_what_xla_built():
+    import jax.numpy as jnp
+
+    from mxnet_tpu import parallel as par
+    mesh = par.local_mesh(2, axis="data")
+    params = {"w": jnp.zeros((4,), jnp.bfloat16)}
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return jnp.mean((x @ params["w"][:, None] - y) ** 2)
+
+    step = par.ShardedTrainStep(loss_fn, params, mesh, optimizer="sgd",
+                                lr=0.05, donate=False)
+    p, s = step.init()
+    batch = (jnp.ones((8, 4)), jnp.ones((8, 1)))
+    counters = lambda: telemetry.snapshot()["counters"]  # noqa: E731
+    p, s, _ = step(p, s, batch, 0)
+    step(p, s, batch, 1)
+    assert counters()["train_step.compile"] == 1
+    # the same batch, parameters of another dtype: jit builds again, and the
+    # batch's signature, which the retrace guard keys on, has not moved
+    p32 = {"w": p["w"].astype(jnp.float32)}
+    step(p32, s, batch, 2)
+    assert counters()["train_step.compile"] == 2
+    assert "train_step.retrace" not in counters()
+    spans = [e for e in telemetry.span_events()
+             if e[0] in ("train_step", "train_step.launch")]
+    assert [e[0] for e in spans] == ["train_step.launch", "train_step"] * 3
+    assert {e[1] for e in spans} == {"phase", "step"}
