@@ -15,7 +15,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..parallel.flash_attention import flash_attention
+from ..parallel.flash_attention import flash_attention_bshd
 from .llama import _dense_init
 
 __all__ = ["BertConfig", "bert_init", "bert_forward", "bert_mlm_loss",
@@ -104,9 +104,8 @@ def _encoder_layer(lp, x, cfg):
     q = (x @ a["wq"] + a["bq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = (x @ a["wk"] + a["bk"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
     v = (x @ a["wv"] + a["bv"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
-    o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                        v.transpose(0, 2, 1, 3), causal=False)
-    o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
+    # the kernels take the projections' own layout: no transpose in or out
+    o = flash_attention_bshd(q, k, v, causal=False).reshape(B, S, -1)
     x = layer_norm(x + (o @ a["wo"] + a["bo"]), lp["attn_norm"], cfg.norm_eps)
     f = lp["ffn"]
     h = jax.nn.gelu(x @ f["w1"] + f["b1"], approximate=True)

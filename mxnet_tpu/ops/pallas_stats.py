@@ -28,12 +28,14 @@ __all__ = ["note_dispatch", "note_fallback", "kernel_span",
            "compiler_params"]
 
 
-def compiler_params(semantics):
-    """Mosaic compiler params carrying the grid's `dimension_semantics`.
-    Shared by fused_conv, fused_optimizer, sparse_ops and
+def compiler_params(semantics, vmem_limit_bytes=None):
+    """Mosaic compiler params carrying the grid's `dimension_semantics`
+    and, where a kernel's tiles outgrow Mosaic's default, the VMEM it may
+    use. Shared by fused_conv, fused_optimizer, sparse_ops and
     parallel/flash_attention."""
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 def note_dispatch(kernel):

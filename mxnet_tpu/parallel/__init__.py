@@ -12,7 +12,8 @@ activations, and XLA-inserted collectives riding ICI (intra-slice) / DCN
 * sharding.py     — Megatron/FSDP-style per-parameter PartitionSpec rules
 * collectives.py  — psum/all_gather/ppermute/reduce_scatter wrappers + comm bench
 * dist.py         — multi-controller init (jax.distributed) with DMLC_* env compat
-* flash_attention.py — fused attention kernel (Pallas on TPU, lax fallback)
+* flash_attention.py — fused attention kernels (Pallas on TPU, lax fallback),
+                    on (B, H, S, D) or on a projection's own (B, S, H, D)
 * ring_attention.py  — sequence-parallel ring attention over a mesh axis
 * train_step.py   — compile a whole train step (fwd+bwd+opt) under shardings
 """
@@ -24,7 +25,7 @@ from .sharding import (ShardingRules, LLAMA_RULES, BERT_RULES,
 from .collectives import (all_reduce, all_gather, reduce_scatter, ppermute,
                           barrier, allreduce_bench)
 from .dist import initialize, is_initialized, rank, num_workers
-from .flash_attention import flash_attention
+from .flash_attention import flash_attention, flash_attention_bshd
 from .ring_attention import ring_attention
 from .train_step import ShardedTrainStep
 from .checkpoint import (save_sharded, restore_sharded, latest_step,
@@ -36,5 +37,6 @@ __all__ = [
     "named_sharding", "shard_pytree", "replicate_pytree", "logical_to_spec",
     "all_reduce", "all_gather", "reduce_scatter", "ppermute", "barrier",
     "allreduce_bench", "initialize", "is_initialized", "rank", "num_workers",
-    "flash_attention", "ring_attention", "ShardedTrainStep",
+    "flash_attention", "flash_attention_bshd", "ring_attention",
+    "ShardedTrainStep",
 ]

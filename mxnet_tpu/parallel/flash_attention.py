@@ -10,17 +10,49 @@ rematerializing backward that recomputes each S-tile IN the kernel from the
 saved logsumexp — dq/dk/dv each see O(S) HBM bytes instead of the S^2
 probability matrix the reference's backward streams.
 
-Layout: grid (batch, head, outer-block, inner-block) with the inner
-dimension sequential ("arbitrary") so accumulators live in VMEM scratch
-across the sweep. Non-128-multiple sequence lengths are handled by in-kernel
-bounds masks; causal uses the (Sk - Sq) diagonal offset convention so
-Sq != Sk cross-attention decodes correctly. The per-row statistics
-(logsumexp, delta) cross the kernel boundary as (B, H, 1, Sq) in blocks of
-(1, 1, 1, block_q): Mosaic wants a block's last two dimensions to be
-multiples of (8, 128) or the array's own, which a (1, block_q) block of an
-(H, Sq) array is not.
+Layout: one algorithm, three kernels (`flash_fwd`, `flash_dq`,
+`flash_dkv`), two views of the operands.
 
-Shapes: q (B, H, Sq, D); k/v (B, Hkv, Sk, D) with H % Hkv == 0 (GQA/MQA).
+* `flash_attention_bshd` takes q, k, v as a projection leaves them,
+  (B, S, H, D), and the kernels index the free (B, S, H*D) view: a block is
+  `block_b` batch rows x a sequence tile x a 128-lane GROUP of heads (two
+  heads at D = 64, one at D >= 128). A head's scores come from operands
+  masked to its own lanes, which costs the MXU what the half-empty
+  contraction over D = 64 costs anyway and keeps every load and store
+  lane-dense. No transpose goes in or comes out, so autodiff has none to
+  transpose back.
+* `flash_attention` keeps the (B, H, S, D) arguments; its view is
+  (B*H, S, D), a block `block_b` of those rows with D on the lanes. Ring
+  attention drives the same kernels per ring block through
+  `_pallas_forward` / `_pallas_backward_inner`.
+
+The tile (`_choose_tile`, one pure function of what a call can see: the
+view, B, H, Hkv, Sq, Sk, D and the item size) takes the whole sequence up to
+512 rows and several batch rows at short sequences, so a grid step moves of
+the order of a megabyte; the grid is (row blocks, head groups, outer
+blocks, inner blocks) with the inner dimension sequential ("arbitrary"), and
+the online-softmax accumulators live in VMEM scratch only where the inner
+sweep has more than one block. Non-128-multiple sequence lengths are handled
+by in-kernel bounds masks; causal uses the (Sk - Sq) diagonal offset
+convention so Sq != Sk cross-attention decodes correctly. The per-row
+statistics (logsumexp, delta) cross the kernel boundary with the sequence on
+the lanes, (rows, heads, 1, Sq) in blocks of (block_b, heads, 1, block_q):
+Mosaic wants a block's last two dimensions to be multiples of (8, 128) or
+the array's own. dk/dv are computed transposed (scores as (k, q)), so the
+statistics broadcast along sublanes and no product transposes an operand.
+
+Products take their operands in the type they arrive in, with float32 out
+of the MXU; jax's matmul precision rides into the kernels on `dot_general`
+as into any other product of the program (float32 operands: one bfloat16
+pass under the default, as Mosaic and XLA both do it; full float32 under
+"highest"). The softmax statistics, `lse`, `delta` and the accumulators are
+float32 always.
+
+Shapes: q (B, H, Sq, D); k/v (B, Hkv, Sk, D) with H % Hkv == 0 (GQA/MQA);
+the `_bshd` entry has S and H swapped. A (B, S, H, D) shape whose heads do
+not fill lane groups (128 % D and D % 128 both non-zero, an odd head count
+at D = 64, GQA at D < 128) is transposed to the other view, and counted
+(`ops.pallas.fallback.flash.<reason>`).
 
 Set MXNET_FLASH_INTERPRET=1 to run the Pallas kernels in interpreter mode
 on CPU (the test suite uses this to pin kernel correctness without a chip).
@@ -29,6 +61,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +69,18 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "paged_attention", "paged_attention_chunk"]
+from ..ops.pallas_stats import compiler_params, note_dispatch, note_fallback
+
+__all__ = ["flash_attention", "flash_attention_bshd", "paged_attention",
+           "paged_attention_chunk"]
 
 _NEG_INF = -1e30
+_LANES = 128
+_MAX_BLOCK = 512        # sequence rows of q or of k in a block, at most
+_MAX_ROWS = 8           # batch rows in a block, at most
+_STEP_SCORES = 1 << 18  # score elements a grid step computes, at most
+_VMEM_LIMIT = 48 << 20  # what the kernels ask Mosaic for; every tile's
+#                         estimate stays under it (v5e has 128 MiB a core)
 
 
 def _interpret():
@@ -67,12 +109,77 @@ def _ref_attention(q, k, v, causal, sm_scale):
     return out.reshape(B, H, Sq, D)
 
 
-def _bounds_mask(s, q_start, k_start, block_q, block_k, seq_q, seq_k,
-                 causal):
-    """Mask logits for causal structure and for rows/cols past the true
-    sequence ends (non-divisible block grids read garbage there)."""
-    qi = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_start
-    ki = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + k_start
+# ------------------------------------------------------------------ the tile
+class _Tile(NamedTuple):
+    """What one grid step of the three kernels works on."""
+    lanes: int      # minor size of a block: `heads` heads side by side
+    heads: int
+    block_b: int    # rows a block: batch rows, or (batch, head) rows
+    block_q: int
+    block_k: int
+    steps: int      # grid steps a call
+    vmem: int       # bytes of VMEM a step is estimated to need
+
+
+def _seq_block(S):
+    """Rows of a sequence a block takes: all of a short one; else the
+    multiple of 128 up to `_MAX_BLOCK` (and to S) that covers S in the fewest
+    rows, a step priced at 64 rows of its own."""
+    if S <= _LANES:
+        return S
+    sizes = range(_LANES, min(S, _MAX_BLOCK) + 1, _LANES)
+    return min(sizes, key=lambda b: (-(-S // b) * (b + 64), -b))
+
+
+def _choose_tile(view, B, H, Hkv, Sq, Sk, D, itemsize):
+    """The tile for one call, from what the call can see; or the reason (a
+    word, for the fallback counter) why this view cannot take the shape.
+
+    `view` "bshd": operands (B, S, H*D), heads in 128-lane groups;
+    "bhsd": operands (B*H, S, D)."""
+    if D % 8 or H % Hkv:
+        return "head_dim" if D % 8 else "gqa_group"
+    if view == "bhsd":
+        lanes, heads, rows = D, 1, B * H
+        # a block's rows share one k/v row only where every q head has its
+        # own
+        most_rows = _MAX_ROWS if H == Hkv else 1
+    else:
+        if D % _LANES == 0:
+            lanes, heads = D, 1
+        elif _LANES % D:
+            return "head_dim"
+        else:
+            lanes, heads = _LANES, _LANES // D
+            if H % heads:
+                return "head_group"
+            if H != Hkv:
+                # a q head and its k/v head sit on different lanes
+                return "gqa_lane_group"
+        rows, most_rows = B, _MAX_ROWS
+    bq, bk = _seq_block(Sq), _seq_block(Sk)
+    most_rows = min(most_rows, max(1, _STEP_SCORES // (heads * bq * bk)))
+    bb = max(b for b in range(1, most_rows + 1) if rows % b == 0)
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    f32 = 4
+    blocks = 2 * itemsize * bb * lanes * 3 * (bq + bk)   # double-buffered
+    stats = 2 * 2 * bb * heads * 8 * bq * f32
+    scratch = 0 if nq == nk == 1 else (
+        2 * bb * max(bq, bk) * lanes * f32
+        + 2 * bb * heads * bq * _LANES * f32)
+    live = heads * 6 * bq * bk * f32    # s, p, dp, ds and two operand copies
+    return _Tile(lanes, heads, bb, bq, bk,
+                 (rows // bb) * (H // heads if view == "bshd" else 1)
+                 * nq * nk, blocks + stats + scratch + live)
+
+
+# --------------------------------------------------------------- in a kernel
+def _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal, q_axis=0):
+    """Mask logits for causal structure and for keys past the true
+    sequence end (non-divisible block grids read garbage there). `q_axis`
+    is the dimension of `s` the queries run along."""
+    qi = lax.broadcasted_iota(jnp.int32, s.shape, q_axis) + q_start
+    ki = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) + k_start
     valid = ki < seq_k
     if causal:
         valid = valid & (ki <= qi + (seq_k - seq_q))
@@ -87,6 +194,262 @@ def _zero_pad_rows(x, start, seq):
     return jnp.where(rows < seq, x, 0.0)
 
 
+def _own_lanes(x, h, tile):
+    """`x` with every lane zeroed but head `h`'s of the block's group."""
+    if tile.heads == 1:
+        return x
+    d = tile.lanes // tile.heads
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * d) & (lane < (h + 1) * d), x, 0.0)
+
+
+def _by_head(values, rows, tile):
+    """(rows, lanes): on head h's lanes, `values[h]` (a (rows, lanes) array
+    or a (rows, 1) column)."""
+    if tile.heads == 1:
+        return values[0]
+    d = tile.lanes // tile.heads
+    lane = lax.broadcasted_iota(jnp.int32, (rows, tile.lanes), 1)
+    out = values[-1]
+    for h in range(tile.heads - 2, -1, -1):
+        out = jnp.where(lane < (h + 1) * d, values[h], out)
+    return out
+
+
+def _dot(a, b, transpose_b=False):
+    """a @ b, or a @ b.T: float32 out of the MXU. float32 operands follow
+    jax's matmul precision; narrower ones have one pass to give (and Mosaic
+    refuses them a float32 contraction)."""
+    return lax.dot_general(
+        a, b, (((1,), (1 if transpose_b else 0,)), ((), ())),
+        precision=None if a.dtype == jnp.float32 else lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale):
+    """`x * scale` in x's type, rounded once."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _each_row(n, body):
+    """`body(r)` for the n rows of a block: traced once and unrolled where
+    it is lowered, so that one row's products run under another's softmax
+    (a rolled loop read 11% slower on the v5e at 8 rows of 128 x 128; n
+    copies made in Python cost the step 3 s of tracing). A step's scores
+    are bounded by `_STEP_SCORES` whatever n is."""
+    if n == 1:
+        body(0)
+    else:
+        lax.fori_loop(0, n, lambda r, carry: body(r), None, unroll=True)
+
+
+def _runs(causal, q_start, k_start, block_q, seq_q, seq_k):
+    """False for a block strictly above the (offset) causal diagonal."""
+    return True if not causal else (
+        k_start <= q_start + (seq_k - seq_q) + block_q - 1)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
+                sm_scale, causal, seq_q, seq_k):
+    """One (row block, head group, q-block, k-block) grid step. The grid's
+    last dim is the sequential K sweep; with more than one block in it the
+    accumulators live in VMEM scratch across it."""
+    bq, bk = tile.block_q, tile.block_k
+    single = seq_k <= bk
+    j = pl.program_id(3)
+    q_start, k_start = pl.program_id(2) * bq, j * bk
+    masked = causal or seq_k % bk != 0
+    if not single:
+        acc, m_sc, l_sc = scratch
+
+    def write(r, o, m, l):
+        l = [jnp.where(x == 0.0, 1.0, x) for x in l]
+        o_ref[r] = (o * _by_head([1.0 / x for x in l], bq, tile)
+                    ).astype(o_ref.dtype)
+        for h in range(tile.heads):
+            # logsumexp per row, consumed by the backward's in-kernel
+            # recompute: a column here, a lane-dense row out there (any row
+            # of the transpose of the column spread over 128 lanes)
+            lse = jnp.broadcast_to(m[h] + jnp.log(l[h]), (bq, _LANES))
+            lse_ref[r, h] = lse.T[:1]
+
+    def step(r):
+        q, k, v = q_ref[r], k_ref[r], v_ref[r]
+        if seq_k % bk:
+            v = _zero_pad_rows(v, k_start, seq_k)
+        pv, m, l, alpha = [], [], [], []
+        for h in range(tile.heads):
+            s = _dot(_scaled(_own_lanes(q, h, tile), sm_scale), k,
+                     transpose_b=True)
+            if masked:
+                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal)
+            m_new = jnp.max(s, axis=1, keepdims=True)
+            if not single:
+                m_prev = m_sc[r, h][:, :1]
+                m_new = jnp.maximum(m_prev, m_new)
+            p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=1, keepdims=True)
+            if not single:
+                alpha.append(jnp.exp(m_prev - m_new))
+                l_new = alpha[h] * l_sc[r, h][:, :1] + l_new
+                m_sc[r, h] = jnp.broadcast_to(m_new, (bq, _LANES))
+                l_sc[r, h] = jnp.broadcast_to(l_new, (bq, _LANES))
+            pv.append(_dot(p.astype(v.dtype), v))
+            m.append(m_new)
+            l.append(l_new)
+        if single:
+            write(r, _by_head(pv, bq, tile), m, l)
+        else:
+            acc[r] = (acc[r] * _by_head(alpha, bq, tile)
+                      + _by_head(pv, bq, tile))
+
+    if single:
+        _each_row(tile.block_b, step)
+        return
+
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+
+    # causal: skip blocks strictly above the (offset) diagonal
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    def _step():
+        _each_row(tile.block_b, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _out():
+        _each_row(tile.block_b, lambda r: write(
+            r, acc[r], [m_sc[r, h][:, :1] for h in range(tile.heads)],
+            [l_sc[r, h][:, :1] for h in range(tile.heads)]))
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
+               *scratch, tile, sm_scale, causal, seq_q, seq_k):
+    """dq = sum_j dS_ij K_j — grid (row block, head group, q-block,
+    k-block), K sweep sequential, dq accumulated in VMEM where the sweep has
+    more than one block."""
+    bq, bk = tile.block_q, tile.block_k
+    single = seq_k <= bk
+    j = pl.program_id(3)
+    q_start, k_start = pl.program_id(2) * bq, j * bk
+    masked = causal or seq_k % bk != 0
+    if not single:
+        dq_acc, = scratch
+
+    def step(r):
+        q, do, k, v = q_ref[r], do_ref[r], k_ref[r], v_ref[r]
+        if seq_k % bk:
+            k = _zero_pad_rows(k, k_start, seq_k)
+            v = _zero_pad_rows(v, k_start, seq_k)
+        # the scale rides on k: into the scores, and into dq = dS (scale K)
+        k = _scaled(k, sm_scale)
+        dq = []
+        for h in range(tile.heads):
+            lse = lse_ref[r, h, 0][:, None]
+            delta = dl_ref[r, h, 0][:, None]
+            s = _dot(_own_lanes(q, h, tile), k, transpose_b=True)
+            if masked:
+                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal)
+            p = jnp.exp(s - lse)
+            dp = _dot(_own_lanes(do, h, tile), v, transpose_b=True)
+            dq.append(_dot((p * (dp - delta)).astype(k.dtype), k))
+        dq = _by_head(dq, bq, tile)
+        if single:
+            dq_ref[r] = dq.astype(dq_ref.dtype)
+        else:
+            dq_acc[r] += dq
+
+    if single:
+        _each_row(tile.block_b, step)
+        return
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    def _step():
+        _each_row(tile.block_b, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _out():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                dk_ref, dv_ref, *scratch, tile, sm_scale, causal, seq_q,
+                seq_k):
+    """dk/dv for one K-block — grid (row block, head group, k-block,
+    q-block), Q sweep sequential. Scores are computed transposed, (k, q):
+    the row statistics then broadcast along sublanes as they arrive, and
+    dv = P^T dO, dk = dS^T Q are plain products. Emits per-ATTENTION-head
+    dk/dv; the GQA group-sum happens in XLA after the call (one reshape+sum,
+    no S^2 traffic)."""
+    bq, bk = tile.block_q, tile.block_k
+    single = seq_q <= bq
+    i = pl.program_id(3)
+    q_start, k_start = i * bq, pl.program_id(2) * bk
+    masked = causal or seq_k % bk != 0
+    ragged_q = seq_q % bq != 0
+    if not single:
+        dk_acc, dv_acc = scratch
+
+    def step(r):
+        q, do, k, v = q_ref[r], do_ref[r], k_ref[r], v_ref[r]
+        if ragged_q:
+            q = _zero_pad_rows(q, q_start, seq_q)
+            do = _zero_pad_rows(do, q_start, seq_q)
+            qcol = lax.broadcasted_iota(jnp.int32, (1, bq), 1) + q_start
+        # the scale rides on q: into the scores, and into dk = dS^T (scale Q)
+        q = _scaled(q, sm_scale)
+        dk, dv = [], []
+        for h in range(tile.heads):
+            lse, delta = lse_ref[r, h], dl_ref[r, h]        # (1, block_q)
+            if ragged_q:
+                lse = jnp.where(qcol < seq_q, lse, 0.0)
+                delta = jnp.where(qcol < seq_q, delta, 0.0)
+            s = _dot(_own_lanes(k, h, tile), q, transpose_b=True)
+            if masked:
+                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal,
+                                 q_axis=1)
+            p = jnp.exp(s - lse)
+            if ragged_q:
+                # queries past seq_q carry no probability mass (lse
+                # sanitized above would otherwise make exp(0-0)=1 columns)
+                p = jnp.where(qcol < seq_q, p, 0.0)
+            dv.append(_dot(p.astype(do.dtype), do))
+            dp = _dot(_own_lanes(v, h, tile), do, transpose_b=True)
+            dk.append(_dot((p * (dp - delta)).astype(q.dtype), q))
+        dk, dv = _by_head(dk, bk, tile), _by_head(dv, bk, tile)
+        if single:
+            dk_ref[r] = dk.astype(dk_ref.dtype)
+            dv_ref[r] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[r] += dk
+            dv_acc[r] += dv
+
+    if single:
+        _each_row(tile.block_b, step)
+        return
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    def _step():
+        _each_row(tile.block_b, step)
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _out():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# ------------------------------------------------------------- the three calls
 def _out_struct(shape, dtype, *args):
     """ShapeDtypeStruct carrying the union of the inputs' varying-mesh-axes
     (vma): required when the kernels run inside shard_map (the ring path)
@@ -95,315 +458,185 @@ def _out_struct(shape, dtype, *args):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
-                sm_scale, causal, block_q, block_k, seq_q, seq_k):
-    """One (batch, head, q-block, k-block) grid step. Grid's last dim is the
-    sequential K sweep; accumulators live in VMEM scratch across it."""
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-
-    q_start = i * block_q
-    k_start = j * block_k
-    # causal: skip blocks strictly above the (offset) diagonal
-    run = True if not causal else (
-        k_start <= q_start + (seq_k - seq_q) + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _bounds_mask(s, q_start, k_start, block_q, block_k,
-                         seq_q, seq_k, causal)
-        m_prev = m_sc[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        v = _zero_pad_rows(v_ref[0, 0].astype(jnp.float32), k_start,
-                           seq_k)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc[...] = acc[...] * alpha + pv
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    @pl.when(j == nk - 1)
-    def _out():
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / l_safe).astype(o_ref.dtype)
-        # logsumexp per row, consumed by the backward's in-kernel recompute.
-        # The scratch holds each row's value in all 128 lanes, so any row
-        # of its transpose is the lane-dense (1, block_q) block.
-        lse = m_sc[...] + jnp.log(jnp.where(l_sc[...] == 0.0, 1.0,
-                                            l_sc[...]))
-        lse_ref[0, 0] = lse.T[:1]
-
-
-def _pallas_forward(q, k, v, causal, sm_scale, block_q=128, block_k=128):
-    B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    nq = pl.cdiv(Sq, block_q)
-    nk = pl.cdiv(Sk, block_k)
+def _geometry(view, q, k, H, Hkv):
+    """(tile, grid rows and groups, index maps of a q-side block, a k-side
+    block and a block of row statistics, the statistics' shape) of one call
+    on the view's 3-D operands. The maps take (row block, head group,
+    q-block, k-block)."""
+    rows, Sq, width = q.shape
+    Sk = k.shape[1]
+    B, D = (rows, width // H) if view == "bshd" else (rows // H, width)
+    tile = _choose_tile(view, B, H, Hkv, Sq, Sk, D, q.dtype.itemsize)
     group = H // Hkv
+    if view == "bshd":
+        groups = H // tile.heads
+        stats = (B, H, 1, Sq)
 
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=Sq, seq_k=Sk)
+        def q_map(b, g, i, j):
+            return (b, i, g)
 
-    from ..ops.pallas_stats import compiler_params
+        def kv_map(b, g, i, j):     # group > 1 only with one head a block
+            return (b, j, g // group)
 
-    call = pl.pallas_call(
-        kernel,
-        grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, block_q),
-                         lambda b, h, i, j: (b, h, 0, i)),
-        ],
-        out_shape=[
-            _out_struct(q.shape, q.dtype, q, k, v),
-            _out_struct((B, H, 1, Sq), jnp.float32, q, k, v),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=_interpret(),
-        compiler_params=compiler_params(("parallel", "parallel", "parallel",
-                                         "arbitrary")),
+        def row_map(b, g, i, j):
+            return (b, g, 0, i)
+    else:
+        groups = 1
+        stats = (rows, 1, 1, Sq)
+
+        def q_map(n, g, i, j):
+            return (n, i, 0)
+
+        def kv_map(n, g, i, j):     # group > 1 only with one row a block
+            return (n if group == 1
+                    else (n // H) * Hkv + (n % H) // group, j, 0)
+
+        def row_map(n, g, i, j):
+            return (n, 0, 0, i)
+    return tile, (rows // tile.block_b, groups), q_map, kv_map, row_map, stats
+
+
+def _scratch(blocks, *shapes):
+    """float32 VMEM accumulators for a sweep of `blocks` blocks: none where
+    there is one block, whose result is written as it is computed."""
+    return [pltpu.VMEM(shape, jnp.float32)
+            for shape in shapes] if blocks > 1 else []
+
+
+def _params(tile):
+    return compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
+                           vmem_limit_bytes=max(_VMEM_LIMIT, tile.vmem))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8))
+def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret):
+    """o like q, and the logsumexp in the statistics' shape, of the view's
+    3-D operands. Jitted so that a model's layers share one trace and one
+    lowering of the kernel."""
+    tile, outer, q_map, kv_map, row_map, stats = _geometry(view, q, k, H,
+                                                           Hkv)
+    Sq, Sk = q.shape[1], k.shape[1]
+    bb, bq, bk, lanes = tile.block_b, tile.block_q, tile.block_k, tile.lanes
+    nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Sk, bk)
+    q_spec = pl.BlockSpec((bb, bq, lanes), q_map)
+    kv_spec = pl.BlockSpec((bb, bk, lanes), kv_map)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, tile=tile, sm_scale=sm_scale,
+                          causal=causal, seq_q=Sq, seq_k=Sk),
+        grid=outer + (nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, pl.BlockSpec((bb, tile.heads, 1, bq), row_map)],
+        out_shape=[_out_struct(q.shape, q.dtype, q, k, v),
+                   _out_struct(stats, jnp.float32, q, k, v)],
+        scratch_shapes=_scratch(nk, (bb, bq, lanes),
+                                (bb, tile.heads, bq, _LANES),
+                                (bb, tile.heads, bq, _LANES)),
+        interpret=interpret,
+        compiler_params=_params(tile),
         name="flash_fwd",   # the HLO instruction, and so the device trace
-    )
-    o, lse = call(q, k, v)
-    return o, lse.reshape(B, H, Sq)
+    )(q, k, v)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-               dq_acc, *, sm_scale, causal, block_q, block_k,
-               seq_q, seq_k):
-    """dq = sum_j dS_ij K_j — grid (B, H, q-block, k-block), K sweep
-    sequential, dq accumulated in VMEM."""
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    q_start = i * block_q
-    k_start = j * block_k
-    run = True if not causal else (
-        k_start <= q_start + (seq_k - seq_q) + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = _zero_pad_rows(k_ref[0, 0].astype(jnp.float32), k_start, seq_k)
-        v = _zero_pad_rows(v_ref[0, 0].astype(jnp.float32), k_start, seq_k)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0]
-        delta = dl_ref[0, 0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _bounds_mask(s, q_start, k_start, block_q, block_k,
-                         seq_q, seq_k, causal)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(j == nk - 1)
-    def _out():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                block_q, block_k, seq_q, seq_k):
-    """dk/dv for one K-block — grid (B, H, k-block, q-block), Q sweep
-    sequential. Emits per-ATTENTION-head dk/dv; the GQA group-sum happens
-    in XLA after the call (one reshape+sum, no S^2 traffic)."""
-    j = pl.program_id(2)
-    i = pl.program_id(3)
-    nq = pl.num_programs(3)
-
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    q_start = i * block_q
-    k_start = j * block_k
-    run = True if not causal else (
-        k_start <= q_start + (seq_k - seq_q) + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        q = _zero_pad_rows(q_ref[0, 0].astype(jnp.float32), q_start, seq_q)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = _zero_pad_rows(v_ref[0, 0].astype(jnp.float32), k_start, seq_k)
-        do = _zero_pad_rows(do_ref[0, 0].astype(jnp.float32), q_start,
-                            seq_q)
-        qrow = lax.broadcasted_iota(jnp.int32, (1, block_q), 1) + q_start
-        lse = jnp.where(qrow < seq_q, lse_ref[0, 0], 0.0)[0]
-        delta = jnp.where(qrow < seq_q, dl_ref[0, 0], 0.0)[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _bounds_mask(s, q_start, k_start, block_q, block_k,
-                         seq_q, seq_k, causal)
-        p = jnp.exp(s - lse[:, None])
-        # rows past seq_q carry no probability mass (lse sanitized above
-        # would otherwise make exp(0-0)=1 rows)
-        qi = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_start
-        p = jnp.where(qi < seq_q, p, 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(i == nq - 1)
-    def _out():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _pallas_backward(q, k, v, o, lse, do, causal, sm_scale,
-                     block_q=128, block_k=128):
-    # delta_i = rowsum(dO_i * O_i): one fused elementwise+reduce in XLA
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    return _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
-                                  block_q=block_q, block_k=block_k)
-
-
-def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
-                           block_q=128, block_k=128):
-    """dq/dk/dv kernels from precomputed (lse, delta). Split out so ring
-    attention can run per-block backwards against the GLOBAL logsumexp."""
-    B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    nq = pl.cdiv(Sq, block_q)
-    nk = pl.cdiv(Sk, block_k)
-    group = H // Hkv
-
-    from ..ops.pallas_stats import compiler_params
-    cparams = compiler_params(("parallel", "parallel", "parallel",
-                               "arbitrary"))
-    lse = lse.reshape(B, H, 1, Sq)
-    delta = delta.reshape(B, H, 1, Sq)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, D),
-                           lambda b, h, i, j, g=group: (b, h // g, j, 0))
-    row_spec = pl.BlockSpec((1, 1, 1, block_q),
-                            lambda b, h, i, j: (b, h, 0, i))
+@functools.partial(jax.jit, static_argnums=(0, 7, 8, 9, 10, 11))
+def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
+              interpret):
+    """dq like q, and dk, dv PER ATTENTION HEAD (like q along the heads,
+    like k along the sequence), from the row statistics (lse, delta) in the
+    statistics' shape. Jitted as `_forward` is."""
+    tile, outer, q_map, kv_map, row_map, _ = _geometry(view, q, k, H, Hkv)
+    Sq, Sk = q.shape[1], k.shape[1]
+    bb, bq, bk, lanes = tile.block_b, tile.block_q, tile.block_k, tile.lanes
+    nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Sk, bk)
+    kernel = dict(tile=tile, sm_scale=sm_scale, causal=causal, seq_q=Sq,
+                  seq_k=Sk)
+    common = dict(interpret=interpret, compiler_params=_params(tile))
+    args = (q, k, v, do, lse, delta)
+    q_spec = pl.BlockSpec((bb, bq, lanes), q_map)
+    kv_spec = pl.BlockSpec((bb, bk, lanes), kv_map)
+    row_spec = pl.BlockSpec((bb, tile.heads, 1, bq), row_map)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          seq_q=Sq, seq_k=Sk),
-        grid=(B, H, nq, nk),
+        functools.partial(_dq_kernel, **kernel),
+        grid=outer + (nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=_out_struct(q.shape, q.dtype, q, k, v, do,
-                              lse, delta),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=_interpret(),
-        compiler_params=cparams,
-        name="flash_dq",
-    )(q, k, v, do, lse, delta)
+        out_specs=q_spec,
+        out_shape=_out_struct(q.shape, q.dtype, *args),
+        scratch_shapes=_scratch(nk, (bb, bq, lanes)),
+        name="flash_dq", **common)(*args)
 
     # dk/dv: grid transposed so the K-block is the parallel dim
-    q_spec_t = pl.BlockSpec((1, 1, block_q, D),
-                            lambda b, h, j, i: (b, h, i, 0))
-    kv_spec_t = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, j, i, g=group: (b, h // g, j, 0))
-    row_spec_t = pl.BlockSpec((1, 1, 1, block_q),
-                              lambda b, h, j, i: (b, h, 0, i))
-    out_kv_t = pl.BlockSpec((1, 1, block_k, D),
-                            lambda b, h, j, i: (b, h, j, 0))
+    def swapped(index_map):
+        return lambda b, g, j, i: index_map(b, g, i, j)
 
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          seq_q=Sq, seq_k=Sk),
-        grid=(B, H, nk, nq),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                  row_spec_t],
-        out_specs=[out_kv_t, out_kv_t],
-        out_shape=[
-            _out_struct((B, H, Sk, D), k.dtype, q, k, v, do, lse, delta),
-            _out_struct((B, H, Sk, D), v.dtype, q, k, v, do, lse, delta),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=_interpret(),
-        compiler_params=cparams,
-        name="flash_dkv",
-    )(q, k, v, do, lse, delta)
+    q_spec = pl.BlockSpec((bb, bq, lanes), swapped(q_map))
+    kv_spec = pl.BlockSpec((bb, bk, lanes), swapped(kv_map))
+    row_spec = pl.BlockSpec((bb, tile.heads, 1, bq), swapped(row_map))
+    # per attention head: a block of dk lies where q's block would, along k
+    dkv_spec = pl.BlockSpec((bb, bk, lanes),
+                            lambda b, g, j, i: q_map(b, g, j, i))
+    dkv_shape = (q.shape[0], Sk, q.shape[2])
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kernel),
+        grid=outer + (nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[dkv_spec, dkv_spec],
+        out_shape=[_out_struct(dkv_shape, k.dtype, *args),
+                   _out_struct(dkv_shape, v.dtype, *args)],
+        scratch_shapes=_scratch(nq, (bb, bk, lanes), (bb, bk, lanes)),
+        name="flash_dkv", **common)(*args)
+    return dq, dk, dv
 
+
+def _pallas_on():
+    if os.environ.get("MXNET_FLASH_DISABLE", "0") == "1":
+        return False            # force the plain-XLA path (A/B probes)
+    return _interpret() or jax.default_backend() == "tpu"
+
+
+# ------------------------------------------------- the (B, H, S, D) arguments
+def _pallas_forward(q, k, v, causal, sm_scale):
+    B, H, Sq, D = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
+    o, lse = _forward("bhsd", q.reshape(B * H, Sq, D),
+                      k.reshape(B * Hkv, Sk, D), v.reshape(B * Hkv, Sk, D),
+                      H, Hkv, causal, sm_scale, _interpret())
+    return o.reshape(q.shape), lse.reshape(B, H, Sq)
+
+
+def _pallas_backward(q, k, v, o, lse, do, causal, sm_scale):
+    # delta_i = rowsum(dO_i * O_i): one fused elementwise+reduce in XLA
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    return _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale)
+
+
+def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale):
+    """dq/dk/dv kernels from precomputed (lse, delta), each (B, H, Sq).
+    Split out so ring attention can run per-block backwards against the
+    GLOBAL logsumexp."""
+    B, H, Sq, D = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
+    dq, dk, dv = _backward(
+        "bhsd", q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
+        v.reshape(B * Hkv, Sk, D), lse.reshape(B * H, 1, 1, Sq),
+        delta.reshape(B * H, 1, 1, Sq), do.reshape(B * H, Sq, D), H, Hkv,
+        causal, sm_scale, _interpret())
+    group = H // Hkv
+    dk = dk.reshape(B, Hkv, group, Sk, D)
+    dv = dv.reshape(B, Hkv, group, Sk, D)
     if group > 1:
-        dk = dk_h.reshape(B, Hkv, group, Sk, D).sum(axis=2)
-        dv = dv_h.reshape(B, Hkv, group, Sk, D).sum(axis=2)
-    else:
-        dk, dv = dk_h, dv_h
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+        dk, dv = dk.sum(axis=2), dv.sum(axis=2)
+    return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype))
 
 
 def _use_pallas(q, k):
-    # lane-friendly head dim; seq lengths are masked in-kernel so any
-    # Sq/Sk works. GQA requires an integer group (a non-divisible head
-    # count would make the kv BlockSpec silently clamp to a wrong head).
-    if os.environ.get("MXNET_FLASH_DISABLE", "0") == "1":
-        return False            # force the plain-XLA path (A/B probes)
-    D = q.shape[3]
-    shapes_ok = D % 8 == 0 and q.shape[1] % k.shape[1] == 0
-    if _interpret():
-        return shapes_ok
-    if jax.default_backend() != "tpu":
-        return False
-    return shapes_ok
+    # any head dim a sublane tile divides; seq lengths are masked in-kernel
+    # so any Sq/Sk works. GQA requires an integer group (a non-divisible
+    # head count would make the kv BlockSpec silently clamp to a wrong
+    # head).
+    B, H, Sq, D = q.shape
+    return _pallas_on() and isinstance(_choose_tile(
+        "bhsd", B, H, k.shape[1], Sq, k.shape[2], D, q.dtype.itemsize), _Tile)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -446,6 +679,67 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     return _flash(q, k, v, bool(causal), float(sm_scale))
+
+
+# ------------------------------------------------- the (B, S, H, D) arguments
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bshd(q, k, v, H, Hkv, causal, sm_scale):
+    """On the (B, S, H*D) view, always through the kernels."""
+    return _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
+                    _interpret())[0]
+
+
+def _flash_bshd_fwd(q, k, v, H, Hkv, causal, sm_scale):
+    o, lse = _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
+                      _interpret())
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bshd_bwd(H, Hkv, causal, sm_scale, res, do):
+    q, k, v, o, lse = res
+    B, Sq, _ = q.shape
+    # delta_i = rowsum(dO_i * O_i) per head, laid out like lse: one fused
+    # elementwise+reduce in XLA and the transpose of a (B, Sq, H) array
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(B, Sq, H, -1), axis=-1)
+    delta = delta.transpose(0, 2, 1)[:, :, None, :]
+    dq, dk, dv = _backward("bshd", q, k, v, lse, delta, do, H, Hkv, causal,
+                           sm_scale, _interpret())
+    if H != Hkv:
+        Sk = k.shape[1]
+        dk = dk.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
+        dv = dv.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
+    return (dq, dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype))
+
+
+_flash_bshd.defvjp(_flash_bshd_fwd, _flash_bshd_bwd)
+
+
+def flash_attention_bshd(q, k, v, causal=False, sm_scale=None):
+    """Fused scaled-dot-product attention on the layout a projection
+    leaves: q (B, Sq, H, D); k, v (B, Sk, Hkv, D), H divisible by Hkv.
+    Returns (B, Sq, H, D) in q's dtype, with no transpose on the way in or
+    out where the heads fill 128-lane groups; a shape that does not is
+    transposed to `flash_attention`'s layout and counted."""
+    B, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    if sm_scale is None:
+        sm_scale = D ** -0.5
+    causal, sm_scale = bool(causal), float(sm_scale)
+    if _pallas_on():
+        tile = _choose_tile("bshd", B, H, Hkv, Sq, Sk, D, q.dtype.itemsize)
+        if isinstance(tile, _Tile):
+            note_dispatch("flash_bshd")
+            o = _flash_bshd(q.reshape(B, Sq, H * D),
+                            k.reshape(B, Sk, Hkv * D),
+                            v.reshape(B, Sk, Hkv * D), H, Hkv, causal,
+                            sm_scale)
+            return o.reshape(q.shape)
+        note_fallback("flash", tile)
+    o = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+               v.transpose(0, 2, 1, 3), causal, sm_scale)
+    return o.transpose(0, 2, 1, 3)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, sm_scale=None):

@@ -41,8 +41,20 @@ def _rand(shape, seed):
                        .astype("float32"))
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
 CASES = [
-    # B, H, Hkv, Sq, Sk, D, causal
+    # B, H, Hkv, Sq, Sk, D, causal: the (B, H, S, D) entry
     (2, 4, 4, 128, 128, 64, False),
     (2, 4, 4, 128, 128, 64, True),
     (1, 8, 2, 256, 256, 64, True),     # GQA
@@ -50,39 +62,207 @@ CASES = [
     (1, 2, 2, 160, 160, 64, True),
     (1, 2, 2, 96, 224, 64, True),      # Sq != Sk causal (decode window)
 ]
+BSHD_CASES = [
+    # B, H, Hkv, Sq, Sk, D, causal, dtype, precision: the (B, S, H, D) entry
+    (4, 4, 4, 128, 128, 64, False, "float32", "highest"),   # 4 rows a step
+    (4, 2, 2, 128, 128, 64, True, "float32", "highest"),
+    (2, 2, 2, 200, 200, 64, False, "float32", "highest"),   # ragged
+    (2, 2, 2, 200, 200, 64, True, "float32", "highest"),
+    (1, 2, 2, 512, 512, 64, False, "float32", "highest"),   # whole-seq tile
+    (1, 2, 2, 640, 640, 64, True, "float32", "highest"),    # 384-row blocks
+    (2, 4, 2, 128, 128, 128, True, "float32", "highest"),   # GQA, D on lanes
+    (3, 2, 1, 200, 200, 128, False, "float32", "highest"),
+    (1, 1, 1, 512, 512, 128, True, "float32", "highest"),
+    (2, 2, 2, 96, 224, 64, True, "float32", "highest"),     # decode window
+    (2, 4, 4, 128, 128, 32, False, "float32", "highest"),   # 4 heads a group
+    (2, 2, 2, 128, 128, 64, False, "bfloat16", "default"),
+    (2, 2, 2, 200, 200, 128, True, "bfloat16", "default"),
+    (2, 2, 2, 128, 128, 64, False, "float32", "bfloat16"),  # one-pass
+    (1, 2, 2, 512, 512, 64, True, "float32", "bfloat16"),
+    (1, 8, 2, 256, 256, 64, True, "float32", "highest"),    # falls back
+]
+ALL_CASES = ([("bhsd",) + c + ("float32", "highest") for c in CASES]
+             + [("bshd",) + c for c in BSHD_CASES])
+ARGS = "layout,B,H,Hkv,Sq,Sk,D,causal,dtype,precision"
 
 
-@pytest.mark.parametrize("B,H,Hkv,Sq,Sk,D,causal", CASES)
-def test_forward_matches_reference(B, H, Hkv, Sq, Sk, D, causal):
-    q = _rand((B, H, Sq, D), 0)
-    k = _rand((B, Hkv, Sk, D), 1)
-    v = _rand((B, Hkv, Sk, D), 2)
-    sc = D ** -0.5
-    out = fa._flash(q, k, v, causal, sc)
-    ref = fa._ref_attention(q, k, v, causal, sc)
-    onp.testing.assert_allclose(out, ref, atol=2e-4, rtol=1e-4)
+def _entry(layout, causal, sc):
+    """(the entry under test, the plain reference) on the layout's
+    arguments."""
+    if layout == "bhsd":
+        return (lambda q, k, v: fa._flash(q, k, v, causal, sc),
+                lambda q, k, v: fa._ref_attention(q, k, v, causal, sc))
+
+    def swap(x):
+        return x.transpose(0, 2, 1, 3)
+    return (lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal, sc),
+            lambda q, k, v: swap(fa._ref_attention(swap(q), swap(k), swap(v),
+                                                   causal, sc)))
 
 
-@pytest.mark.parametrize("B,H,Hkv,Sq,Sk,D,causal", CASES)
-def test_backward_matches_reference(B, H, Hkv, Sq, Sk, D, causal):
-    q = _rand((B, H, Sq, D), 3)
-    k = _rand((B, Hkv, Sk, D), 4)
-    v = _rand((B, Hkv, Sk, D), 5)
-    sc = D ** -0.5
+def _operands(layout, B, H, Hkv, Sq, Sk, D, dtype, seed):
+    shapes = [(B, H, Sq, D), (B, Hkv, Sk, D), (B, Hkv, Sk, D), (B, H, Sq, D)]
+    if layout == "bshd":
+        shapes = [(b, s, h, d) for b, h, s, d in shapes]
+    return [_rand(shape, seed + i).astype(dtype)
+            for i, shape in enumerate(shapes)]
+
+
+def _tolerance(dtype, precision, backward):
+    """float32 operands hold the CPU-set tolerances; operands rounded to
+    bfloat16 (the inputs, or the products' under "bfloat16") are held to
+    bfloat16's eight bits."""
+    if dtype == "bfloat16" or precision == "bfloat16":
+        return dict(atol=1e-1 if backward else 3e-2, rtol=5e-2)
+    return (dict(atol=5e-3, rtol=1e-3) if backward
+            else dict(atol=2e-4, rtol=1e-4))
+
+
+@pytest.mark.parametrize(ARGS, ALL_CASES)
+def test_forward_matches_reference(layout, B, H, Hkv, Sq, Sk, D, causal,
+                                   dtype, precision):
+    q, k, v, _ = _operands(layout, B, H, Hkv, Sq, Sk, D, dtype, 0)
+    run, ref = _entry(layout, causal, D ** -0.5)
+    with jax.default_matmul_precision(precision):
+        out = run(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = ref(*(x.astype(jnp.float32) for x in (q, k, v)))
+    assert out.dtype == q.dtype and out.shape == q.shape
+    onp.testing.assert_allclose(out.astype(jnp.float32), want,
+                                **_tolerance(dtype, precision, False))
+
+
+@pytest.mark.parametrize(ARGS, ALL_CASES)
+def test_backward_matches_reference(layout, B, H, Hkv, Sq, Sk, D, causal,
+                                    dtype, precision):
     # weighted sum so cotangents vary per position
-    w = _rand((B, H, Sq, D), 6)
+    q, k, v, w = _operands(layout, B, H, Hkv, Sq, Sk, D, dtype, 3)
+    run, ref = _entry(layout, causal, D ** -0.5)
 
-    def loss_pl(q_, k_, v_):
-        return jnp.sum(fa._flash(q_, k_, v_, causal, sc) * w)
+    def loss(f):
+        return lambda q_, k_, v_: jnp.sum(
+            f(q_, k_, v_).astype(jnp.float32) * w.astype(jnp.float32))
 
-    def loss_ref(q_, k_, v_):
-        return jnp.sum(fa._ref_attention(q_, k_, v_, causal, sc) * w)
-
-    g_pl = jax.grad(loss_pl, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision(precision):
+        g_pl = jax.grad(loss(run), argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
     for got, want, name in zip(g_pl, g_ref, ["dq", "dk", "dv"]):
-        onp.testing.assert_allclose(got, want, atol=5e-3, rtol=1e-3,
-                                    err_msg=name)
+        assert got.dtype == q.dtype, name
+        onp.testing.assert_allclose(got.astype(jnp.float32), want,
+                                    err_msg=name,
+                                    **_tolerance(dtype, precision, True))
+
+
+def test_matmul_precision_rides_into_the_kernels():
+    """The kernels' products follow jax's matmul precision like the
+    program's other products: float32 operands under "highest" reach Mosaic
+    as a float32 contraction, under the default as its one-pass default;
+    bfloat16 operands ask for the default always (Mosaic refuses them a
+    float32 contraction)."""
+    def precisions(dtype, precision):
+        q = _rand((1, 128, 2, 64), 0).astype(dtype)
+        with jax.default_matmul_precision(precision):
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda x: fa.flash_attention_bshd(x, x, x).astype(
+                    jnp.float32).sum()))(q).jaxpr
+        found = {str(e.params["precision"]) for e in _eqns(jaxpr)
+                 if e.primitive.name == "dot_general"}
+        assert sum(e.primitive.name == "pallas_call"
+                   for e in _eqns(jaxpr)) == 3
+        return found
+
+    highest = str((jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST))
+    default = str((jax.lax.Precision.DEFAULT, jax.lax.Precision.DEFAULT))
+    assert precisions("float32", "highest") == {highest}
+    assert precisions("float32", "default") <= {"None", default}
+    assert precisions("bfloat16", "highest") == {default}
+
+
+def _counters():
+    from mxnet_tpu import telemetry
+    return dict(telemetry.snapshot()["counters"])
+
+
+def test_a_shape_the_head_group_cannot_take_falls_back_counted():
+    """Counted once a trace: the new tile under
+    `ops.pallas.dispatch.flash_bshd`, a shape sent to the (B, H, S, D)
+    kernels under `ops.pallas.fallback.flash.<reason>`."""
+    def moved(before, name):
+        return _counters().get(name, 0) - before.get(name, 0)
+
+    for shape, reason in [
+            ((1, 128, 8, 2, 64), "gqa_lane_group"),  # B, S, H, Hkv, D
+            ((1, 128, 3, 3, 64), "head_group"),
+            ((1, 128, 2, 2, 96), "head_dim")]:
+        B, S, H, Hkv, D = shape
+        q, k = _rand((B, S, H, D), 0), _rand((B, S, Hkv, D), 1)
+        before = _counters()
+        out = fa.flash_attention_bshd(q, k, k)
+        assert moved(before, "ops.pallas.fallback.flash." + reason) == 1
+        assert moved(before, "ops.pallas.dispatch.flash_bshd") == 0
+        want = fa._ref_attention(q.transpose(0, 2, 1, 3),
+                                 k.transpose(0, 2, 1, 3),
+                                 k.transpose(0, 2, 1, 3), False, D ** -0.5)
+        onp.testing.assert_allclose(out.transpose(0, 2, 1, 3), want,
+                                    atol=2e-4, rtol=1e-4)
+    q = _rand((2, 128, 4, 64), 0)
+    before = _counters()
+    jax.jit(jax.grad(lambda x: fa.flash_attention_bshd(x, x, x).sum()))(q)
+    assert moved(before, "ops.pallas.dispatch.flash_bshd") == 1
+    assert moved(before, "ops.pallas.fallback") == 0
+
+
+# name: (view, B, H, Hkv, Sq, Sk, D, itemsize) ->
+#       (lanes, heads, block_b, block_q, block_k, grid steps a call)
+TILES = {
+    "bert_base_s128": (("bshd", 128, 12, 12, 128, 128, 64, 4),
+                       (128, 2, 8, 128, 128, 96)),
+    "bert_base_s512": (("bshd", 32, 12, 12, 512, 512, 64, 4),
+                       (128, 2, 1, 512, 512, 192)),
+    "bert_large_s512": (("bshd", 8, 16, 16, 512, 512, 64, 2),
+                        (128, 2, 1, 512, 512, 64)),
+    "llama_gqa_d128": (("bshd", 4, 32, 8, 2048, 2048, 128, 2),
+                       (128, 1, 1, 512, 512, 2048)),
+    "ragged_s200": (("bshd", 6, 4, 4, 200, 200, 64, 4),
+                    (128, 2, 6, 128, 128, 8)),
+    "short_s64_d128": (("bshd", 16, 2, 2, 64, 64, 128, 4),
+                       (128, 1, 8, 64, 64, 4)),
+    "ring_block_bhsd": (("bhsd", 1, 2, 2, 128, 128, 64, 4),
+                        (64, 1, 2, 128, 128, 1)),
+    "gqa_bhsd": (("bhsd", 1, 8, 2, 256, 256, 64, 4),
+                 (64, 1, 1, 256, 256, 8)),
+    "gqa_d64_bshd": (("bshd", 1, 8, 2, 256, 256, 64, 4), "gqa_lane_group"),
+    "odd_heads_d64": (("bshd", 1, 3, 3, 128, 128, 64, 4), "head_group"),
+    "d96": (("bshd", 1, 2, 2, 128, 128, 96, 4), "head_dim"),
+    "d12": (("bhsd", 1, 2, 2, 128, 128, 12, 4), "head_dim"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_the_tile_follows_the_shape(name):
+    """The chooser alone: one pure function of what a call can see. The
+    old grid was (B, H, Sq/128, Sk/128): 1,536 steps a call for
+    `bert_base_s128`, 6,144 for `bert_base_s512`."""
+    args, want = TILES[name]
+    tile = fa._choose_tile(*args)
+    if isinstance(want, str):
+        assert tile == want
+        return
+    assert tile[:6] == want
+    view, B, H, Hkv, Sq, Sk, D, itemsize = args
+    rows = B if view == "bshd" else B * H
+    groups = H // tile.heads if view == "bshd" else 1
+    assert tile.steps == (rows // tile.block_b) * groups * (
+        -(-Sq // tile.block_q)) * (-(-Sk // tile.block_k))
+    assert tile.lanes == tile.heads * D and rows % tile.block_b == 0
+    # a step's blocks and temporaries stay inside what the kernels ask
+    # Mosaic for, and a step computes at most `_STEP_SCORES` scores
+    assert 0 < tile.vmem <= fa._VMEM_LIMIT
+    assert (tile.block_b * tile.heads * tile.block_q * tile.block_k
+            <= max(fa._STEP_SCORES, tile.heads * tile.block_q * tile.block_k))
+    assert max(tile.block_q, tile.block_k) <= fa._MAX_BLOCK
 
 
 def test_lse_is_logsumexp():
@@ -155,29 +335,39 @@ def test_ring_flash_matches_full_attention(H, Hkv, causal):
                                     err_msg=name)
 
 
-def _pallas_calls(jaxpr, found):
-    """Every `pallas_call` equation of a jaxpr and of the jaxprs inside it."""
+def _pallas_calls(jaxpr, found, outer=""):
+    """(equation, its whole name stack) of every `pallas_call` of a jaxpr
+    and of the jaxprs inside it. The kernels sit inside the jitted
+    `_forward` / `_backward`, whose equation carries the caller's part of
+    the stack."""
     for eqn in jaxpr.eqns:
+        stack = "/".join(filter(None, [outer,
+                                       str(eqn.source_info.name_stack)]))
         if eqn.primitive.name == "pallas_call":
-            found.append(eqn)
+            found.append((eqn, stack))
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple))
                         else [value]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _pallas_calls(sub, found)
+                    inner = stack if eqn.primitive.name in (
+                        "pjit", "jit") else outer
+                    _pallas_calls(sub, found, inner)
     return found
 
 
-def test_the_three_kernels_carry_their_names():
+@pytest.mark.parametrize("entry", ["flash_attention",
+                                   "flash_attention_bshd"])
+def test_the_three_kernels_carry_their_names(entry):
     """The names are what the device trace shows the kernels under
     (`%flash_fwd.1` in the compiled program), and the benchmark's
     `flash_*_ms_per_step` read them."""
-    q = _rand((1, 2, 128, 64), 0)
-    grad = jax.grad(lambda q, k, v: fa.flash_attention(q, k, v).sum(),
+    q = _rand((1, 2, 128, 64) if entry == "flash_attention"
+              else (1, 128, 2, 64), 0)
+    grad = jax.grad(lambda q, k, v: getattr(fa, entry)(q, k, v).sum(),
                     argnums=(0, 1, 2))
     calls = _pallas_calls(jax.make_jaxpr(grad)(q, q, q).jaxpr, [])
-    assert [c.params["name"] for c in calls] == [
+    assert [c.params["name"] for c, _ in calls] == [
         "flash_fwd", "flash_dq", "flash_dkv"]
 
 
@@ -189,10 +379,11 @@ def test_in_a_train_step_a_kernel_keeps_its_own_name():
     chip. With the scope around `value_and_grad`, or with none, it would be
     `jvp(flash_dq)` and `%jvp_flash_dq_.1`."""
     from mxnet_tpu import parallel as par
-    q = _rand((2, 2, 128, 64), 0)
+    q = _rand((2, 128, 2, 64), 0)
 
     def loss_fn(params, batch):
-        return fa.flash_attention(batch * params["w"], batch, batch).sum()
+        return fa.flash_attention_bshd(batch * params["w"], batch,
+                                       batch).sum()
 
     step = par.ShardedTrainStep(loss_fn, {"w": jnp.ones(())},
                                 par.local_mesh(1, axis="data"),
@@ -200,8 +391,31 @@ def test_in_a_train_step_a_kernel_keeps_its_own_name():
     params, state = step.init()
     step(params, state, q, 0)
     jaxpr = jax.make_jaxpr(step._compiled)(params, state, q, 0).jaxpr
-    stacks = [str(c.source_info.name_stack)
-              for c in _pallas_calls(jaxpr, [])]
+    stacks = [stack for _, stack in _pallas_calls(jaxpr, [])]
     assert stacks == ["jvp(forward)/flash_fwd",
                       "transpose(jvp(forward))/flash_dq",
                       "transpose(jvp(forward))/flash_dkv"]
+
+
+@pytest.mark.parametrize("heads,dim", [(4, 256), (2, 256)])
+def test_the_encoder_layer_transposes_no_rank_4_array(heads, dim):
+    """`_encoder_layer` hands the kernels its projections as they are, so
+    neither it nor its gradient holds a transpose of a (B, S, H, D) or
+    (B, H, S, D) array: the eight a layer that `all_copy` was made of."""
+    from mxnet_tpu.models import bert
+    cfg = bert.BertConfig(vocab_size=64, dim=dim, n_layers=1, n_heads=heads,
+                          hidden_dim=512, max_seq_len=128,
+                          dtype=jnp.float32)
+    lp = bert.bert_init(jax.random.PRNGKey(0), cfg)["layers"]["0"]
+    x = _rand((2, 128, dim), 0)
+
+    def transposed_ranks(fn):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(lp, x).jaxpr))
+        assert sum(e.primitive.name == "pallas_call" for e in eqns) in (1, 3)
+        return [len(e.invars[0].aval.shape) for e in eqns
+                if e.primitive.name == "transpose"]
+
+    forward = transposed_ranks(lambda lp, x: bert._encoder_layer(lp, x, cfg))
+    both = transposed_ranks(jax.grad(
+        lambda lp, x: bert._encoder_layer(lp, x, cfg).sum(), argnums=(0, 1)))
+    assert all(rank < 4 for rank in forward + both), (forward, both)
