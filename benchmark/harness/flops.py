@@ -3,9 +3,18 @@
 What the algorithm needs, not what the compiler emitted: recomputation is not
 counted, a training step is three forward passes' worth of products (forward,
 gradient to the input, gradient to the weights). Only a `benchmark` PR may
-change a formula here; a later PR adds a function and names it in its
-configuration's `flops` key or in a metric's `work` parameter.
+change a formula here. A later PR brings its own in the reference module of
+its configuration, in tables named as the two at the end of this file, and
+names the function in the configuration's `flops` key or in a metric's
+`work` parameter (`train_flops_per_sample`, `kernel_work`):
+
+  TRAIN_FLOPS_PER_SAMPLE[name](cfg, traffic) -> operations one sample (an
+      image, a token) requires of one training step
+  KERNEL_WORK[name](cfg, traffic) -> (operations, bytes) one training step
+      requires of the kernel, whole batch
 """
+from .spec import lookup
+
 F32 = 4      # bytes
 
 
@@ -115,6 +124,17 @@ KERNEL_WORK = {
     "resnet_v1_conv": resnet_v1_conv_work,
     "attention_core": attention_core_work,
 }
+
+
+def train_flops_per_sample(cfg, traffic, reference=None):
+    """Required operations a sample, by the configuration's `flops` name."""
+    return lookup("TRAIN_FLOPS_PER_SAMPLE", cfg["flops"],
+                  TRAIN_FLOPS_PER_SAMPLE, reference)(cfg, traffic)
+
+
+def kernel_work(name, cfg, traffic, reference=None):
+    """(operations, bytes) a step requires of the kernel `name`."""
+    return lookup("KERNEL_WORK", name, KERNEL_WORK, reference)(cfg, traffic)
 
 
 def roofline_seconds(flops, nbytes, peak):

@@ -1,8 +1,10 @@
 """Per-layer metrics: one small reader each, found by the `reader` key of
 the metric's file under `benchmark/metrics/`.
 
-A reader takes what the traced run gathered (`run`, see `run.py`) and its own
-parameters, and returns a number, or None where it finds nothing to read: the
+A reader takes what the traced run gathered (`run`, see `run.py`; its
+`reference` is the configuration's reference module, where a FLOPs count or a
+kernel's work that `flops.py` lacks is looked for) and its own parameters,
+and returns a number, or None where it finds nothing to read: the
 harness then leaves that metric out of the line. It never returns 0 for a
 share of a roofline or of a peak. A metric's file may name a `.py` beside it
 (`"reader_file"`) that defines `read(run, params)` instead.
@@ -47,8 +49,8 @@ def mfu(run, params):
     dev = _first_device(run)
     if dev is None or not dev["steps"] or run["peak"] is None:
         return None
-    per_sample = flops.TRAIN_FLOPS_PER_SAMPLE[run["cfg"]["flops"]](
-        run["cfg"], run["traffic"])
+    per_sample = flops.train_flops_per_sample(run["cfg"], run["traffic"],
+                                              run.get("reference"))
     samples_per_s = dev["steps"] * run["samples_per_step"] / dev["window_s"]
     return 100.0 * per_sample * samples_per_s / (
         run["chips"] * run["peak"]["bf16_flops_per_s"])
@@ -71,8 +73,8 @@ def category_roofline(run, params):
     ms = category_ms_per_step(run, params)
     if ms is None or run["peak"] is None:
         return None
-    work, nbytes = flops.KERNEL_WORK[params["work"]](run["cfg"],
-                                                     run["traffic"])
+    work, nbytes = flops.kernel_work(params["work"], run["cfg"],
+                                     run["traffic"], run.get("reference"))
     least, bound = flops.roofline_seconds(work / run["chips"],
                                           nbytes / run["chips"], run["peak"])
     run["notes"].append("%s: bound by %s (%.3f ms least, %.3f ms taken)"

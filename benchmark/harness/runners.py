@@ -14,9 +14,12 @@ and batch. It offers:
                    got it, from the state after one step
   free()           drop everything held on the device
 
-A configuration's `entry` key names its runner in `RUNNERS`. The reference
-put in the program's place (`ReferenceRunner`) is how the controls and the
-planted faults are read, and never a cell's runner.
+A configuration's `entry` key names its runner in `RUNNERS`: which entry of
+the program the window drives. What the entry is given comes by name too
+(the model zoo's `model`; the `program` module with a functional model's
+loss), so a new architecture on an entry that is here brings files and no
+edit. The reference put in the program's place (`ReferenceRunner`) is how
+the controls and the planted faults are read, and never a cell's runner.
 """
 import functools
 import gc
@@ -24,6 +27,8 @@ import json
 
 import jax
 import jax.numpy as jnp
+
+from .spec import module
 
 
 def _copy_tree(tree):
@@ -99,21 +104,17 @@ class GluonFusedStep:
 
 
 class ShardedStep:
-    """`parallel.ShardedTrainStep` over the functional BERT and its
-    masked-LM loss, on a mesh of the mix's size (one chip: `data=1`)."""
+    """`parallel.ShardedTrainStep` over a functional model's loss, on a mesh
+    of the mix's size (one chip: `data=1`). The loss is the configuration's
+    own: its `program` key names a module under `benchmark/programs/` whose
+    `loss_fn(cfg)` returns the `loss_fn(params, batch)` that a user hands to
+    the step, over the tree that the reference's dotted leaf names spell."""
     entry = "parallel.ShardedTrainStep.__call__"
     counters = ("train_step.compile", "train_step.retrace")
 
     def __init__(self, cfg, traffic, reference, params, batch, rehearse):
-        from mxnet_tpu.models.bert import BertConfig, bert_mlm_loss
         from mxnet_tpu.parallel import ShardedTrainStep, create_mesh
 
-        bert = BertConfig(
-            vocab_size=cfg["vocab_size"], dim=cfg["dim"],
-            n_layers=cfg["n_layers"], n_heads=cfg["n_heads"],
-            hidden_dim=cfg["hidden_dim"], max_seq_len=cfg["max_seq_len"],
-            n_types=cfg["n_types"], norm_eps=cfg["norm_eps"],
-            dtype=jnp.dtype(cfg["dtype"]))
         opt = cfg["optimizer"]
         self._beta1 = opt["beta1"]
         tree = {}
@@ -124,7 +125,7 @@ class ShardedStep:
                 node = node.setdefault(key, {})
             node[last] = value
         self._step = ShardedTrainStep(
-            lambda p, b: bert_mlm_loss(p, b, bert), tree,
+            module("programs", cfg["program"]).loss_fn(cfg), tree,
             create_mesh(data=traffic.get("mesh", {}).get("data", 1)),
             optimizer=opt["name"], lr=opt["learning_rate"], wd=opt["wd"],
             beta1=opt["beta1"], beta2=opt["beta2"], eps=opt["eps"])

@@ -1,7 +1,11 @@
 """Find a cell's files by the names in `BENCHMARK.json`.
 
 Nothing here knows a cell, a configuration, a mix or a metric by name: a
-later PR adds files and entries, and edits no file that is there.
+later PR adds files and entries, and edits no file that is there. Code comes
+the same way as data: a configuration's `reference` and `program` keys name
+modules under `reference/` and `programs/` (`module`), and a traffic kind, a
+FLOPs count or a kernel's work that the harness's own tables lack is looked
+for in the table of the same name in the reference module (`lookup`).
 """
 import importlib
 import json
@@ -13,6 +17,32 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _load(path):
     with open(path) as f:
         return json.load(f)
+
+
+def module(folder, name):
+    """`benchmark/<folder>/<name>.py`, by the name a configuration gives."""
+    return importlib.import_module(folder + "." + name)
+
+
+def lookup(table, name, own, reference):
+    """The function that `name` stands for in `own`, one of the harness's
+    tables (`table` is its name there: `KINDS`, `TRAIN_FLOPS_PER_SAMPLE`,
+    `KERNEL_WORK`), or in the table of that name that the configuration's
+    reference module keeps: where a new architecture brings its own. A name
+    in both is an error: nothing shadows a formula of the yardstick."""
+    theirs = getattr(reference, table, {})
+    if name in own and name in theirs:
+        raise SystemExit(
+            "benchmark: %s has %r of its own, which the harness's %s "
+            "defines: give it another name" % (reference.__name__, name,
+                                               table))
+    if name in own:
+        return own[name]
+    if name in theirs:
+        return theirs[name]
+    raise SystemExit("benchmark: no %r in the harness's %s%s" % (
+        name, table, " or in %s's" % reference.__name__
+        if reference is not None else ""))
 
 
 class Cell:
@@ -58,7 +88,7 @@ class Cell:
         return data
 
     def reference(self):
-        return importlib.import_module("reference." + self.cfg["reference"])
+        return module("reference", self.cfg["reference"])
 
     def rate_metric(self):
         """The end-to-end rate this cell reports (the one that is not

@@ -4,7 +4,9 @@ Read with `jax.profiler.ProfileData`, nothing else. A TPU trace has one
 plane per chip (`/device:TPU:<n>`) whose line `XLA Ops` holds every operation
 that ran, named by its whole HLO text, `XLA Modules` every program run, and
 `Async XLA Ops` the spans of asynchronous copies and collectives; the host's
-annotations are on the `python` line of `/host:CPU`, on the same clock.
+annotations are on the line of `/host:CPU` that is named after the program
+that was started (`python`, `python3`, ...), on the same clock: the line
+that holds the benchmark's own `step` annotations.
 
 The program's kernels and steps carry no names of their own yet, so
 operations are grouped by what the HLO text says they are:
@@ -21,6 +23,7 @@ operations are grouped by what the HLO text says they are:
 """
 import re
 
+STEP = "step"       # the annotation `run.py` puts around every call
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 _OP = re.compile(r"^%(\S+) = .*? ([a-z][a-z0-9\-]*)\(")
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -149,17 +152,18 @@ def _reduce_device(plane):
 
 
 def _host_spans(data):
-    """[(start, end, name)] of the host's annotations."""
+    """[(start, end, name)] of the host's annotations: every event of the
+    lines of `/host:CPU` that hold a `step` annotation. The thread that
+    drives the steps is found by what it holds: its name is the command's."""
     spans = []
     for plane in data.planes:
         if plane.name != "/host:CPU":
             continue
         for line in plane.lines:
-            if line.name != "python":
-                continue
-            for ev in line.events:
-                spans.append((ev.start_ns, ev.start_ns + ev.duration_ns,
-                              ev.name))
+            events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                      for ev in line.events]
+            if any(name == STEP for _, _, name in events):
+                spans += events
     return spans
 
 

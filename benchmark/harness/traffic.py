@@ -1,12 +1,16 @@
 """The one generator of inputs: weights and the batch, from the seed.
 
 A traffic mix is a data file under `benchmark/traffic/`; its `kind` names the
-maker below that reads its parameters. Everything is made on the device in
-one jitted call, in the type it is used in, and the same seed gives the same
-arrays.
+maker that reads its parameters: one of `KINDS` below or, for a batch that
+none of them makes, one of the `KINDS` that the configuration's reference
+module brings, `maker(key, traffic, cfg) -> {name: array}`. Everything is
+made on the device in one jitted call, in the type it is used in, and the
+same seed gives the same arrays.
 """
 import jax
 import jax.numpy as jnp
+
+from .spec import lookup
 
 
 def seed_key(seed):
@@ -48,9 +52,10 @@ def samples_per_step(traffic):
 def make(seed, reference, cfg, traffic):
     """(params, batch): the reference's leaves and the mix's batch, in one
     jitted call from the seed."""
+    maker = lookup("KINDS", traffic["kind"], KINDS, reference)
+
     @jax.jit
     def both(key):
         kp, kb = jax.random.split(key)
-        return (reference.init_params(kp, cfg),
-                KINDS[traffic["kind"]](kb, traffic, cfg))
+        return reference.init_params(kp, cfg), maker(kb, traffic, cfg)
     return both(seed_key(seed))

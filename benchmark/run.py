@@ -8,10 +8,11 @@ the seed, drives it through its first three steps (which the comparison that
 decides `correct` reads, and which warm it up), measures the timed window,
 reads the chips' memory, frees the program and follows the same three steps
 with the plain reference. The last line of standard output is one JSON
-object: `correct`, `attempted`, `failed`, `metrics`, `device`, with
-`--trace 1` `breakdown`, and last `compared`, every number compared beside
-its limit. `--trace 0` reports the cell's end-to-end metrics, `--trace 1` its
-per-layer metrics from a short traced window.
+object: `correct`, `attempted`, `failed`, `metrics`, `device`, the window's
+`trimmed_steps` and `last_gap_ms`, with `--trace 1` `breakdown`, and last
+`compared`, every number compared beside its limit. `--trace 0` reports the
+cell's end-to-end metrics, `--trace 1` its per-layer metrics from a short
+traced window.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result. `--rehearse` runs the same code at toy size on the CPU
@@ -185,13 +186,18 @@ def measure(cell, devices, seed, seconds, trace, runner_factory=None):
         with tempfile.TemporaryDirectory(prefix="benchmark_trace_") as tmp:
             def annotate(i):
                 stack = contextlib.ExitStack()
-                stack.enter_context(
-                    jax.profiler.StepTraceAnnotation("step", step_num=i))
+                stack.enter_context(jax.profiler.StepTraceAnnotation(
+                    trace_reduce.STEP, step_num=i))
                 stack.enter_context(
                     jax.profiler.TraceAnnotation(runner.entry))
                 return stack
+
+            def traced_wait(loss):     # names the gaps the host waits in
+                with jax.profiler.TraceAnnotation("window.wait"):
+                    wait(loss)
             with jax.profiler.trace(tmp, profiler_options=opts):
-                window = run_window(call, wait, min(seconds, TRACE_SECONDS),
+                window = run_window(call, traced_wait,
+                                    min(seconds, TRACE_SECONDS),
                                     annotate=annotate, dispatched=dispatched)
             found = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
                                            "*.xplane.pb"))
@@ -203,16 +209,20 @@ def measure(cell, devices, seed, seconds, trace, runner_factory=None):
     else:
         window = run_window(call, wait, seconds, dispatched=dispatched)
     after = counters()
-    losses = [float(x) for x in window["losses"][:window["completed"]]]
+    # every step that was awaited, those after the window's end too
+    losses = [float(x) for x in window["losses"][:len(window["done_s"])]]
     failed = sum(not math.isfinite(x) for x in losses)
     if window["error"] is not None:
         failed += 1
         say("window ended by: %r" % (window["error"],))
     peaks = memory_peaks(mine, held or [None] * len(mine))
+    last_gap_ms = 1e3 * (window["last_gap_s"] or 0.0)
     say("steps_done_ms: %s" % json.dumps(
         [round(1e3 * t, 2) for t in window["done_s"]]))
-    say("window: %d steps completed of %d in %.4f s; losses %.4f .. %.4f"
+    say("window: %d steps counted of %d in %.4f s, %d after its end (the "
+        "last step's gap %.2f ms); losses %.4f .. %.4f"
         % (window["completed"], window["attempted"], window["elapsed_s"],
+           window["trimmed_steps"], last_gap_ms,
            losses[0] if losses else float("nan"),
            losses[-1] if losses else float("nan")))
     say("bytes per chip with the queue full (in use + reserved): %s; the "
@@ -259,9 +269,12 @@ def measure(cell, devices, seed, seconds, trace, runner_factory=None):
               "memory_peak_bytes": max((p for p in peaks if p), default=0)}
     metrics = {}
     result = {"correct": bool(ok), "attempted": window["attempted"],
-              "failed": failed, "metrics": metrics, "device": device}
+              "failed": failed, "metrics": metrics, "device": device,
+              "trimmed_steps": window["trimmed_steps"],
+              "last_gap_ms": last_gap_ms}
     if trace:
-        run = {"cfg": cfg, "traffic": mix, "chips": cell.chips,
+        run = {"cfg": cfg, "traffic": mix, "reference": reference,
+               "chips": cell.chips,
                "samples_per_step": samples, "window": window,
                "counters_before": before, "counters_after": after,
                "first_call_s": first_call["s"], "cache_misses": cache_misses,

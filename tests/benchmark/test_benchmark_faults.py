@@ -33,6 +33,7 @@ def drive(cell_name, factory, capsys):
 @pytest.mark.parametrize("cell,fault", [
     ("resnet50_b256", "unchanged"), ("resnet50_b256", "half_batch"),
     ("bert_base_s128", "unchanged"), ("bert_base_s128", "half_batch"),
+    ("bert_base_s512", "unchanged"), ("bert_base_s512", "half_batch"),
     ("resnet50_dp4_b1024", "no_exchange")])
 def test_a_run_with_the_timed_path_broken_is_not_correct(cell, fault,
                                                          capsys):
@@ -68,7 +69,8 @@ def test_a_loss_that_is_not_finite_is_a_failed_step(capsys):
     assert result["failed"] == 1 and result["correct"] is False
 
 
-@pytest.mark.parametrize("cell", ["resnet50_b256", "bert_base_s128"])
+@pytest.mark.parametrize("cell", ["resnet50_b256", "bert_base_s128",
+                                  "bert_base_s512"])
 def test_the_control_reads_far_above_the_reference(cell):
     """fp8 operands against float32 at `highest`: at least a hundred times
     what the same reference reads against itself, on the gradient."""
@@ -92,10 +94,11 @@ def test_the_control_reads_far_above_the_reference(cell):
     assert not ok, compared
 
 
-def test_float32_state_kept_in_bfloat16_is_not_correct():
+@pytest.mark.parametrize("cell", ["bert_base_s128", "bert_base_s512"])
+def test_float32_state_kept_in_bfloat16_is_not_correct(cell):
     """The control of a configuration stated in float32: weights and AdamW's
     moments kept in bfloat16 fail the cell's limits on the change."""
-    spec = Cell("bert_base_s128", rehearse=True)
+    spec = Cell(cell, rehearse=True)
     ref = spec.reference()
 
     def readings(**kw):

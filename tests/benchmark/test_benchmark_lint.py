@@ -5,10 +5,13 @@ import json
 import os
 import re
 import shutil
+import subprocess
+import sys
+import textwrap
 
 import pytest
 
-from bench_paths import BENCH_DIR, ROOT, on_path
+from bench_paths import BENCH_DIR, FIXTURES, ROOT, on_path
 
 on_path()
 from harness import readers  # noqa: E402
@@ -139,7 +142,11 @@ def test_per_layer_metrics_match_their_files_and_cells(bench):
         with open(os.path.join(BENCH_DIR, "metrics",
                                m["name"] + ".json")) as f:
             held = json.load(f)
-        assert {k: held[k] for k in m} == m
+        # which cells report it is said once, in `BENCHMARK.json`: a later
+        # PR lists its cell there and edits no metric's file
+        assert "workloads" not in held
+        assert {k: held[k] for k in m if k != "workloads"} == {
+            k: v for k, v in m.items() if k != "workloads"}
         assert held.get("reader_file") or held["reader"] in readers.READERS
         layers.setdefault(m["layer"].split(" (")[0], set()).add(m["layer"])
     # a share of a roofline or of a peak is a percentage with its own name
@@ -201,7 +208,6 @@ def test_a_later_pr_adds_a_cell_by_files_alone(tmp_path, bench):
     (root / "benchmark/metrics/steps_traced.tok.json").write_text(json.dumps(
         {"name": "steps_traced.tok", "unit": "count", "better": "higher",
          "source": "device_trace", "layer": "Device", "moves": "tok_per_s",
-         "workloads": ["bert_large_s512"],
          "reader_file": "steps_traced.tok.py", "params": {"scale": 2}}))
     (root / "benchmark/metrics/steps_traced.tok.py").write_text(
         "def read(run, params):\n"
@@ -239,3 +245,139 @@ def test_a_later_pr_adds_a_cell_by_files_alone(tmp_path, bench):
     assert readers.read(mine["steps_traced.tok"], run, copy) == 14
     after = _digests(root / "benchmark")
     assert {k: after[k] for k in before} == before
+
+
+# ---------------------------------------------------- a new architecture
+TOY = os.path.join(FIXTURES, "toy_lm")
+PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def copy_with_the_toy(tmp_path, bench):
+    """A copy of the benchmark with what `fixtures/toy_lm/` holds laid into
+    it: a toy causal language model's reference module (with its traffic
+    kind, its FLOPs count and its kernel's work), its program module, its
+    configuration, mix, limits and a roofline metric; and the entries of
+    `BENCHMARK.json`. Returns (root, the digests of the files that were
+    there)."""
+    root = tmp_path / "repo"
+    shutil.copytree(BENCH_DIR, root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(root / "benchmark")
+    shutil.copytree(TOY, root / "benchmark", dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    assert not set(_digests(TOY)) & set(before)     # files added, none laid over
+    later = json.loads(json.dumps(bench))
+    later["configs"].append({
+        "name": "toy_lm", "source": "tests/benchmark/fixtures/toy_lm",
+        "file": "benchmark/configs/toy_lm.json", "reduced": [],
+        "why": "a causal decoder: a loss, a traffic kind and counts of its "
+               "own"})
+    later["workloads"].append({
+        "name": "toy_lm_b8", "config": "toy_lm", "traffic": "causal_b8_s128",
+        "chips": 1, "why": "8 sequences of 128 tokens, next-token loss"})
+    with open(os.path.join(TOY, "metrics",
+                           "toy_lm_attention_roofline.json")) as f:
+        roofline = json.load(f)
+    later["per_layer"].append(dict(
+        {k: roofline[k] for k in ("name", "unit", "better", "source",
+                                  "layer", "moves")},
+        workloads=["toy_lm_b8"]))
+    for m in later["end_to_end"] + later["per_layer"]:
+        if m["name"] in ("tok_per_s", "mfu.tok", "compiles_in_window.tok",
+                         "launch_ms_per_step.tok"):
+            m["workloads"].append("toy_lm_b8")
+    (root / "BENCHMARK.json").write_text(json.dumps(later))
+    return root, before
+
+
+def in_the_copy(root, code, *args):
+    """Python started in the copy as `run.py` is: the copy's own `harness`,
+    `reference` and `programs` on the path, the repo's `mxnet_tpu`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [str(root / "benchmark"), ROOT]))
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run([sys.executable, *code, *args], cwd=str(root),
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+
+
+def test_a_later_pr_adds_an_architecture_by_files_alone(tmp_path, bench):
+    """A functional model's loss on `ShardedTrainStep`, a traffic kind, a
+    train-FLOPs count and a kernel's work, none of which the harness has:
+    the rehearsal runs to its end and is correct, the readers find the
+    counts, and no file that was there is edited."""
+    root, before = copy_with_the_toy(tmp_path, bench)
+    p = in_the_copy(root, [str(root / "benchmark" / "run.py")],
+                    "--workload", "toy_lm_b8", "--seed", str(2 ** 31 + 28),
+                    "--seconds", "1", "--trace", "1", "--rehearse")
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = p.stdout.strip().splitlines()
+    assert lines[-1] == "REHEARSAL (cpu): not a chip result"
+    result = json.loads(lines[-2])
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["compared"]) == {
+        "loss1", "loss2", "loss3", "grad1", "grad1_median", "change3",
+        "change3_median"}
+    assert result["metrics"]["compiles_in_window.tok"]["value"] == 0
+    assert result["metrics"]["launch_ms_per_step.tok"]["value"] > 0
+
+    # the readers over a made-up traced run, in the copy: required FLOPs and
+    # the kernel's work come from the toy's reference module
+    script = textwrap.dedent("""
+        import json, sys
+        from harness import readers
+        from harness.spec import Cell
+        cell = Cell('toy_lm_b8')
+        dev = {'steps': 10, 'window_s': 0.5, 'busy_s': 0.4,
+               'category_s': {'mosaic': 0.001}}
+        run = {'cfg': cell.cfg, 'traffic': cell.traffic, 'chips': 1,
+               'reference': cell.reference(), 'notes': [],
+               'samples_per_step': 8 * 128, 'peak': json.loads(sys.argv[1]),
+               'trace': {'devices': [dev]}}
+        mine = {m['name']: m for m in cell.per_layer}
+        print(json.dumps({n: readers.read(mine[n], run, cell.bench_dir)
+                          for n in ('mfu.tok', 'toy_lm_attention_roofline')}
+                         | {'notes': run['notes'], 'names': sorted(mine),
+                            'where': readers.__file__}))
+        """)
+    p = in_the_copy(root, ["-c", script], json.dumps(PEAK))
+    assert p.returncode == 0, p.stderr[-3000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    assert got["where"].startswith(str(root))
+    d, h, L, V, S, B = 128, 256, 2, 512, 128, 8
+    per_token = 3 * (2 * (L * (4 * d * d + 2 * d * h) + d * V) + L * 2 * S * d)
+    assert got["mfu.tok"] == pytest.approx(
+        100 * per_token * (10 * B * S / 0.5) / PEAK["bf16_flops_per_s"])
+    least = max(B * S * 3 * L * 2 * S * d / PEAK["bf16_flops_per_s"],
+                B * S * L * 12 * d * 4 / PEAK["hbm_bytes_per_s"])
+    assert got["toy_lm_attention_roofline"] == pytest.approx(
+        100 * least * 1e3 / (1e3 * 0.001 / 10))
+    assert "toy_lm_attention: bound by" in got["notes"][0]
+    assert "flash_roofline" not in got["names"]
+    after = _digests(root / "benchmark")
+    assert {k: after[k] for k in before} == before
+
+
+@pytest.mark.parametrize("table,name", [
+    ("TRAIN_FLOPS_PER_SAMPLE", "bert"), ("KERNEL_WORK", "attention_core"),
+    ("KINDS", "mlm_tokens")])
+def test_a_name_the_harness_defines_cannot_be_defined_again(table, name):
+    """Nothing shadows a formula of the yardstick: a reference module that
+    brings a name which the harness's own table has stops the run."""
+    import types
+
+    from harness import flops, traffic
+    from harness.spec import lookup
+    own = {"TRAIN_FLOPS_PER_SAMPLE": flops.TRAIN_FLOPS_PER_SAMPLE,
+           "KERNEL_WORK": flops.KERNEL_WORK, "KINDS": traffic.KINDS}[table]
+    plain = types.ModuleType("reference.plain")
+    assert lookup(table, name, own, plain) is own[name]
+    assert lookup(table, name, own, None) is own[name]
+    mine = types.ModuleType("reference.mine")
+    setattr(mine, table, {"mine": len})
+    assert lookup(table, "mine", own, mine) is len
+    with pytest.raises(SystemExit, match="no 'other'"):
+        lookup(table, "other", own, mine)
+    setattr(mine, table, {name: len})
+    with pytest.raises(SystemExit, match="give it another name"):
+        lookup(table, name, own, mine)

@@ -9,8 +9,9 @@ import pytest
 
 from bench_paths import ROOT
 
-CELLS = ("resnet50_b256", "bert_base_s128", "resnet50_dp4_b1024")
 REHEARSAL = "REHEARSAL (cpu): not a chip result"
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
 
 
 def run(args, cwd=ROOT, timeout=300):
@@ -52,8 +53,7 @@ def test_an_unknown_workload_fails():
 
 
 def test_in_a_directory_with_the_benchmark_alone_it_fails(tmp_path):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        paths = json.load(f)["paths"]
+    paths = BENCH["paths"]
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     for p in paths:
         shutil.copytree(os.path.join(ROOT, p), tmp_path / p,
@@ -63,8 +63,20 @@ def test_in_a_directory_with_the_benchmark_alone_it_fails(tmp_path):
     assert p.returncode != 0 and not result_lines(p.stdout)
 
 
-@pytest.mark.parametrize("cell,trace", [
-    ("resnet50_b256", 0), ("bert_base_s128", 1), ("resnet50_dp4_b1024", 1)])
+def cells_and_traces():
+    """Every cell of `BENCHMARK.json`: the first traced not, the others
+    traced, so that both kinds of result line are rehearsed."""
+    return [(w["name"], int(i > 0))
+            for i, w in enumerate(BENCH["workloads"])]
+
+
+def rate_of(cell):
+    (m,) = [m["name"] for m in BENCH["end_to_end"]
+            if cell in m.get("workloads", [])]
+    return m
+
+
+@pytest.mark.parametrize("cell,trace", cells_and_traces())
 def test_rehearsal_of_each_cell_ends_on_the_rehearsal_line(cell, trace):
     p = run(["--workload", cell, "--seed", str(2 ** 31 + 17), "--seconds",
              "1", "--trace", str(trace), "--rehearse"])
@@ -86,7 +98,9 @@ def test_rehearsal_of_each_cell_ends_on_the_rehearsal_line(cell, trace):
                  if n.startswith("compiles_in_window")]
         assert moved and result["metrics"][moved[0]]["value"] == 0
     else:
-        assert set(result["metrics"]) == {"img_per_s", "setup_s"}
+        assert set(result["metrics"]) == {rate_of(cell), "setup_s"}
+    # the window's end: at most the queue's steps lie after it
+    assert 0 <= result["trimmed_steps"] <= 8 and result["last_gap_ms"] >= 0
     for name, check in result["compared"].items():
         assert "compared %s:" % name in p.stderr
     assert any(line.startswith("steps_done_ms: [") for line in lines)

@@ -23,9 +23,37 @@ TOK_PHASES = ["launch_ms_per_step.tok", "dispatch_other_ms_per_step.tok"]
 KERNELS = ["flash_fwd_ms_per_step", "flash_dq_ms_per_step",
            "flash_dkv_ms_per_step"]
 SETUP = ["setup_trace_s", "setup_lower_s", "setup_xla_s"]
-CELLS = {"resnet50_b256": [m for m in IMG_PHASES if "stage" not in m] + SETUP,
-         "resnet50_dp4_b1024": IMG_PHASES + SETUP,
-         "bert_base_s128": TOK_PHASES + KERNELS + SETUP}
+
+
+def cells_by_rule():
+    """{cell: the metrics above that it reports}, from `BENCHMARK.json` and
+    the configurations' files and from no list of names, so that a later
+    cell needs no edit here. A cell that reports `img_per_s` reports the
+    `.img` phases, `stage` on a mesh only; one that reports `tok_per_s` the
+    `.tok` phases, and the three kernels where its configuration is BERT's
+    program; every cell the three set-up spans."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    rates = {w: m["name"] for m in bench["end_to_end"]
+             if m["name"] in ("img_per_s", "tok_per_s")
+             for w in m["workloads"]}
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    out = {}
+    for w in bench["workloads"]:
+        with open(os.path.join(ROOT, files[w["config"]])) as f:
+            cfg = json.load(f)
+        names = list(SETUP)
+        if rates.get(w["name"]) == "img_per_s":
+            names += [m for m in IMG_PHASES
+                      if "stage" not in m or w["chips"] > 1]
+        if rates.get(w["name"]) == "tok_per_s":
+            names += TOK_PHASES
+            names += KERNELS if cfg.get("program") == "bert" else []
+        out[w["name"]] = names
+    return out
+
+
+CELLS = cells_by_rule()
 
 
 def metric(name, **params):
@@ -185,7 +213,16 @@ def test_each_new_metric_resolves_in_its_cells_alone(name):
         for m in held:
             assert os.path.exists(os.path.join(BENCH_DIR, "metrics",
                                                m["reader_file"]))
-            assert {k: m[k] for k in entry} == entry
+            assert {k: m[k] for k in entry if k != "workloads"} == {
+                k: v for k, v in entry.items() if k != "workloads"}
+
+
+def test_the_rule_finds_the_cells_that_are_there():
+    assert set(CELLS["resnet50_b256"]) == set(
+        m for m in IMG_PHASES if "stage" not in m) | set(SETUP)
+    assert set(CELLS["resnet50_dp4_b1024"]) == set(IMG_PHASES + SETUP)
+    assert set(CELLS["bert_base_s128"]) == set(TOK_PHASES + KERNELS + SETUP)
+    assert CELLS["bert_base_s512"] == CELLS["bert_base_s128"]
 
 
 def test_a_traced_rehearsal_prints_the_phases_and_the_set_up_times():

@@ -46,6 +46,47 @@ def test_idle_gaps_are_named_by_the_host_span(reduced):
     assert any(name.startswith("mosaic/") for name, _ in out["device_ops"])
 
 
+def test_the_hosts_line_is_found_by_the_step_annotations_it_holds():
+    """`toy_bert_named.xplane.pb.gz` was recorded from a process started as
+    the benchmark's command is, `python3`: the host's line bears that name,
+    and the gaps are named all the same."""
+    with gzip.open(os.path.join(FIXTURES,
+                                "toy_bert_named.xplane.pb.gz")) as f:
+        data = tr.loads(f.read())
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    assert "python" not in [line.name for line in host.lines]
+    spans = tr._host_spans(data)
+    assert sum(name == tr.STEP for _, _, name in spans) == 4
+    assert {"train_step", "train_step.launch"} <= {n for _, _, n in spans}
+    # nothing of the runtime's own threads
+    assert not any(n.startswith("tpu::") for _, _, n in spans)
+    gaps = tr.reduce_trace(data)["idle_gaps"]
+    assert len(gaps) == 5
+    assert all(name != "no_host_span" for name, _ in gaps)
+    assert gaps[0][0] == "PjitFunction(step_fn)"
+
+
+class _Made:
+    """A made-up plane, line or event: just the fields the reduction
+    reads."""
+
+    def __init__(self, **kw):
+        self.__dict__.update(kw)
+
+
+def test_a_host_without_step_annotations_names_no_gap():
+    line = _Made(name="python3", events=[
+        _Made(name="PjitFunction(f)", start_ns=0, duration_ns=10)])
+    data = _Made(planes=[_Made(name="/host:CPU", lines=[line])])
+    assert tr._host_spans(data) == []
+    assert tr._host_doing([], 5) == "no_host_span"
+    line.events.append(_Made(name=tr.STEP, start_ns=0, duration_ns=20))
+    assert sorted(tr._host_spans(data)) == [(0, 10, "PjitFunction(f)"),
+                                            (0, 20, tr.STEP)]
+    assert tr._host_doing(tr._host_spans(data), 5) == "PjitFunction(f)"
+    assert tr._host_doing(tr._host_spans(data), 15) == tr.STEP
+
+
 @pytest.mark.parametrize("text,want", [
     ('%all-reduce-start.3 = f32[64]{0} all-reduce-start(f32[64]{0} %x), '
      'replica_groups={{0,1,2,3}}', ("all-reduce-start.3", "collective")),
