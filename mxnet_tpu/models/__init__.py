@@ -12,6 +12,7 @@ from . import llama
 from . import bert
 from . import resnet
 from . import dlrm
+from .losses import linear_cross_entropy
 from .llama import (LlamaConfig, llama_init, llama_forward, llama_loss,
                     llama_prefill_paged, llama_decode_paged,
                     llama_chunk_paged, llama_draft_loop, init_kv_pools)
@@ -27,4 +28,5 @@ __all__ = [
     "BertConfig", "bert_init", "bert_forward", "bert_mlm_loss",
     "ResNetConfig", "resnet_init", "resnet_forward", "resnet_loss",
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_loss",
+    "linear_cross_entropy",
 ]

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..parallel.flash_attention import flash_attention_bshd
 from .llama import _dense_init
+from .losses import linear_cross_entropy
 
 __all__ = ["BertConfig", "bert_init", "bert_forward", "bert_mlm_loss",
            "CONFIGS"]
@@ -135,9 +136,8 @@ def bert_mlm_loss(params, batch, cfg: BertConfig):
     batch = {'tokens', 'targets', 'mask'} each (B,S); mask 1 where the
     position is an MLM prediction site."""
     h = bert_forward(params, batch["tokens"], cfg)
-    logits = (h @ params["word_embed"].T.astype(h.dtype)).astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
-                               axis=-1)[..., 0]
-    mask = batch["mask"].astype(jnp.float32)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    # every position goes through the decoder; the scope names the head's
+    # operations in a device trace
+    with jax.named_scope("mlm_head"):
+        return linear_cross_entropy(h, params["word_embed"],
+                                    batch["targets"], batch["mask"])

@@ -32,6 +32,7 @@ from jax import lax
 from ..parallel.flash_attention import (flash_attention, paged_attention,
                                         paged_attention_chunk)
 from ..parallel.ring_attention import ring_attention
+from .losses import linear_cross_entropy
 
 __all__ = ["LlamaConfig", "llama_init", "llama_forward", "llama_loss",
            "init_kv_cache", "llama_decode_step", "init_kv_pools",
@@ -186,13 +187,9 @@ def _layer(lp, x, cos, sin, cfg, seq_axis=None):
     return _mlp(lp, _attention(lp, x, cos, sin, cfg, seq_axis), cfg)
 
 
-def llama_forward(params, tokens, cfg: LlamaConfig, seq_axis=None,
-                  positions=None):
-    """tokens (B,S) int32 → logits (B,S,vocab) fp32.
-
-    seq_axis: name of a mesh axis tokens are sequence-sharded over; attention
-    then runs as ring attention (call under shard_map). positions overrides
-    the default iota (needed for the sequence-sharded case)."""
+def _hidden(params, tokens, cfg: LlamaConfig, seq_axis=None, positions=None):
+    """tokens (B,S) int32 → the final norm's output (B,S,dim) in cfg.dtype,
+    and the (vocab, dim) head that reads it."""
     B, S = tokens.shape
     if cfg.embed_onehot:
         oh = jax.nn.one_hot(tokens, cfg.vocab_size,
@@ -217,18 +214,27 @@ def llama_forward(params, tokens, cfg: LlamaConfig, seq_axis=None,
             x = layer(params["layers"][str(i)], x, cos, sin, cfg, seq_axis)
     x = rms_norm(x, params["norm"], cfg.norm_eps)
     head = params["tok_embeddings"] if cfg.tie_embeddings else params["lm_head"]
+    return x, head
+
+
+def llama_forward(params, tokens, cfg: LlamaConfig, seq_axis=None,
+                  positions=None):
+    """tokens (B,S) int32 → logits (B,S,vocab) fp32.
+
+    seq_axis: name of a mesh axis tokens are sequence-sharded over; attention
+    then runs as ring attention (call under shard_map). positions overrides
+    the default iota (needed for the sequence-sharded case)."""
+    x, head = _hidden(params, tokens, cfg, seq_axis, positions)
     return (x @ head.T.astype(x.dtype)).astype(jnp.float32)
 
 
 def llama_loss(params, batch, cfg: LlamaConfig, seq_axis=None):
     """Next-token cross entropy. batch = {'tokens': (B,S+1) int32} or a
-    (B,S+1) array; fp32 log-softmax for numerical safety."""
+    (B,S+1) array; fp32 logits and statistics for numerical safety."""
     tokens = batch["tokens"] if isinstance(batch, dict) else batch
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
-    logits = llama_forward(params, inp, cfg, seq_axis=seq_axis)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    x, head = _hidden(params, inp, cfg, seq_axis)
+    return linear_cross_entropy(x, head, tgt)
 
 
 # -------------------------------------------------------------- decoding

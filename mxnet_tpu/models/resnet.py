@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .losses import linear_cross_entropy
+
 __all__ = ["ResNetConfig", "resnet_init", "resnet_forward", "resnet_loss",
            "CONFIGS"]
 
@@ -132,12 +134,8 @@ def _bottleneck(x, blk, cfg, train, stride, stats_out, prefix):
     return jax.nn.relu(out + x)
 
 
-def resnet_forward(params, images, cfg: ResNetConfig, train=False):
-    """images (B,H,W,3) → (logits (B,classes) fp32, batch-stats dict).
-
-    In train mode the returned stats dict maps "stages/si/bi/bnX" →
-    (batch_mean, batch_var) for the running-stat EMA update (done by the
-    caller, outside the grad)."""
+def _pooled(params, images, cfg: ResNetConfig, train):
+    """images (B,H,W,3) → (pooled features (B,C) fp32, batch-stats dict)."""
     stats = {}
     x = images.astype(cfg.dtype)
     x, s = _bn(_conv(x, params["stem"]["conv"], 2), params["stem"]["bn"],
@@ -153,7 +151,16 @@ def resnet_forward(params, images, cfg: ResNetConfig, train=False):
             stride = 2 if (bi == 0 and si > 0) else 1
             x = _bottleneck(x, stage[str(bi)], cfg, train, stride, stats,
                             "stages/%d/%d" % (si, bi))
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
+    return jnp.mean(x.astype(jnp.float32), axis=(1, 2)), stats
+
+
+def resnet_forward(params, images, cfg: ResNetConfig, train=False):
+    """images (B,H,W,3) → (logits (B,classes) fp32, batch-stats dict).
+
+    In train mode the returned stats dict maps "stages/si/bi/bnX" →
+    (batch_mean, batch_var) for the running-stat EMA update (done by the
+    caller, outside the grad)."""
+    x, stats = _pooled(params, images, cfg, train)
     logits = x @ params["fc"]["w"].astype(jnp.float32) + \
         params["fc"]["b"].astype(jnp.float32)
     return logits, stats
@@ -161,11 +168,12 @@ def resnet_forward(params, images, cfg: ResNetConfig, train=False):
 
 def resnet_loss(params, batch, cfg: ResNetConfig):
     """Softmax CE; returns (loss, batch stats) for use with has_aux grad."""
-    logits, stats = resnet_forward(params, batch["images"], cfg, train=True)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["labels"][..., None],
-                               axis=-1)[..., 0]
-    return jnp.mean(nll), stats
+    x, stats = _pooled(params, batch["images"], cfg, train=True)
+    # the bias as the weight of one more feature that is always 1
+    x = jnp.concatenate([x, jnp.ones_like(x[:, :1])], axis=1)
+    w = jnp.concatenate([params["fc"]["w"], params["fc"]["b"][None]], axis=0)
+    return linear_cross_entropy(x, w.T.astype(jnp.float32),
+                                batch["labels"]), stats
 
 
 def update_running_stats(params, stats, cfg: ResNetConfig):
