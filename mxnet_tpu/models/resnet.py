@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.nn import batch_moments
 from .losses import linear_cross_entropy
 
 __all__ = ["ResNetConfig", "resnet_init", "resnet_forward", "resnet_loss",
@@ -104,8 +105,9 @@ def _conv(x, w, stride=1, padding="SAME"):
 def _bn(x, p, cfg, train):
     xf = x.astype(jnp.float32)
     if train:
-        mu = jnp.mean(xf, axis=(0, 1, 2))
-        var = jnp.var(xf, axis=(0, 1, 2))
+        # the statistics of `BatchNorm` (ops/nn.py): a 16-bit activation
+        # gives both moments in one pass about the running mean
+        mu, var = batch_moments(xf, (0, 1, 2), p["mean"], x.dtype)
         stats = (mu, var)
     else:
         mu, var = p["mean"], p["var"]

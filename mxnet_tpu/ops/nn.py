@@ -200,30 +200,66 @@ alias("Pooling", "pooling")
 # Normalization (reference: batch_norm.cc, layer_norm.cc, instance_norm.cc,
 # group_norm.cc, l2_normalization.cc)
 # ---------------------------------------------------------------------------
+def batch_moments(x32, red, running_mean, dtype):
+    """Mean and biased variance of `x32` over the axes `red`, accumulated in
+    float32 and with no pass over `x32` that the mathematics does not need.
+    `x32` is the input widened from `dtype`, whose width chooses the order
+    of evaluation; `running_mean` broadcasts against `x32`.
+
+    Wider than 16 bits: two passes, the second about stop_gradient(mean).
+    The values are jnp.mean's and jnp.var's to the bit; the gradient loses
+    the cotangent of the inner mean, -(2/N) * sum(x - mean), which is zero
+    in exact arithmetic and cost one read of `x32` to compute its rounding
+    residue.
+
+    16 bits or narrower: one pass about c = stop_gradient(running_mean),
+    mean = E[x - c] + c and var = E[(x - c)^2] - E[x - c]^2, so both sums
+    can join the epilogue of whatever produced the tensor. A square of a
+    16-bit value is exact in float32; what the form adds is the sums' own
+    rounding times 1 + r^2, r = |mean - c| / std (2e-5 * (1 + r^2) relative
+    at most, tests/test_op_parity.py), under a 16-bit result's own step for
+    r up to about 20. A non-finite running mean reaches the batch statistics
+    this way; evaluation is broken by then already."""
+    if jnp.dtype(dtype).itemsize > 2:
+        mean = jnp.mean(x32, axis=red, keepdims=True)
+        var = jnp.var(x32, axis=red, mean=lax.stop_gradient(mean))
+        return jnp.squeeze(mean, red), var
+    c = lax.stop_gradient(running_mean.astype(jnp.float32))
+    xc = x32 - c
+    m1 = jnp.mean(xc, axis=red)
+    m2 = jnp.mean(xc * xc, axis=red)
+    return m1 + c.reshape(m1.shape), jnp.maximum(m2 - m1 * m1, 0.0)
+
+
 @register("BatchNorm")
 def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
                 momentum=0.9, fix_gamma=True, use_global_stats=False,
                 output_mean_var=False, axis=1, cudnn_off=None):
     """Normalization math only; the moving-average update is done by the
     caller (Gluon layer / executor) functionally — reference mutates aux
-    states inside the op (batch_norm.cc), which XLA forbids."""
+    states inside the op (batch_norm.cc), which XLA forbids.
+
+    Training-mode statistics are `batch_moments`': accumulated in float32,
+    their evaluation order chosen from the operand's width (one pass about
+    the running mean for a 16-bit input, two for a wider one)."""
     red = tuple(i for i in range(data.ndim) if i != axis % data.ndim)
     shape = [1] * data.ndim
     shape[axis % data.ndim] = data.shape[axis % data.ndim]
+    # read once, for the statistics and the normalisation: autodiff then
+    # rounds the input's cotangent to a 16-bit input's type once, not twice
+    x32 = data.astype(jnp.float32)
     if use_global_stats:
         mean, var = moving_mean, moving_var
     else:
-        x32 = data.astype(jnp.float32)
-        mean = jnp.mean(x32, axis=red)
-        var = jnp.var(x32, axis=red)
+        mean, var = batch_moments(x32, red, moving_mean.reshape(shape),
+                                  data.dtype)
     g = jnp.ones_like(gamma) if fix_gamma else gamma
     # normalize in fp32, emit in the input dtype (reference cudnn BN does
     # fp32 internal math for fp16 inputs) — keeps a bf16 conv chain bf16
     # even when gamma/beta/stats are kept fp32 by BatchNorm.cast
     inv = lax.rsqrt(var.astype(jnp.float32) + eps)
-    out = ((data.astype(jnp.float32) -
-            mean.reshape(shape).astype(jnp.float32)) * inv.reshape(shape) *
-           g.reshape(shape).astype(jnp.float32) +
+    out = ((x32 - mean.reshape(shape).astype(jnp.float32)) *
+           inv.reshape(shape) * g.reshape(shape).astype(jnp.float32) +
            beta.reshape(shape).astype(jnp.float32)).astype(data.dtype)
     if output_mean_var:
         return out, mean, var

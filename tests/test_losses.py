@@ -197,3 +197,41 @@ def test_sharded_step_of_float32_bert_tiny_compiles_once():
         losses.append(float(loss))
     assert step._compiled._cache_size() == 1
     assert losses[-1] < losses[0]
+
+
+def _two_pass_bn(x, p, cfg, train):
+    """`models/resnet.py::_bn` as it was before PR 30: `jnp.var`'s two
+    passes, differentiated through the inner mean as well."""
+    xf = x.astype(jnp.float32)
+    mu, var = jnp.mean(xf, axis=(0, 1, 2)), jnp.var(xf, axis=(0, 1, 2))
+    y = (xf - mu) * jax.lax.rsqrt(var + cfg.bn_eps) * p["gamma"] + p["beta"]
+    return y.astype(x.dtype), (mu, var)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_resnet_statistics_keep_loss_and_gradients(monkeypatch, dtype, tol):
+    """`resnet_loss` on `ops.nn.batch_moments` against its own old two-pass
+    statistics: loss and every leaf's gradient, to 1e-5 in float32 and to
+    bfloat16's rounding where the activations are bfloat16."""
+    cfg = dataclasses.replace(resnet.CONFIGS["resnet_tiny"], dtype=dtype)
+    k = jax.random.split(jax.random.PRNGKey(5), 3)
+    params = resnet.resnet_init(k[0], cfg)
+    batch = {"images": jax.random.normal(k[1], (8, 32, 32, 3)),
+             "labels": jax.random.randint(k[2], (8,), 0, cfg.classes)}
+
+    def loss(p):
+        return resnet.resnet_loss(p, batch, cfg)[0]
+
+    got, got_g = jax.value_and_grad(loss)(params)
+    monkeypatch.setattr(resnet, "_bn", _two_pass_bn)
+    want, want_g = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+    flat = jax.tree_util.tree_flatten_with_path(want_g)[0]
+    for (path, b), a in zip(flat, jax.tree_util.tree_leaves(got_g)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(
+            np.linalg.norm(a - b), 0, rtol=0,
+            atol=tol * max(float(np.linalg.norm(b)), 1.0),
+            err_msg=jax.tree_util.keystr(path))

@@ -210,3 +210,190 @@ def test_im2col_col2im():
     loss.backward()
     g = x.grad.asnumpy()
     assert g[0, 0, 0, 0] == 4.0 and g[0, 0, 2, 2] == 9.0
+
+
+# ---------------------------------------------------------------------------
+# BatchNorm's training-mode statistics (PR 30): one pass about the running
+# mean for a 16-bit input, jnp.var's two passes about stop_gradient(mean)
+# for a wider one. References are float64 numpy over the same stored values.
+# ---------------------------------------------------------------------------
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+_BN = _reg.get("BatchNorm").fn
+_EPS = 1e-5
+
+
+def _two_pass_bn(data, gamma, beta, axis=1):
+    """`_batch_norm`'s training mode as it was before PR 30."""
+    red = tuple(i for i in range(data.ndim) if i != axis)
+    shape = [1] * data.ndim
+    shape[axis] = data.shape[axis]
+    x32 = data.astype(jnp.float32)
+    mean, var = jnp.mean(x32, axis=red), jnp.var(x32, axis=red)
+    out = ((data.astype(jnp.float32) - mean.reshape(shape)) *
+           jax.lax.rsqrt(var + _EPS).reshape(shape) * gamma.reshape(shape)
+           + beta.reshape(shape)).astype(data.dtype)
+    return out, mean, var
+
+
+def _bn(data, gamma, beta, moving_mean, axis=1):
+    return _BN(data, gamma, beta, moving_mean, jnp.ones_like(moving_mean),
+               eps=_EPS, fix_gamma=False, output_mean_var=True, axis=axis)
+
+
+def _stored(dtype, rows, channels, ratio, seed=0):
+    """(the 16-bit array, its values in float64, their mean, variance)."""
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray((rng.standard_normal((rows, channels)) + ratio)
+                    .astype(np.float32)).astype(dtype)
+    x64 = np.asarray(x.astype(jnp.float32), np.float64)
+    return x, x64, x64.mean(0), x64.var(0)
+
+
+@pytest.mark.parametrize("rows", [8, 802816])
+@pytest.mark.parametrize("moving", ["zeros", "mean", "near"])
+@pytest.mark.parametrize("ratio", [0, 3, 30, 100])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16"])
+def test_batch_norm_one_pass_statistics(dtype, ratio, moving, rows):
+    """A 16-bit input at |mean| / std = `ratio`, the running mean at nought,
+    at the batch mean or 0.1 std from it, 8 and 802,816 values a channel
+    (ResNet-50's stage 1): what the one-pass form adds to the variance's
+    error is the sums' rounding times 1 + r^2, r = |mean - moving| / std, so
+    a converged running mean gives 1e-4 at any |mean| / std."""
+    channels = 4
+    x, x64, mean, var = _stored(dtype, rows, channels, ratio)
+    std = np.sqrt(var)
+    shift = {"zeros": 0 * mean, "mean": mean, "near": mean + 0.1 * std}[moving]
+    shift = np.asarray(shift, np.float32)
+    out, got_mean, got_var = jax.jit(_bn)(
+        x, jnp.ones(channels), jnp.zeros(channels), jnp.asarray(shift))
+    assert got_mean.dtype == got_var.dtype == jnp.float32
+    assert out.dtype == x.dtype
+    r = np.abs(mean - shift) / std
+    tol = 2e-5 * (1 + r ** 2)
+    np.testing.assert_array_less(np.abs(np.asarray(got_var) - var) / var, tol)
+    np.testing.assert_array_less(
+        np.abs(np.asarray(got_mean) - mean), 2e-5 * (np.abs(mean - shift) +
+                                                     std) + 1e-7 * np.abs(mean))
+    want = (x64 - mean) / np.sqrt(var + _EPS)
+    step = float(jnp.finfo(x.dtype).eps)      # the stored result's own
+    np.testing.assert_array_less(
+        np.abs(np.asarray(out.astype(jnp.float32)) - want),
+        (step + tol) * np.maximum(np.abs(want), 1.0))
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("shape,axis", [((8, 16, 1, 1), 1), ((32, 6, 14, 14), 1),
+                                        ((4, 7, 5, 3), 3), ((64, 5), 1)])
+def test_batch_norm_float32_forward_is_the_old_one_to_the_bit(shape, axis,
+                                                              jit):
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    c = shape[axis]
+    x = jax.random.normal(k[0], shape) * 3 + 2
+    gamma, beta = jax.random.normal(k[1], (c,)), jax.random.normal(k[2], (c,))
+
+    def new(x, gamma, beta):
+        return _bn(x, gamma, beta, jnp.full((c,), 0.5), axis=axis)
+
+    def old(x, gamma, beta):
+        return _two_pass_bn(x, gamma, beta, axis=axis)
+
+    if jit:
+        new, old = jax.jit(new), jax.jit(old)
+    for got, want in zip(new(x, gamma, beta), old(x, gamma, beta)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("moving", ["zeros", "mean"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16"])
+def test_batch_norm_gradients_are_the_two_pass_forms(dtype, moving):
+    """d data, d gamma, d beta against autodiff of the old two-pass form in
+    float32 on the same stored values: 1e-5 of the largest entry for a
+    float32 input, the input's own step for a 16-bit one."""
+    shape, c = (16, 6, 5, 5), 6
+    k = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = (jax.random.normal(k[0], shape) * 2 + 3).astype(dtype)
+    gamma = jax.random.normal(k[1], (c,)) + 1.5
+    beta = jax.random.normal(k[2], (c,))
+    weight = jax.random.normal(k[3], shape)
+    x32 = x.astype(jnp.float32)
+    shift = (jnp.mean(x32, (0, 2, 3)) if moving == "mean"
+             else jnp.zeros(c))
+
+    def loss(fn, x, gamma, beta):
+        return jnp.sum(fn(x, gamma, beta)[0].astype(jnp.float32) * weight)
+
+    got = jax.grad(lambda *a: loss(
+        lambda x, g, b: _bn(x, g, b, shift), *a), (0, 1, 2))(x, gamma, beta)
+    want = jax.grad(lambda *a: loss(_two_pass_bn, *a), (0, 1, 2))(
+        x32, gamma, beta)
+    assert got[0].dtype == x.dtype
+    tol = 1e-5 if dtype == "float32" else float(jnp.finfo(x.dtype).eps)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=0, atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("value", [0.0, 3.3, 1000.0])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16"])
+def test_batch_norm_constant_channel(dtype, value):
+    """A channel that is constant over the batch: the variance is not
+    negative (for float32 the two-pass form's to the bit: 0, or the square
+    of what the mean's own rounding left), and the output and every
+    gradient are finite."""
+    shape, c = (32, 3, 9, 9), 3
+    x = jax.random.normal(jax.random.PRNGKey(1), shape)
+    x = x.at[:, 1].set(value).astype(dtype)
+    gamma, beta, shift = jnp.ones(c), jnp.zeros(c), jnp.zeros(c)
+    out, _, var = _bn(x, gamma, beta, shift)
+    assert np.all(np.asarray(var) >= 0)
+    if dtype == "float32":
+        assert float(var[1]) == float(_two_pass_bn(x, gamma, beta)[2][1])
+        assert float(var[1]) <= 1e-8 * value ** 2
+    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+    grads = jax.grad(lambda x, g, b: jnp.sum(jnp.square(
+        _bn(x, g, b, shift)[0].astype(jnp.float32))), (0, 1, 2))(
+            x, gamma, beta)
+    for g in grads:
+        assert np.all(np.isfinite(np.asarray(g, np.float32)))
+
+
+@pytest.mark.parametrize("moving", ["zeros", "mean"])
+def test_batch_norm_data_gradient_is_rounded_once(moving):
+    """BatchNorm's output does not change when a constant is added to a
+    channel, so d data sums to nought over it: to float32 rounding in
+    float32, and for a bfloat16 input the gradient is that float32 one
+    rounded to bfloat16 once. Read twice and added in bfloat16, as before
+    PR 30, the small mean correction was lost at every position alike and
+    the sum drifted with N (PERF.md, fault 2)."""
+    # rows x channels: the CPU sums a column of such an array to float32
+    # rounding, which it does not do over the (0, 2, 3) of an NCHW one
+    shape, c = (50176, 4), 4
+    k = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = (jax.random.normal(k[0], shape) + 0.5).astype(jnp.bfloat16)
+    x32 = x.astype(jnp.float32)
+    weight = jnp.abs(jax.random.normal(k[1], shape)) + 1.0   # all one sign
+    ones, zeros = jnp.ones(c), jnp.zeros(c)
+    shift = jnp.mean(x32, 0) if moving == "mean" else zeros
+
+    def d_data(fn, x):
+        return jax.grad(lambda x: jnp.sum(
+            fn(x)[0].astype(jnp.float32) * weight))(x)
+
+    def over_channel(g):
+        return np.asarray(g.astype(jnp.float32), np.float64).sum(0)
+
+    # the float32 gradient under the cotangent a bfloat16 output hands back
+    weight = weight.astype(jnp.bfloat16).astype(jnp.float32)
+    wide = d_data(lambda x: _two_pass_bn(x, ones, zeros), x32)
+    assert np.all(np.abs(over_channel(wide)) <
+                  1e-6 * np.abs(np.asarray(wide)).sum(0))
+    once = wide.astype(jnp.bfloat16)
+    got = d_data(lambda x: _bn(x, ones, zeros, shift), x)
+    assert float(jnp.mean(got == once)) > 0.999
+    walk = 2.0 ** -9 * np.sqrt(np.asarray(jnp.sum(wide * wide, 0)))
+    assert np.all(np.abs(over_channel(got) - over_channel(once)) < walk)
+    twice = d_data(lambda x: _two_pass_bn(x, ones, zeros), x)
+    assert float(jnp.mean(twice == once)) < 0.5
+    assert np.all(np.abs(over_channel(twice)) > 20 * walk)
