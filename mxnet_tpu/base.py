@@ -121,7 +121,10 @@ register_env("MXNET_OPTIMIZER_AGGREGATION_SIZE", 4, int,
              "Multi-tensor (fused) optimizer update group size in Trainer; "
              "0 disables aggregation (reference: optimizer_op.cc multi_sgd).")
 register_env("MXNET_TPU_USE_PALLAS", True, bool,
-             "Use Pallas kernels for hot ops (attention, fused optimizer) when on TPU.")
+             "Pallas kernels on TPU. Unset: the registry's per-op tpu_impls are on, the "
+             "flat optimizer (ops/fused_optimizer.py) and segment-sum (ops/sparse_ops.py) "
+             "kernels are off; 1 turns those on too, 0 turns all of them off. The flash "
+             "attention kernels do not read it.")
 register_env("MXNET_KVSTORE_BIGARRAY_BOUND", 1000000, int,
              "Kept for API compat (reference sharded big arrays across PS servers).")
 register_env("MXNET_PROFILER_AUTOSTART", False, bool,

@@ -14,8 +14,7 @@ Two coupled layers (ROADMAP item #2, ISSUE 11):
   versions, with atomic writes, corruption-tolerant loads and keep=N
   eviction — `mx.serve`'s warmup executables and the train-step programs
   ride the same cache, so a fleet replica or a preempted elastic worker
-  cold-starts in seconds instead of recompiling (`BENCH=startup` is the
-  evidence).
+  restores instead of recompiling (not measured on the chip: ROADMAP S4).
 """
 from . import cache, lower, passes
 from .cache import AOTCache, aot_cache, cache_key

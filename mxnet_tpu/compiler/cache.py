@@ -153,9 +153,23 @@ class AOTCache:
             if hashlib.sha256(payload).hexdigest().encode() != digest:
                 raise ValueError("checksum mismatch")
             meta, serialized, in_tree_b, out_tree_b = pickle.loads(payload)
+            # the executable goes back onto the devices it was compiled
+            # for, in their order (a mesh's order is part of the program):
+            # left to itself the runtime loads it over every device of the
+            # process and the first call wants a shard for each
+            import jax
+            by_id = {d.id: d for d in jax.devices()}
+            try:
+                devices = [by_id[i] for i in meta["device_ids"]]
+            except KeyError:
+                # written before ids were recorded, or for a device this
+                # process lacks: not this process's entry
+                _telem.inc("compiler.cache.misses")
+                return None
             from jax.experimental import serialize_executable as _se
             loaded = _se.deserialize_and_load(
-                serialized, pickle.loads(in_tree_b), pickle.loads(out_tree_b))
+                serialized, pickle.loads(in_tree_b), pickle.loads(out_tree_b),
+                execution_devices=devices)
         except Exception:
             # a bad entry must cost a recompile, not a crash — count it
             # and treat as a miss (the next store overwrites it)
@@ -187,8 +201,13 @@ class AOTCache:
         try:
             from jax.experimental import serialize_executable as _se
             serialized, in_tree, out_tree = _se.serialize(compiled)
+            # the same object `serialize` pickles: its device list is the
+            # program's own assignment, whatever else the process holds
+            device_ids = [d.id for d in compiled._executable
+                          ._unloaded_executable.device_list]
             payload = pickle.dumps(
-                (dict(meta or {}, label=label, versions=_versions()),
+                (dict(meta or {}, label=label, versions=_versions(),
+                      device_ids=device_ids),
                  serialized, pickle.dumps(in_tree), pickle.dumps(out_tree)))
         except Exception:
             _telem.inc("compiler.cache.serialize_error")

@@ -36,9 +36,7 @@ Quickstart::
 
 Observability: table + state bytes land in the HBM ledger scope
 ``embedding``; pushes/lookups tick ``embedding.*`` counters and the comm
-layer ticks ``comm.sparse.*`` — `parse_log --sparse` renders the table
-and ``BENCH=sparse`` A/Bs unique-rows comm against the densified
-baseline.
+layer ticks ``comm.sparse.*`` — `parse_log --sparse` renders the table.
 """
 from .table import EmbeddingComm, MeshEmbeddingComm, ShardedEmbedding
 from .serving import EmbeddingLookupService

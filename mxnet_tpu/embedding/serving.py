@@ -12,8 +12,8 @@ The no-retrace contract is the serve one (`ServePrograms._on_miss`): a
 post-warm-up bucket miss counts ``serve.retrace``, notes the compile, and
 routes through `analysis.guard.on_retrace` so the trace guard can veto —
 steady-state traffic never compiles. Lookup latency lands in the
-``embedding.serve.lookup_ms`` histogram; `BENCH=sparse` reports its
-p50/p99.
+``embedding.serve.lookup_ms`` histogram; `parse_log --sparse` reports its
+quantiles.
 
 ``refresh()`` re-snapshots the table after training steps — serving reads
 a consistent snapshot, never a half-updated shard.

@@ -10,7 +10,7 @@ applied to rows). The three legs:
   with out-of-shard rows masked to zero, and one cross-rank sum
   (`comm.all_reduce`) completes the batch: exactly one rank contributes
   each real row, so the sum is bit-identical to the dense gather
-  (the SCALE.md one-hot-matmul embedding trick, as a masked gather).
+  (the 8B plan's one-hot-matmul embedding trick, as a masked gather).
 * **apply_grads** — the sparse data-parallel update: each rank dedups its
   local (ids, grad-rows) via the traceable stable-sort merge, exchanges
   fixed-size unique-row slabs (`comm.all_gather` — rank-order concat, the

@@ -1,6 +1,4 @@
 """`gluon.contrib.cnn` (reference: python/mxnet/gluon/contrib/cnn/)."""
-from .conv_layers import (DeformableConvolution,  # noqa: F401
-                          FusedConvBNReLU, FusedConvBNReLUTrain)
+from .conv_layers import DeformableConvolution  # noqa: F401
 
-__all__ = ["DeformableConvolution", "FusedConvBNReLU",
-           "FusedConvBNReLUTrain"]
+__all__ = ["DeformableConvolution"]

@@ -82,161 +82,3 @@ class DeformableConvolution(HybridBlock):
             out = self.act(out)
         return out
 
-
-class FusedConvBNReLU(HybridBlock):
-    """Inference-path fused conv3x3 + folded-BN + ReLU (+ residual).
-
-    Wraps `_contrib_conv_bn_relu` (ops/fused_conv.py — Pallas implicit-GEMM
-    on TPU under MXNET_TPU_USE_PALLAS): the BN affine and the activation run
-    on the conv accumulator in VMEM instead of round-tripping HBM. Build it
-    from a trained (Conv2D, BatchNorm) pair with `from_layers`; training
-    keeps the composed layers (batch statistics need the conv output).
-
-    Layout NHWC, stride 1, SAME pad — the shape of every interior ResNet
-    block conv (ROOFLINE.md fusion project).
-    """
-
-    def __init__(self, weight, scale, shift, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.weight = self.params.get("weight", shape=weight.shape,
-                                          grad_req="null")
-            self.scale = self.params.get("scale", shape=scale.shape,
-                                         grad_req="null")
-            self.shift = self.params.get("shift", shape=shift.shape,
-                                         grad_req="null")
-        for p, v in ((self.weight, weight), (self.scale, scale),
-                     (self.shift, shift)):
-            p.initialize(ctx=v.context)
-            p.set_data(v)
-
-    @classmethod
-    def from_layers(cls, conv, bn, eps=None, **kwargs):
-        """Fold a Conv2D (layout NHWC, 3x3, stride 1, pad 1, no bias) and
-        a trained BatchNorm into one fused block. The preconditions are
-        enforced — a silent fold of an unsupported conv would produce
-        wrong numerics, not an error."""
-        from ....ops.fused_conv import fold_bn_params
-        kw = conv._kwargs
-        if kw.get("layout") != "NHWC":
-            raise ValueError("FusedConvBNReLU.from_layers: layout must be "
-                             "NHWC, got %r" % kw.get("layout"))
-        if tuple(kw.get("kernel", ())) != (3, 3) or \
-                tuple(kw.get("stride", (1, 1))) != (1, 1) or \
-                tuple(kw.get("pad", (0, 0))) != (1, 1):
-            raise ValueError(
-                "FusedConvBNReLU.from_layers needs a 3x3/stride-1/pad-1 "
-                "conv, got kernel=%s stride=%s pad=%s"
-                % (kw.get("kernel"), kw.get("stride"), kw.get("pad")))
-        if tuple(kw.get("dilate", (1, 1))) != (1, 1) or \
-                kw.get("num_group", 1) != 1:
-            raise ValueError(
-                "FusedConvBNReLU.from_layers: dilated/grouped convs are "
-                "not folded (dilate=%s num_group=%s)"
-                % (kw.get("dilate"), kw.get("num_group")))
-        if not kw.get("no_bias", False):
-            raise ValueError("FusedConvBNReLU.from_layers: conv bias is "
-                             "not folded; build the conv with "
-                             "use_bias=False")
-        w = conv.weight.data()
-        # Conv2D NHWC keeps weights (Cout, kh, kw, Cin) — to HWIO
-        w_hwio = w.data_jax.transpose(1, 2, 3, 0)
-        scale, shift = fold_bn_params(
-            bn.gamma.data().data_jax, bn.beta.data().data_jax,
-            bn.running_mean.data().data_jax, bn.running_var.data().data_jax,
-            eps=eps if eps is not None else bn._kwargs.get("eps", 1e-3))
-        from ....ndarray.ndarray import from_jax
-        return cls(from_jax(w_hwio), from_jax(scale), from_jax(shift),
-                   **kwargs)
-
-    def hybrid_forward(self, F, x, residual=None, weight=None, scale=None,
-                       shift=None):
-        args = [x, weight, scale, shift]
-        if residual is not None:
-            args.append(residual)
-        return F.contrib.conv_bn_relu(*args)
-
-
-class FusedConvBNReLUTrain(HybridBlock):
-    """TRAINABLE fused conv3x3 + BatchNorm + ReLU (+ residual), NHWC,
-    stride 1, SAME pad — the training-form counterpart of FusedConvBNReLU
-    (round-5 ROOFLINE task: the reference's cuDNN fused conv-bias-act
-    serves training too, SURVEY §2.1).
-
-    Training rides `_contrib_conv_bn_relu_train`: the batch statistics are
-    computed in the conv kernel's epilogue from the f32 VMEM accumulator
-    (the stats reduction never re-reads the conv output from HBM), then
-    one normalize+relu pass; the BACKWARD is the ISSUE 10 fused Pallas
-    kernel (`_kernel_train_bwd`): conv_out/dy stream through VMEM, xhat
-    and the relu mask are recomputed in-register, and the dgamma/dbeta
-    reductions + dconv (+dres) tiles all come out of ONE pallas_call —
-    this block gains it for free through the op's custom-vjp, so the
-    `MXNET_TPU_FUSED_CONVBN=1` headline resnet50 trains on it end to end.
-    Inference folds the running stats and takes the
-    `_contrib_conv_bn_relu` inference kernel.
-
-    Drop-in for a Conv2D(3x3, NHWC, no bias) -> BatchNorm -> relu chain;
-    call as `block(x)` or `block(x, residual)`.
-    """
-
-    def __init__(self, channels, in_channels, momentum=0.9, epsilon=1e-3,
-                 weight_initializer="xavier", **kwargs):
-        super().__init__(**kwargs)
-        self._momentum = momentum
-        self._epsilon = epsilon
-        with self.name_scope():
-            self.weight = self.params.get(
-                "weight", shape=(3, 3, in_channels, channels),
-                init=weight_initializer, allow_deferred_init=False)
-            self.gamma = self.params.get("gamma", shape=(channels,),
-                                         init="ones")
-            self.beta = self.params.get("beta", shape=(channels,),
-                                        init="zeros")
-            self.running_mean = self.params.get(
-                "running_mean", grad_req="null", shape=(channels,),
-                init="zeros", differentiable=False)
-            self.running_var = self.params.get(
-                "running_var", grad_req="null", shape=(channels,),
-                init="ones", differentiable=False)
-
-    def cast(self, dtype):
-        # BN params/stats stay fp32 (reference AMP keeps BatchNorm fp32)
-        import numpy as _onp
-        try:
-            name = _onp.dtype(dtype).name
-        except TypeError:
-            name = str(dtype)
-        if name in ("float16", "bfloat16"):
-            # cast only the conv weight; leave gamma/beta/stats fp32
-            self.weight.cast(dtype)
-            return
-        super().cast(dtype)
-
-    def hybrid_forward(self, F, x, residual=None, weight=None, gamma=None,
-                       beta=None, running_mean=None, running_var=None):
-        from .... import autograd as _ag
-        from ...block import record_aux_update
-        if not _ag.is_training():
-            from ....ops.fused_conv import fold_bn_params
-            scale, shift = fold_bn_params(
-                gamma._read(), beta._read(), running_mean._read(),
-                running_var._read(), eps=self._epsilon)
-            from ....ndarray.ndarray import from_jax
-            args = [x, weight, from_jax(scale), from_jax(shift)]
-            if residual is not None:
-                args.append(residual)
-            return F.contrib.conv_bn_relu(*args)
-        args = [x, weight, gamma, beta]
-        if residual is not None:
-            args.append(residual)
-        out, mean, var = F.contrib.conv_bn_relu_train(*args,
-                                                      eps=self._epsilon)
-        m = self._momentum
-        record_aux_update(
-            running_mean, (running_mean._read() * m +
-                           mean._read().astype(running_mean.dtype) *
-                           (1 - m)))
-        record_aux_update(
-            running_var, (running_var._read() * m +
-                          var._read().astype(running_var.dtype) * (1 - m)))
-        return out

@@ -706,8 +706,8 @@ class FusedTrainStep:
             self._programs[repr(in_fmt)] = prog
             if _telem.ENABLED:
                 # ISSUE 10 dispatch observability: Pallas call sites (the
-                # fused conv fwd/bwd) count ops.pallas.dispatch while the
-                # first call TRACES this program — the delta across the
+                # flash attention kernels) count ops.pallas.dispatch while
+                # the first call TRACES this program — the delta across the
                 # trace is the number of kernels fused into the step
                 pallas_before = _telem.counter("ops.pallas.dispatch").value
         jitted, holder = prog

@@ -5,8 +5,7 @@ self-attention op surface the reference exposed for it
 `_contrib_interleaved_matmul_selfatt_qk` / `_valatt`; GluonNLP's
 BERTEncoder consumed exactly these ops in TNC layout).
 
-The functional twin lives in `mxnet_tpu/models/bert.py` (drives the
-`BENCH=bert` headline); this module is the user-facing HybridBlock stack:
+The functional twin lives in `mxnet_tpu/models/bert.py`; this module is the user-facing HybridBlock stack:
 hybridize() compiles each block through the CachedOp≙jax.jit path, and the
 whole model works with `gluon.Trainer`/`FusedTrainStep`.
 

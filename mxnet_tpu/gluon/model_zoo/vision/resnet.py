@@ -24,28 +24,6 @@ def _bn_axis(layout):
     return 1 if layout == "NCHW" else -1
 
 
-def _use_fused_convbn(layout, stride, in_channels):
-    """MXNET_TPU_FUSED_CONVBN=1 swaps interior conv3x3+BN+ReLU triples
-    for gluon.contrib.cnn.FusedConvBNReLUTrain (NHWC, stride 1, known
-    in_channels only — the fused kernel's shape). Off by default: the
-    fused block's parameter names differ from the composed triple, so
-    checkpoints are not interchangeable across the gate."""
-    import os
-    return (os.environ.get("MXNET_TPU_FUSED_CONVBN", "0") == "1"
-            and layout == "NHWC" and stride == 1 and in_channels > 0)
-
-
-def _add_conv3x3_bn_relu(body, channels, stride, in_channels, layout):
-    """conv3x3 -> BN -> relu, fused when the gate + shape allow."""
-    if _use_fused_convbn(layout, stride, in_channels):
-        from ...contrib.cnn import FusedConvBNReLUTrain
-        body.add(FusedConvBNReLUTrain(channels, in_channels=in_channels))
-    else:
-        body.add(_conv3x3(channels, stride, in_channels, layout))
-        body.add(nn.BatchNorm(axis=_bn_axis(layout)))
-        body.add(nn.Activation("relu"))
-
-
 class BasicBlockV1(HybridBlock):
     """reference: resnet.py (BasicBlockV1)."""
 
@@ -90,8 +68,9 @@ class BottleneckV1(HybridBlock):
                                 layout=layout))
         self.body.add(nn.BatchNorm(axis=ax))
         self.body.add(nn.Activation("relu"))
-        _add_conv3x3_bn_relu(self.body, channels // 4, 1, channels // 4,
-                             layout)
+        self.body.add(_conv3x3(channels // 4, 1, channels // 4, layout))
+        self.body.add(nn.BatchNorm(axis=ax))
+        self.body.add(nn.Activation("relu"))
         self.body.add(nn.Conv2D(channels, kernel_size=1, strides=1,
                                 layout=layout))
         self.body.add(nn.BatchNorm(axis=ax))

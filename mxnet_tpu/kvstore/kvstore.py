@@ -397,7 +397,7 @@ class KVStoreLocal(KVStore):
         each retried AS A UNIT in store-replace mode with the existing
         `kvstore.push` fault sites firing per key. Bucket bytes count
         TOUCHED rows, not table rows; `comm.sparse.*` counters feed
-        `parse_log --sparse` and `BENCH=sparse`."""
+        `parse_log --sparse`."""
         from ..resilience import faults as _faults
         from ..resilience.retry import call_with_retry
         use_faults = _faults.active_plan() is not None

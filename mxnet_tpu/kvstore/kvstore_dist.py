@@ -387,8 +387,7 @@ class KVStoreDist(KVStoreLocal):
     def _sparse_dense_push(self):
         """The densified baseline (full-vocab mask allreduce + dense
         allreduce over the union rows), kept behind
-        ``MXNET_TPU_SPARSE_DENSE_PUSH=1`` for A/B benchmarking — the
-        `BENCH=sparse` baseline leg."""
+        ``MXNET_TPU_SPARSE_DENSE_PUSH=1`` for A/B comparison."""
         import os
         return os.environ.get("MXNET_TPU_SPARSE_DENSE_PUSH", "0") == "1"
 

@@ -74,7 +74,7 @@ CONFIGS = {
                              rope_theta=500000.0, max_seq_len=8192,
                              embed_onehot=True),
     # 8B layer shapes at reduced depth/vocab/context — validates the
-    # SCALE.md v5e-64 program on a host-CPU virtual mesh (every layer
+    # v5e-64 plan's program on a host-CPU virtual mesh (every layer
     # dimension identical to llama3_8b; only depth-like axes shrink).
     "llama3_8b_dry": LlamaConfig(vocab_size=8192, dim=4096, n_layers=2,
                                  n_heads=32, n_kv_heads=8, hidden_dim=14336,

@@ -2,8 +2,8 @@
 
 The API-compatible Gluon model zoo (`mxnet_tpu.gluon.model_zoo.vision`,
 mirroring python/mxnet/gluon/model_zoo/vision/resnet.py in the reference)
-remains the user-facing surface; this module is the performance path used by
-`bench.py` (BASELINE.md headline: ResNet-50 images/sec/chip):
+remains the user-facing surface, and the one the benchmark's ResNet cells
+drive; this module is the functional twin:
 
   * NHWC layout — TPU convolutions want feature-minor;
   * bf16 activations/weights, fp32 BatchNorm statistics;

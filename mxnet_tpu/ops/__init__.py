@@ -16,7 +16,6 @@ from . import extended     # noqa: F401  linalg_* / multi_* / LRN / SVM / ST
 from . import contrib_vision  # noqa: F401  box_nms/ROIAlign/resize/adaptive
 from . import image_ops    # noqa: F401  _image_* family (nd.image/sym.image)
 from . import grad_rules   # noqa: F401  FGradient-style vjp rules (hot ops)
-from . import fused_conv   # noqa: F401  Pallas conv+BN+ReLU fusion
 from . import fused_optimizer  # noqa: F401  Pallas fused optimizer kernels
 from . import sparse_ops   # noqa: F401  Pallas sparse segment-sum scatter-add
 from . import shape_hints  # noqa: F401  FInferShape-style param-shape hints

@@ -79,13 +79,12 @@ def _interpret():
 def use_pallas_flat():
     """Is the Pallas optimizer path requested? Interpreter runs always take
     it (that is what they test); compiled runs need the TPU backend plus
-    the MXNET_TPU_USE_PALLAS opt-in — same gate shape as the fused-conv
-    training kernels."""
+    the MXNET_TPU_USE_PALLAS opt-in."""
     if _interpret():
         return True
     if jax.default_backend() != "tpu":
         return False
-    return os.environ.get("MXNET_TPU_USE_PALLAS", "0") == "1"
+    return _pstats.use_pallas(False)
 
 
 def _flat_geometry(n):

@@ -167,8 +167,11 @@ def _fc_grad(cot, out, raw_args, kwargs, nd_positions):
     x = data.reshape(data.shape[0], -1) if (flatten and data.ndim > 2) \
         else data
     dx = (cot @ weight).reshape(data.shape).astype(data.dtype)
-    dw = (cot.reshape(-1, cot.shape[-1]).T
-          @ x.reshape(-1, x.shape[-1])).astype(weight.dtype)
+    # contracted over the rows as they lie, the product jax's own transpose
+    # rule writes: `cot.T @ x` would run the transpose as a program of its own
+    dw = jax.lax.dot_general(
+        cot.reshape(-1, cot.shape[-1]), x.reshape(-1, x.shape[-1]),
+        (((0,), (0,)), ((), ()))).astype(weight.dtype)
     outs = [dx, dw]
     if len(nd_positions) > 2:
         red = tuple(range(cot.ndim - 1))

@@ -10,6 +10,8 @@ selection collapses into the compiler).
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as _np
 
 import jax
@@ -32,7 +34,15 @@ def _pair(v, n):
 # ---------------------------------------------------------------------------
 # FullyConnected (reference: src/operator/nn/fully_connected.cc)
 # ---------------------------------------------------------------------------
+# One program eagerly too: op by op, `transpose(weight)` ran as a program of
+# its own and the product then read the other layout, which XLA's CPU rounds
+# differently from the product it makes of the pair under a trace, so a dense
+# graph compiled whole (`compiler/`) was not the eager one to the bit.
+# `inline=True`: under a trace the body is traced in place, and a step's
+# lowered text is what it was.
 @register("FullyConnected")
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("num_hidden", "no_bias", "flatten"))
 def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False,
                      flatten=True):
     """y = x W^T + b; weight is (num_hidden, in_units) like the reference."""

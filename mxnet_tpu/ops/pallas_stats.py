@@ -14,7 +14,7 @@ Every Pallas kernel call site in the ops layer reports through here:
   span (cat ``kernel``) so chrome traces show which stages ran fused.
 
 Counting context: eager call sites count once per call; sites inside a
-``custom_vjp``/``jit`` trace (the fused conv backward under a compiled train
+``custom_vjp``/``jit`` trace (the flash kernels under a compiled train
 step) count once per (re)trace — dispatches-per-program, not per step, the
 same convention as `engine.reassociate_bucketed`. ``parse_log --kernels``
 renders the table.
@@ -25,17 +25,27 @@ import contextlib
 import time
 
 __all__ = ["note_dispatch", "note_fallback", "kernel_span",
-           "compiler_params"]
+           "compiler_params", "use_pallas"]
 
 
 def compiler_params(semantics, vmem_limit_bytes=None):
     """Mosaic compiler params carrying the grid's `dimension_semantics`
     and, where a kernel's tiles outgrow Mosaic's default, the VMEM it may
-    use. Shared by fused_conv, fused_optimizer, sparse_ops and
+    use. Shared by fused_optimizer, sparse_ops and
     parallel/flash_attention."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=vmem_limit_bytes)
+
+
+def use_pallas(unset):
+    """MXNET_TPU_USE_PALLAS, read here and nowhere else. `unset` is the
+    caller's answer where the variable is not set, and the callers differ:
+    the registry's per-op `tpu_impl`s are on (True), the flat optimizer
+    and segment-sum kernels are opt-in (False). ROADMAP S7 decides whether
+    they should."""
+    from ..base import get_env
+    return get_env("MXNET_TPU_USE_PALLAS", unset)
 
 
 def note_dispatch(kernel):

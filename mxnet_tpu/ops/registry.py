@@ -73,8 +73,8 @@ class Operator:
 
     def best_fn(self, on_tpu):
         if on_tpu and self.tpu_fn is not None:
-            from ..base import get_env
-            if get_env("MXNET_TPU_USE_PALLAS"):
+            from .pallas_stats import use_pallas
+            if use_pallas(True):
                 return self.tpu_fn
         return self.fn
 

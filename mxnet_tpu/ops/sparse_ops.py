@@ -69,7 +69,7 @@ def use_pallas_sparse():
         return True
     if jax.default_backend() != "tpu":
         return False
-    return os.environ.get("MXNET_TPU_USE_PALLAS", "0") == "1"
+    return _pstats.use_pallas(False)
 
 
 # ---------------------------------------------------------------------------

@@ -94,8 +94,8 @@ def place_compile_cache():
     Where JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and nothing
     is set here; otherwise the cache goes to `.jax_cache/` at the root of
     the checkout. Entry points that compile whole models (chip_smoke.py,
-    bench.py) call this before their first compile; `import mxnet_tpu`
-    does not."""
+    benchmark/run.py) call this before their first compile; `import
+    mxnet_tpu` does not."""
     import os
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:

@@ -13,8 +13,7 @@ decoding is configured — every signature compiled at warm-up, so
 admission never retraces mid-traffic). `ReplicaGroup` supervises N
 replicas over one shared queue.
 
-Serving v2 throughput layers, all attributable in telemetry and
-`BENCH=serve`: burst arrivals prefill TOGETHER in chunk windows
+Serving v2 throughput layers, all attributable in telemetry: burst arrivals prefill TOGETHER in chunk windows
 interleaved with decode (``serve.prefill_chunks``); N users of one
 system prompt share its KV blocks by refcount with copy-on-write at the
 divergence block (``serve.prefix.*``); a small draft model multiplies

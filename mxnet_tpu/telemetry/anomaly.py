@@ -20,8 +20,8 @@ rest of the observability plane (exporter, flight recorder, mxtop,
 
 The same rolling windows answer the latency questions a scrape cannot
 (histogram buckets are too coarse for tails): `quantiles(site)` returns
-p50/p99 over the window, exported by the `/snapshot` endpoint, the JSONL
-stream, and `bench.py` rows.
+p50/p99 over the window, exported by the `/snapshot` endpoint and the
+JSONL stream.
 
 Everything here is behind the telemetry gate: callers route through
 `telemetry.step_event`, which is a no-op when `MXNET_TPU_TELEMETRY=0`.

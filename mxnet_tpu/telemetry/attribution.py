@@ -35,7 +35,7 @@ region where collectives are in flight), the fraction the host spent OFF
 the collective path, i.e. free to overlap pack/compute against in-flight
 comm. Per-parameter sync (``MXNET_TPU_COMM_BUCKET_MB=0``) serializes the
 host through N launches and drives the fraction down; bucketing frees the
-phase and drives it up — the 0-vs-default delta `BENCH=comm` reports.
+phase and drives it up.
 
 Surfaces: `telemetry.overlap_report()` (full per-step report),
 ``parse_log --overlap`` (same table from a chrome trace dump, stdlib

@@ -41,7 +41,7 @@ Every scope exports a ``memory.scope.<name>.bytes`` gauge (→ `/metrics`,
 `/snapshot`, the JSONL stream); `format_scopes()` renders the top-scopes
 breakdown that OOM / `Overloaded(kv_exhausted)` / `StallError`
 post-mortems embed; `check_budget()` validates a run against a declared
-per-chip budget (the SCALE.md acceptance seam for ROADMAP item #3).
+per-chip budget (what `tests/test_scale_8b.py` asserts of the 8B dryrun).
 
 Gating: inert under ``MXNET_TPU_TELEMETRY=0`` (no state, no gauges) and
 under ``MXNET_TPU_LEDGER=0`` (the bench A/B lever — telemetry stays up,
@@ -276,11 +276,11 @@ def last_reconcile():
 
 # ------------------------------------------------------------------- budget
 def check_budget(budget_bytes_per_chip, residual_tolerance=0.25):
-    """Validate the run against a declared per-chip HBM budget (the
-    SCALE.md acceptance seam): reconciles, then checks that (a) the
-    per-chip device total fits the budget and (b) the per-scope breakdown
-    sums to within ``residual_tolerance`` (a fraction of the device
-    total) — i.e. the ledger actually explains the memory it budgets.
+    """Validate the run against a declared per-chip HBM budget:
+    reconciles, then checks that (a) the per-chip device total fits the
+    budget and (b) the per-scope breakdown sums to within
+    ``residual_tolerance`` (a fraction of the device total) — i.e. the
+    ledger actually explains the memory it budgets.
 
     Returns ``{ok, budget_bytes_per_chip, per_chip_bytes, device_bytes,
     scoped_bytes, residual_bytes, residual_frac, device_count, source,

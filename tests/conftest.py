@@ -87,3 +87,22 @@ def _pallas_interpret_mode(request, monkeypatch):
         else:
             monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
     yield
+
+
+@pytest.fixture
+def resnet18_grad_shapes():
+    """resnet18 (classes=1000) parameter shapes: conv1 + 8 basic blocks
+    (2 convs + 2 BN pairs each, stage-transition downsamples) + fc — the
+    62-tensor gradient set the acceptance tests of the bucketed comm engine
+    (tests/test_comm_bucket.py) and of ZeRO (tests/test_zero.py) sync."""
+    shapes = [(64, 3, 7, 7), (64,), (64,)]
+    widths = [(64, 64), (64, 128), (128, 256), (256, 512)]
+    for cin, cout in widths:
+        for blk in range(2):
+            first_in = cin if blk == 0 else cout
+            shapes += [(cout, first_in, 3, 3), (cout,), (cout,),
+                       (cout, cout, 3, 3), (cout,), (cout,)]
+            if blk == 0 and cin != cout:
+                shapes += [(cout, cin, 1, 1), (cout,), (cout,)]
+    shapes += [(1000, 512), (1000,)]
+    return shapes

@@ -42,20 +42,6 @@ def test_smoke_fails_when_a_mesh_could_fall_back_to_the_host():
     assert "MXNET_MESH_HOST_FALLBACK" in r.stderr
 
 
-@no_accelerator
-@pytest.mark.parametrize("mode", ["gluon", "startup"])
-def test_bench_fails_on_a_cpu_it_was_not_asked_for(mode):
-    # with JAX_PLATFORMS unset and no chip, jax hands back the cpu devices
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    env["BENCH"] = mode     # startup: the child that owns the device fails
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                       env=env, cwd=REPO, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode != 0
-    assert "platform 'cpu'" in r.stderr
-    assert r.stdout == ""        # no row
-
-
 @pytest.mark.slow
 def test_smoke_rehearsal_passes_on_the_cpu():
     # conftest's XLA_FLAGS give the child its virtual devices for the mesh

@@ -381,17 +381,9 @@ def test_bucketed_pushpull_keeps_pull_fault_site():
 # resnet18-sized gradient set
 # ===========================================================================
 
-def _resnet18_grad_shapes():
-    """The bench's 62-tensor gradient set — imported, not duplicated, so
-    bench and acceptance test always sync the same model."""
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    from bench import resnet18_grad_shapes
-    return resnet18_grad_shapes()
-
-
-def test_resnet18_sized_sync_collectives_below_param_count():
-    shapes = _resnet18_grad_shapes()
+def test_resnet18_sized_sync_collectives_below_param_count(
+        resnet18_grad_shapes):
+    shapes = resnet18_grad_shapes
     assert len(shapes) == 62
 
     def run(mb):

@@ -5,10 +5,8 @@ residual math, per-chip budget checks, per-program static footprints on
 both cold compile and warm AOT-cache restore), the on-demand profiling
 endpoint (capture + rate limiting + full inertness under
 ``MXNET_TPU_TELEMETRY=0``), the serve KV byte gauges and the ledger
-breakdown carried by `Overloaded(kv_exhausted)` / `StallError`, the
-bench-history store (`tools/benchdb.py`) and the perf-regression gate
-(`tools/check_bench.py --ci`), and the tracelint cleanliness of every
-new module.
+breakdown carried by `Overloaded(kv_exhausted)` / `StallError`, and the
+tracelint cleanliness of every new module.
 """
 import json
 import os
@@ -26,9 +24,6 @@ from mxnet_tpu.telemetry import export, ledger, profiling
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
-sys.path.insert(0, TOOLS)
-import benchdb  # noqa: E402
-import check_bench  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -347,113 +342,6 @@ print("INERT_OK")
     assert "INERT_OK" in r.stdout
 
 
-# ----------------------------------------------------------------- benchdb
-def test_fingerprint_stable_and_distinct():
-    fp1 = benchdb.fingerprint(backend="cpu", device_count=1)
-    fp2 = benchdb.fingerprint(backend="cpu", device_count=1)
-    assert benchdb.fingerprint_id(fp1) == benchdb.fingerprint_id(fp2)
-    fp3 = benchdb.fingerprint(backend="tpu", device_count=64)
-    assert benchdb.fingerprint_id(fp1) != benchdb.fingerprint_id(fp3)
-    # a silent cpu fallback is a DIFFERENT environment, not a regression
-    fp4 = benchdb.fingerprint(backend="cpu", device_count=1,
-                              cpu_fallback=True)
-    assert benchdb.fingerprint_id(fp1) != benchdb.fingerprint_id(fp4)
-
-
-def test_append_load_roundtrip_and_bad_lines(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    benchdb.append({"metric": "m", "value": 1.0}, path)
-    benchdb.append({"metric": "m", "value": 2.0}, path)
-    with open(path, "a") as f:
-        f.write("{truncated garbage\n")
-    rows = benchdb.load(path)
-    assert [r["value"] for r in rows] == [1.0, 2.0]
-
-
-def test_history_path_env_override(tmp_path, monkeypatch):
-    monkeypatch.setenv("MXNET_TPU_BENCH_HISTORY", str(tmp_path / "h.jsonl"))
-    assert benchdb.history_path() == str(tmp_path / "h.jsonl")
-
-
-# ------------------------------------------------------------- check_bench
-def test_direction_heuristics():
-    assert check_bench.direction_for("resnet50_img_per_sec") == "up"
-    assert check_bench.direction_for("serve_tok_per_sec") == "up"
-    assert check_bench.direction_for("obs_scrape_p50_us") == "down"
-    assert check_bench.direction_for("startup_warm_s") == "down"
-
-
-def _hist_rows(metric, values, fpid):
-    return [{"metric": metric, "value": v, "fingerprint_id": fpid}
-            for v in values]
-
-
-def test_check_passes_healthy_fails_regressed():
-    rows = _hist_rows("x_tok_per_sec", [100, 101, 99, 100], "fp1")
-    rep = check_bench.check(rows)
-    assert rep["ok"] and not rep["regressions"]
-    rows.append({"metric": "x_tok_per_sec", "value": 80,
-                 "fingerprint_id": "fp1"})   # -20% vs median 100
-    rep = check_bench.check(rows)
-    assert not rep["ok"]
-    assert rep["regressions"][0]["delta_pct"] == -20.0
-    # latency direction: +20% on a _us metric is also a regression
-    rows2 = _hist_rows("y_p50_us", [10, 10, 10, 12.5], "fp1")
-    rep2 = check_bench.check(rows2)
-    assert not rep2["ok"]
-
-
-def test_check_skips_cross_fingerprint_and_short_series():
-    rows = (_hist_rows("m_tok_per_sec", [100, 100, 100], "fast-chip")
-            + _hist_rows("m_tok_per_sec", [5], "laptop"))
-    rep = check_bench.check(rows)
-    # the laptop row is never compared against the fast-chip baseline
-    assert rep["ok"]
-    assert rep["skipped"]["fingerprint_mismatch"] == 1
-    assert rep["skipped"]["insufficient_history"] == 1
-
-
-def test_per_metric_tolerance_override():
-    rows = _hist_rows("noisy_tok_per_sec", [100, 100, 100, 85], "fp1")
-    assert not check_bench.check(rows)["ok"]
-    assert check_bench.check(rows, tolerances={"noisy": 0.25})["ok"]
-
-
-def test_check_bench_ci_subprocess(tmp_path):
-    """The gate as CI runs it: exit 0 on healthy history, exit 1 after an
-    injected 20% regression, exit 2 on an empty history."""
-    script = os.path.join(TOOLS, "check_bench.py")
-    hist = tmp_path / "hist.jsonl"
-    fp = benchdb.fingerprint(backend="cpu", device_count=1)
-    fpid = benchdb.fingerprint_id(fp)
-    for v in (100, 102, 99, 101):
-        benchdb.append({"metric": "gate_tok_per_sec", "value": v,
-                        "fingerprint_id": fpid}, str(hist))
-    run = lambda *a: subprocess.run(  # noqa: E731
-        [sys.executable, script, "--ci", *a], capture_output=True,
-        text=True, timeout=120)
-    r = run(str(hist))
-    assert r.returncode == 0, r.stdout + r.stderr
-    benchdb.append({"metric": "gate_tok_per_sec", "value": 80,
-                    "fingerprint_id": fpid}, str(hist))
-    r = run(str(hist))
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout
-    r = run(str(tmp_path / "missing.jsonl"))
-    assert r.returncode == 2
-
-
-def test_check_bench_ci_passes_on_committed_history():
-    """Acceptance: the gate exits 0 against the repo's real committed
-    bench history (run alongside run_tracelint.sh --ci)."""
-    hist = os.path.join(REPO, "bench_history.jsonl")
-    assert os.path.exists(hist), "committed bench_history.jsonl missing"
-    r = subprocess.run(
-        [sys.executable, os.path.join(TOOLS, "check_bench.py"), "--ci",
-         hist], capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
 def test_serve_warmup_programs_have_footprints(tmp_path, monkeypatch):
     """Acceptance: every serve executable (chunked prefill, decode, CoW)
     records a memory_analysis footprint at warmup — on the cold compile
@@ -513,14 +401,12 @@ def test_parse_log_mem_mode(tmp_path):
 # -------------------------------------------------------------------- lint
 @pytest.mark.lint
 def test_new_modules_tracelint_clean_zero_suppressions():
-    """ledger/profiling/benchdb/check_bench are tracelint-clean with ZERO
-    suppression markers — observability code meets the bar it enforces."""
+    """ledger/profiling are tracelint-clean with ZERO suppression markers —
+    observability code meets the bar it enforces."""
     from mxnet_tpu import analysis
     paths = [
         os.path.join(REPO, "mxnet_tpu", "telemetry", "ledger.py"),
         os.path.join(REPO, "mxnet_tpu", "telemetry", "profiling.py"),
-        os.path.join(TOOLS, "benchdb.py"),
-        os.path.join(TOOLS, "check_bench.py"),
     ]
     findings = analysis.lint_paths(paths)
     assert not findings, "\n".join(f.format() for f in findings)

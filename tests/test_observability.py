@@ -715,7 +715,14 @@ def test_stall_post_mortem_embeds_flight_ring():
 def test_runner_stall_flight_ledger(tmp_path):
     """End-to-end: a fused-step run that hangs produces a StallError whose
     flight ring shows the steps that led up to it, and the recovered run's
-    ledger carries the restore event."""
+    ledger carries the restore event.
+
+    The deadline is 5 s and not the 0.5 s it was: the first guarded step
+    compiles the program (0.46 s on an idle machine, 0.15 s again after
+    the restore), so six workers' load made a second stall and two
+    restarts of one. A load that stretches that compile tenfold stretches
+    the whole suite past its time limit first; the test waits the
+    deadline out once, inside the injected hang."""
     mx.random.seed(42)
     net = nn.HybridSequential()
     with net.name_scope():
@@ -731,7 +738,7 @@ def test_runner_stall_flight_ledger(tmp_path):
     with faults.inject("train.step:hang:3:30"):
         runner = rz.ResilientRunner.for_fused_step(
             fused, batch_fn, ckpt_dir=str(tmp_path / "ck"), ckpt_every=1,
-            max_restarts=2, step_deadline_s=0.5)
+            max_restarts=2, step_deadline_s=5.0)
         report = runner.run(4)
     assert report.restarts == 1
     events = [e for r in telemetry.flight_records()

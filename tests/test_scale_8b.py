@@ -1,4 +1,5 @@
-"""SCALE.md validation: the Llama-3-8B sharded program at reduced depth.
+"""The Llama-3-8B sharded program at reduced depth (the v5e-64 plan:
+`create_mesh(data=4, fsdp=4, model=4)`, PERF.md "Before the chip").
 
 reference: BASELINE.json configs[4] (8B pretraining on v5e-64). The dry
 config keeps every LAYER dimension of the 8B (d_model 4096, 32/8 GQA
@@ -40,7 +41,7 @@ assert 6.0 < float(loss) < 12.0, float(loss)
 leaf = jax.tree_util.tree_leaves(p)[0]
 assert len(leaf.sharding.device_set) == 8
 
-# HBM-ledger budget check (SCALE.md: 16 GB/chip on v5e-64, ~12.9 GB/chip
+# HBM-ledger budget check (the plan: 16 GB/chip on v5e-64, ~12.9 GB/chip
 # planned): the dryrun must fit the declared budget AND the per-scope
 # breakdown must explain the device bytes. CPU live_arrays counts host
 # copies (llama_init's unsharded tree is still live), so the residual

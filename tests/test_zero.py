@@ -77,7 +77,12 @@ class _FakeComm:
             fleet.box.setdefault((tag, spec.index), {})[self.rank] = \
                 np.asarray(value)
         fleet.barrier.wait()
-        parts = fleet.box[(tag, spec.index)]
+        # this rank's own copy, taken between the barriers: the mailbox is
+        # keyed by (tag, bucket) and not by call, so a rank that has left
+        # the second barrier writes its NEXT contribution (the momentum
+        # gathered for a checkpoint right after the weights, the next
+        # step's gradient) into the very dict a slower rank still sums
+        parts = dict(fleet.box[(tag, spec.index)])
         fleet.barrier.wait()
         return parts
 
@@ -193,13 +198,6 @@ def test_pack_unpack_flat_padded_roundtrip():
 # through injectable single-process collectives
 # ===========================================================================
 
-def _resnet18_grad_shapes():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    from bench import resnet18_grad_shapes
-    return resnet18_grad_shapes()
-
-
 def _replicated_phases(optname, shapes, init_w, phases, **opt_kw):
     """Replicated baseline over a SEQUENCE of (grads_per_rank, steps)
     phases with ONE continuously-carried updater (momentum/moments survive
@@ -256,12 +254,13 @@ _ADAM_DYADIC = {"learning_rate": 0.125, "beta1": 0.5, "beta2": 0.5,
     ("sgd", _SGD_DYADIC),
     ("adam", _ADAM_DYADIC),
 ])
-def test_zero_resnet18_sized_parity_injectable_fleet(optname, opt_kw):
+def test_zero_resnet18_sized_parity_injectable_fleet(
+        optname, opt_kw, resnet18_grad_shapes):
     """ISSUE 9 acceptance: final params bit-identical to the replicated
     update on the resnet18-sized 62-tensor param set, world=2, simulated
     on one process (dyadic lr keeps every fp32 step exactly representable;
     the fake fleet and the baseline sum ranks in the same order)."""
-    shapes = _resnet18_grad_shapes()
+    shapes = resnet18_grad_shapes
     assert len(shapes) == 62
     world, steps = 2, 2
     rng = np.random.RandomState(0)
@@ -355,14 +354,14 @@ _LAMB_KW = {"learning_rate": 0.01, "beta1": 0.9, "beta2": 0.999,
             "epsilon": 1e-6, "rescale_grad": 1.0}
 
 
-def test_zero_lamb_resnet18_sized_parity_vs_eager():
+def test_zero_lamb_resnet18_sized_parity_vs_eager(resnet18_grad_shapes):
     """ISSUE 10 satellite: ZeRO LAMB (two-pass flat update with
     per-segment norms completed by ONE tiny all-reduce) vs the eager
     per-param LAMB updater on the resnet18-sized 62-tensor key set,
     world=2. The flat path accumulates each parameter's ‖w‖/‖g‖ in shard
     segments rather than `jnp.linalg.norm`'s single reduce, so parity is
     fp32-round-off (documented tolerance), not bitwise."""
-    shapes = _resnet18_grad_shapes()
+    shapes = resnet18_grad_shapes
     assert len(shapes) == 62
     world, steps = 2, 2
     rng = np.random.RandomState(5)

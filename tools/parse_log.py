@@ -511,22 +511,9 @@ def parse_kernels(obj):
     fused (`ops.pallas.dispatch.<kernel>`), which calls fell back and WHY
     (`ops.pallas.fallback.<reason>` / `.<kernel>.<reason>`), how many
     kernels each compiled step program carries (`*.pallas_kernels`
-    gauges), and the fused-update latency histogram. Also accepts a
-    `BENCH=fused_bwd` / `BENCH=fused_opt` row (a dict with
-    bytes_fused/bytes_composed) and derives the traffic ratio.
+    gauges), and the fused-update latency histogram.
     Returns [(kind, name, value)] rows."""
     rows = []
-    if "bytes_fused" in obj or "bytes_composed" in obj:
-        bf, bc = obj.get("bytes_fused"), obj.get("bytes_composed")
-        rows.append(("bench", obj.get("metric", "?"), obj.get("value")))
-        rows.append(("bench", "vs_baseline", obj.get("vs_baseline")))
-        if bf is not None:
-            rows.append(("bench", "bytes_fused", bf))
-        if bc is not None:
-            rows.append(("bench", "bytes_composed", bc))
-        if bf and bc:
-            rows.append(("bench", "bytes_ratio", round(bf / bc, 4)))
-        return rows
     if "telemetry" in obj and isinstance(obj["telemetry"], dict):
         obj = obj["telemetry"]
     counters = obj.get("counters", {})
@@ -572,17 +559,10 @@ def parse_compile(obj):
     how many graphs lowered and compiled, what the graph passes removed,
     cache hits/misses/writes/corruption, which executors fell back to
     op-by-op dispatch and WHY, plus per-site compile counters and the
-    lower/compile latency histograms. Accepts a telemetry JSON dump, a
+    lower/compile latency histograms. Accepts a telemetry JSON dump or a
     `telemetry.compile_report()` dict (adds the recent-compiles ring
-    rows), or a `BENCH=startup` row. Returns [(kind, name, value)]."""
+    rows). Returns [(kind, name, value)]."""
     rows = []
-    if "startup_cold_s" in obj or obj.get("metric") == "startup_warm_s":
-        for k in ("metric", "value", "startup_cold_s", "startup_warm_s",
-                  "compile_count_cold", "compile_count_warm",
-                  "cache_hits_warm", "vs_baseline"):
-            if k in obj:
-                rows.append(("bench", k, obj[k]))
-        return rows
     ring = obj.get("recent_compiles")
     if "telemetry" in obj and isinstance(obj["telemetry"], dict):
         obj = obj["telemetry"]
@@ -1053,8 +1033,7 @@ def main():
     parser.add_argument("--kernels", action="store_true",
                         help="Pallas kernel-layer mode: dispatch/fallback "
                              "counts by kernel/reason, per-program fused-"
-                             "kernel gauges, fused-update latency, and "
-                             "bytes ratios from BENCH=fused_* rows")
+                             "kernel gauges and fused-update latency")
     parser.add_argument("--compile", dest="compile_mode",
                         action="store_true",
                         help="compiler mode: whole-graph lower/compile "
@@ -1062,8 +1041,7 @@ def main():
                              "hits/misses/corruption, op-by-op fallbacks "
                              "by reason, and the recent-compiles ring "
                              "from a telemetry JSON dump / "
-                             "telemetry.compile_report() / BENCH=startup "
-                             "row")
+                             "telemetry.compile_report()")
     parser.add_argument("--requests", dest="requests_mode",
                         action="store_true",
                         help="per-request trace mode: one row per served "

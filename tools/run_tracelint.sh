@@ -44,10 +44,7 @@ fi
 # tools/mxtop.py and tools/prebake_cache.py ride along: the dashboard
 # spawns no traces itself but shares the telemetry thread model the
 # TPU006 rule audits, and the pre-bake tool drives the serve warmup
-# path. tools/benchdb.py and tools/check_bench.py (the bench-history
-# store and the perf-regression gate) ride along too — stdlib-only, but
-# bench.py imports benchdb in-process so it must hold the same bar. The
-# package root covers mxnet_tpu/serve/ AND mxnet_tpu/compiler/
+# path. The package root covers mxnet_tpu/serve/ AND mxnet_tpu/compiler/
 # — the serving scheduler/replica threads are TPU006-clean with zero
 # suppressions (tests/test_serve.py asserts it under the lint marker),
 # and the whole-graph compiler package is tracelint-clean with zero
@@ -57,5 +54,5 @@ fi
 # must itself pass TPU009/TPU010 (its _GRAPH_LOCK is the one lock the
 # guard holds while checking, and nothing blocking happens under it).
 exec python -m mxnet_tpu.analysis mxnet_tpu tools/mxtop.py \
-    tools/prebake_cache.py tools/benchdb.py tools/check_bench.py \
+    tools/prebake_cache.py \
     --fail-on=error "$@"
