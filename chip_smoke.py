@@ -272,11 +272,14 @@ def kernel_phase(rehearse):
     require(os.environ.get("MXNET_FLASH_DISABLE", "0") != "1",
             "MXNET_FLASH_DISABLE is not set")
     (text, flash), compile_s = timed(lambda: build(False))
-    calls = text.count("@tpu_custom_call")
+    # counted in the compiled module: the layers share one lowering of each
+    # kernel (the entries are jitted), so the lowered text holds three
+    calls = flash.as_text().count('custom_call_target="tpu_custom_call"')
     # the interpreter lowers a kernel to plain HLO, so a rehearsal has none
     want = 0 if rehearse else 3 * cfg.n_layers
     require(calls == want and (rehearse or all(
-        k in text for k in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))),
+        'kernel_name = "%s"' % k in text
+        for k in ("flash_fwd", "flash_dq", "flash_dkv"))),
         "the lowered step holds %d Mosaic custom calls: forward, dq and "
         "dk/dv for each of %d layers" % (want, cfg.n_layers))
     print("  compile: %.2f s" % compile_s, flush=True)

@@ -25,7 +25,8 @@ from .sharding import (ShardingRules, LLAMA_RULES, BERT_RULES,
 from .collectives import (all_reduce, all_gather, reduce_scatter, ppermute,
                           barrier, allreduce_bench)
 from .dist import initialize, is_initialized, rank, num_workers
-from .flash_attention import flash_attention, flash_attention_bshd
+from .flash_attention import (flash_attention, flash_attention_bshd,
+                              flash_attention_packed, pack_qkv)
 from .ring_attention import ring_attention
 from .train_step import ShardedTrainStep
 from .checkpoint import (save_sharded, restore_sharded, latest_step,
@@ -37,6 +38,7 @@ __all__ = [
     "named_sharding", "shard_pytree", "replicate_pytree", "logical_to_spec",
     "all_reduce", "all_gather", "reduce_scatter", "ppermute", "barrier",
     "allreduce_bench", "initialize", "is_initialized", "rank", "num_workers",
-    "flash_attention", "flash_attention_bshd", "ring_attention",
+    "flash_attention", "flash_attention_bshd", "flash_attention_packed",
+    "pack_qkv", "ring_attention",
     "ShardedTrainStep",
 ]

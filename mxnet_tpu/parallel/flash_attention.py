@@ -11,7 +11,7 @@ saved logsumexp — dq/dk/dv each see O(S) HBM bytes instead of the S^2
 probability matrix the reference's backward streams.
 
 Layout: one algorithm, three kernels (`flash_fwd`, `flash_dq`,
-`flash_dkv`), two views of the operands.
+`flash_dkv`), three views of the operands.
 
 * `flash_attention_bshd` takes q, k, v as a projection leaves them,
   (B, S, H, D), and the kernels index the free (B, S, H*D) view: a block is
@@ -21,6 +21,16 @@ Layout: one algorithm, three kernels (`flash_fwd`, `flash_dq`,
   contraction over D = 64 costs anyway and keeps every load and store
   lane-dense. No transpose goes in or comes out, so autodiff has none to
   transpose back.
+* `flash_attention_packed` takes the result of ONE q|k|v projection,
+  (B, S, 3*H*D) in `pack_qkv`'s column order: the same blocks of the same
+  kernels, picked out of the one array by the index maps. Its backward
+  returns one cotangent of that shape: `flash_dq` writes q's blocks of it,
+  `flash_dkv` takes the array through `input_output_aliases` and fills k's
+  and v's (neighbours in the column order, one block of twice the lanes),
+  and both sum their blocks' columns on the way, which is the projection's
+  bias gradient. So the projection's dx and dW are one product each over
+  one plain array, and nothing passes over an activation between the
+  products and the kernels.
 * `flash_attention` keeps the (B, H, S, D) arguments; its view is
   (B*H, S, D), a block `block_b` of those rows with D on the lanes. Ring
   attention drives the same kernels per ring block through
@@ -71,7 +81,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.pallas_stats import compiler_params, note_dispatch, note_fallback
 
-__all__ = ["flash_attention", "flash_attention_bshd", "paged_attention",
+__all__ = ["flash_attention", "flash_attention_bshd",
+           "flash_attention_packed", "pack_qkv", "paged_attention",
            "paged_attention_chunk"]
 
 _NEG_INF = -1e30
@@ -79,8 +90,13 @@ _LANES = 128
 _MAX_BLOCK = 512        # sequence rows of q or of k in a block, at most
 _MAX_ROWS = 8           # batch rows in a block, at most
 _STEP_SCORES = 1 << 18  # score elements a grid step computes, at most
-_VMEM_LIMIT = 48 << 20  # what the kernels ask Mosaic for; every tile's
-#                         estimate stays under it (v5e has 128 MiB a core)
+_VMEM_LIMIT = 16 << 20  # what the kernels ask Mosaic for (its own default
+#                         on the v5e), more only for a tile estimated at
+#                         more. XLA keeps a step's activations in the core's
+#                         128 MiB of VMEM from one operation to the next and
+#                         has to clear what a Mosaic call asks for: asking
+#                         for 48 MiB cost BERT-base's step 1.9-4.9 ms of 98,
+#                         for 32 MiB 3.9 ms (PERF.md, PR 32)
 
 
 def _interpret():
@@ -131,11 +147,28 @@ def _seq_block(S):
     return min(sizes, key=lambda b: (-(-S // b) * (b + 64), -b))
 
 
+def _lane_group(H, Hkv, D):
+    """(lanes, heads) of a block of the (B, S, H*D) view, whole heads side
+    by side on a multiple of 128 lanes; or the reason there is none."""
+    if D % _LANES == 0:
+        return D, 1
+    if _LANES % D:
+        return "head_dim"
+    heads = _LANES // D
+    if H % heads:
+        return "head_group"
+    if H != Hkv:
+        # a q head and its k/v head sit on different lanes
+        return "gqa_lane_group"
+    return _LANES, heads
+
+
 def _choose_tile(view, B, H, Hkv, Sq, Sk, D, itemsize):
     """The tile for one call, from what the call can see; or the reason (a
     word, for the fallback counter) why this view cannot take the shape.
 
     `view` "bshd": operands (B, S, H*D), heads in 128-lane groups;
+    "packed": the same blocks, out of one (B, S, 3*H*D) array;
     "bhsd": operands (B*H, S, D)."""
     if D % 8 or H % Hkv:
         return "head_dim" if D % 8 else "gqa_group"
@@ -145,18 +178,10 @@ def _choose_tile(view, B, H, Hkv, Sq, Sk, D, itemsize):
         # own
         most_rows = _MAX_ROWS if H == Hkv else 1
     else:
-        if D % _LANES == 0:
-            lanes, heads = D, 1
-        elif _LANES % D:
-            return "head_dim"
-        else:
-            lanes, heads = _LANES, _LANES // D
-            if H % heads:
-                return "head_group"
-            if H != Hkv:
-                # a q head and its k/v head sit on different lanes
-                return "gqa_lane_group"
-        rows, most_rows = B, _MAX_ROWS
+        group = _lane_group(H, Hkv, D)
+        if isinstance(group, str):
+            return group
+        (lanes, heads), rows, most_rows = group, B, _MAX_ROWS
     bq, bk = _seq_block(Sq), _seq_block(Sk)
     most_rows = min(most_rows, max(1, _STEP_SCORES // (heads * bq * bk)))
     bb = max(b for b in range(1, most_rows + 1) if rows % b == 0)
@@ -169,7 +194,7 @@ def _choose_tile(view, B, H, Hkv, Sq, Sk, D, itemsize):
         + 2 * bb * heads * bq * _LANES * f32)
     live = heads * 6 * bq * bk * f32    # s, p, dp, ds and two operand copies
     return _Tile(lanes, heads, bb, bq, bk,
-                 (rows // bb) * (H // heads if view == "bshd" else 1)
+                 (rows // bb) * (1 if view == "bhsd" else H // heads)
                  * nq * nk, blocks + stats + scratch + live)
 
 
@@ -192,6 +217,15 @@ def _zero_pad_rows(x, start, seq):
     through the dots even where probabilities are exactly zero."""
     rows = lax.broadcasted_iota(jnp.int32, x.shape, 0) + start
     return jnp.where(rows < seq, x, 0.0)
+
+
+def _add_column_sums(sum_ref, x, start, seq):
+    """Add to a (1, 1, 1, lanes) block of sums the column sums of `x`, a
+    block's (rows, lanes) result whose first row is row `start` of a
+    sequence of `seq` rows (rows past its end hold garbage)."""
+    if seq % x.shape[0]:
+        x = _zero_pad_rows(x, start, seq)
+    sum_ref[0, 0] += jnp.sum(x, axis=0, keepdims=True)
 
 
 def _own_lanes(x, h, tile):
@@ -326,15 +360,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-               *scratch, tile, sm_scale, causal, seq_q, seq_k):
+               *scratch, tile, sm_scale, causal, seq_q, seq_k, sums=False):
     """dq = sum_j dS_ij K_j — grid (row block, head group, q-block,
     k-block), K sweep sequential, dq accumulated in VMEM where the sweep has
-    more than one block."""
+    more than one block. With `sums`, one more result: the column sums of
+    the block of dq, while it is in VMEM (the gradient of a bias that was
+    added to q, less the sum over blocks)."""
     bq, bk = tile.block_q, tile.block_k
     single = seq_k <= bk
     j = pl.program_id(3)
     q_start, k_start = pl.program_id(2) * bq, j * bk
     masked = causal or seq_k % bk != 0
+    if sums:
+        sum_ref, *scratch = scratch
     if not single:
         dq_acc, = scratch
 
@@ -358,10 +396,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         dq = _by_head(dq, bq, tile)
         if single:
             dq_ref[r] = dq.astype(dq_ref.dtype)
+            if sums:
+                _add_column_sums(sum_ref, dq, q_start, seq_q)
         else:
             dq_acc[r] += dq
 
     if single:
+        if sums:
+            sum_ref[...] = jnp.zeros_like(sum_ref)
         _each_row(tile.block_b, step)
         return
 
@@ -376,25 +418,36 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     @pl.when(j == pl.num_programs(3) - 1)
     def _out():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        if sums:
+            sum_ref[...] = jnp.zeros_like(sum_ref)
+            _each_row(tile.block_b, lambda r: _add_column_sums(
+                sum_ref, dq_acc[r], q_start, seq_q))
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, *scratch, tile, sm_scale, causal, seq_q,
-                seq_k):
+                seq_k, sums=False):
     """dk/dv for one K-block — grid (row block, head group, k-block,
     q-block), Q sweep sequential. Scores are computed transposed, (k, q):
     the row statistics then broadcast along sublanes as they arrive, and
     dv = P^T dO, dk = dS^T Q are plain products. Emits per-ATTENTION-head
     dk/dv; the GQA group-sum happens in XLA after the call (one reshape+sum,
-    no S^2 traffic)."""
+    no S^2 traffic). With `sums`, two more results: the column sums of the
+    blocks of dk and of dv, as `_dq_kernel` gives dq's."""
     bq, bk = tile.block_q, tile.block_k
     single = seq_q <= bq
     i = pl.program_id(3)
     q_start, k_start = i * bq, pl.program_id(2) * bk
     masked = causal or seq_k % bk != 0
     ragged_q = seq_q % bq != 0
+    if sums:
+        dk_sum_ref, dv_sum_ref, *scratch = scratch
     if not single:
         dk_acc, dv_acc = scratch
+
+    def add_sums(dk, dv):
+        _add_column_sums(dk_sum_ref, dk, k_start, seq_k)
+        _add_column_sums(dv_sum_ref, dv, k_start, seq_k)
 
     def step(r):
         q, do, k, v = q_ref[r], do_ref[r], k_ref[r], v_ref[r]
@@ -426,11 +479,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         if single:
             dk_ref[r] = dk.astype(dk_ref.dtype)
             dv_ref[r] = dv.astype(dv_ref.dtype)
+            if sums:
+                add_sums(dk, dv)
         else:
             dk_acc[r] += dk
             dv_acc[r] += dv
 
+    def zero_sums():
+        dk_sum_ref[...] = jnp.zeros_like(dk_sum_ref)
+        dv_sum_ref[...] = jnp.zeros_like(dv_sum_ref)
+
     if single:
+        if sums:
+            zero_sums()
         _each_row(tile.block_b, step)
         return
 
@@ -447,6 +508,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _out():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if sums:
+            zero_sums()
+            _each_row(tile.block_b,
+                      lambda r: add_sums(dk_acc[r], dv_acc[r]))
 
 
 # ------------------------------------------------------------- the three calls
@@ -458,42 +523,75 @@ def _out_struct(shape, dtype, *args):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
+class _Geometry(NamedTuple):
+    """Where one call's blocks lie. The index maps take (row block, head
+    group, q-block, k-block)."""
+    tile: _Tile
+    outer: tuple        # the grid's row blocks and head groups
+    q: object           # index maps of a block of q, of k, of v,
+    k: object
+    v: object
+    o: object           # of a block of o, do or a per-head dk or dv,
+    row: object         # and of a block of row statistics
+    o_shape: tuple
+    stats: tuple        # the row statistics' shape
+
+
 def _geometry(view, q, k, H, Hkv):
-    """(tile, grid rows and groups, index maps of a q-side block, a k-side
-    block and a block of row statistics, the statistics' shape) of one call
-    on the view's 3-D operands. The maps take (row block, head group,
-    q-block, k-block)."""
+    """The geometry of one call on the view's 3-D operands (under "packed"
+    q and k are both the one (B, S, 3*H*D) array)."""
     rows, Sq, width = q.shape
     Sk = k.shape[1]
-    B, D = (rows, width // H) if view == "bshd" else (rows // H, width)
+    if view == "bhsd":
+        B, D = rows // H, width
+    else:
+        B, D = rows, width // (3 * H if view == "packed" else H)
     tile = _choose_tile(view, B, H, Hkv, Sq, Sk, D, q.dtype.itemsize)
     group = H // Hkv
-    if view == "bshd":
-        groups = H // tile.heads
-        stats = (B, H, 1, Sq)
-
-        def q_map(b, g, i, j):
-            return (b, i, g)
-
-        def kv_map(b, g, i, j):     # group > 1 only with one head a block
-            return (b, j, g // group)
-
-        def row_map(b, g, i, j):
-            return (b, g, 0, i)
-    else:
+    if view == "bhsd":
         groups = 1
         stats = (rows, 1, 1, Sq)
 
         def q_map(n, g, i, j):
             return (n, i, 0)
 
-        def kv_map(n, g, i, j):     # group > 1 only with one row a block
+        def k_map(n, g, i, j):      # group > 1 only with one row a block
             return (n if group == 1
                     else (n // H) * Hkv + (n % H) // group, j, 0)
 
         def row_map(n, g, i, j):
             return (n, 0, 0, i)
-    return tile, (rows // tile.block_b, groups), q_map, kv_map, row_map, stats
+        o_map = q_map
+        v_map = k_map
+    else:
+        groups = H // tile.heads
+        stats = (B, H, 1, Sq)
+
+        def o_map(b, g, i, j):
+            return (b, i, g)
+
+        def row_map(b, g, i, j):
+            return (b, g, 0, i)
+        if view == "packed":
+            # `pack_qkv`'s columns, in blocks a group wide:
+            # [k_0 v_0 k_1 v_1 ... | q_0 q_1 ...]
+            def q_map(b, g, i, j):
+                return (b, i, 2 * groups + g)
+
+            def k_map(b, g, i, j):
+                return (b, j, 2 * g)
+
+            def v_map(b, g, i, j):
+                return (b, j, 2 * g + 1)
+        else:
+            q_map = o_map
+
+            def k_map(b, g, i, j):  # group > 1 only with one head a block
+                return (b, j, g // group)
+            v_map = k_map
+    return _Geometry(tile, (rows // tile.block_b, groups), q_map, k_map,
+                     v_map, o_map, row_map,
+                     (rows, Sq, D if view == "bhsd" else H * D), stats)
 
 
 def _scratch(blocks, *shapes):
@@ -510,24 +608,25 @@ def _params(tile):
 
 @functools.partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8))
 def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret):
-    """o like q, and the logsumexp in the statistics' shape, of the view's
-    3-D operands. Jitted so that a model's layers share one trace and one
+    """o, and the logsumexp in the statistics' shape, of the view's 3-D
+    operands. Jitted so that a model's layers share one trace and one
     lowering of the kernel."""
-    tile, outer, q_map, kv_map, row_map, stats = _geometry(view, q, k, H,
-                                                           Hkv)
+    geo = _geometry(view, q, k, H, Hkv)
+    tile = geo.tile
     Sq, Sk = q.shape[1], k.shape[1]
     bb, bq, bk, lanes = tile.block_b, tile.block_q, tile.block_k, tile.lanes
     nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Sk, bk)
-    q_spec = pl.BlockSpec((bb, bq, lanes), q_map)
-    kv_spec = pl.BlockSpec((bb, bk, lanes), kv_map)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, tile=tile, sm_scale=sm_scale,
                           causal=causal, seq_q=Sq, seq_k=Sk),
-        grid=outer + (nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, pl.BlockSpec((bb, tile.heads, 1, bq), row_map)],
-        out_shape=[_out_struct(q.shape, q.dtype, q, k, v),
-                   _out_struct(stats, jnp.float32, q, k, v)],
+        grid=geo.outer + (nq, nk),
+        in_specs=[pl.BlockSpec((bb, bq, lanes), geo.q),
+                  pl.BlockSpec((bb, bk, lanes), geo.k),
+                  pl.BlockSpec((bb, bk, lanes), geo.v)],
+        out_specs=[pl.BlockSpec((bb, bq, lanes), geo.o),
+                   pl.BlockSpec((bb, tile.heads, 1, bq), geo.row)],
+        out_shape=[_out_struct(geo.o_shape, q.dtype, q, k, v),
+                   _out_struct(geo.stats, jnp.float32, q, k, v)],
         scratch_shapes=_scratch(nk, (bb, bq, lanes),
                                 (bb, tile.heads, bq, _LANES),
                                 (bb, tile.heads, bq, _LANES)),
@@ -537,13 +636,28 @@ def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret):
     )(q, k, v)
 
 
+def _dkv_packed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
+                       dkv_ref, sum_ref, *scratch, tile, **kernel):
+    """`_dkv_kernel` on one block [dk_g | dv_g] of the packed cotangent and
+    one of its column sums. The array arrives holding `flash_dq`'s blocks
+    (`dq_ref`, left in HBM and untouched: it is the result's own memory)."""
+    n = tile.lanes
+    _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                dkv_ref.at[:, :, :n], dkv_ref.at[:, :, n:],
+                sum_ref.at[:, :, :, :n], sum_ref.at[:, :, :, n:],
+                *scratch, tile=tile, sums=True, **kernel)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 7, 8, 9, 10, 11))
 def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
               interpret):
     """dq like q, and dk, dv PER ATTENTION HEAD (like q along the heads,
     like k along the sequence), from the row statistics (lse, delta) in the
-    statistics' shape. Jitted as `_forward` is."""
-    tile, outer, q_map, kv_map, row_map, _ = _geometry(view, q, k, H, Hkv)
+    statistics' shape; under "packed" the one cotangent of the packed
+    array, which both kernels wrote into, and its column sums in float32.
+    Jitted as `_forward` is."""
+    geo = _geometry(view, q, k, H, Hkv)
+    tile = geo.tile
     Sq, Sk = q.shape[1], k.shape[1]
     bb, bq, bk, lanes = tile.block_b, tile.block_q, tile.block_k, tile.lanes
     nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Sk, bk)
@@ -551,39 +665,70 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
                   seq_k=Sk)
     common = dict(interpret=interpret, compiler_params=_params(tile))
     args = (q, k, v, do, lse, delta)
-    q_spec = pl.BlockSpec((bb, bq, lanes), q_map)
-    kv_spec = pl.BlockSpec((bb, bk, lanes), kv_map)
-    row_spec = pl.BlockSpec((bb, tile.heads, 1, bq), row_map)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel),
-        grid=outer + (nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=_out_struct(q.shape, q.dtype, *args),
-        scratch_shapes=_scratch(nk, (bb, bq, lanes)),
-        name="flash_dq", **common)(*args)
+    def in_specs(at=lambda index_map: index_map):
+        return [pl.BlockSpec((bb, bq, lanes), at(geo.q)),
+                pl.BlockSpec((bb, bk, lanes), at(geo.k)),
+                pl.BlockSpec((bb, bk, lanes), at(geo.v)),
+                pl.BlockSpec((bb, bq, lanes), at(geo.o)),
+                pl.BlockSpec((bb, tile.heads, 1, bq), at(geo.row)),
+                pl.BlockSpec((bb, tile.heads, 1, bq), at(geo.row))]
 
     # dk/dv: grid transposed so the K-block is the parallel dim
     def swapped(index_map):
         return lambda b, g, j, i: index_map(b, g, i, j)
 
-    q_spec = pl.BlockSpec((bb, bq, lanes), swapped(q_map))
-    kv_spec = pl.BlockSpec((bb, bk, lanes), swapped(kv_map))
-    row_spec = pl.BlockSpec((bb, tile.heads, 1, bq), swapped(row_map))
-    # per attention head: a block of dk lies where q's block would, along k
-    dkv_spec = pl.BlockSpec((bb, bk, lanes),
-                            lambda b, g, j, i: q_map(b, g, j, i))
+    dq_call = dict(grid=geo.outer + (nq, nk), in_specs=in_specs(),
+                   scratch_shapes=_scratch(nk, (bb, bq, lanes)),
+                   name="flash_dq", **common)
+    dkv_call = dict(grid=geo.outer + (nk, nq), in_specs=in_specs(swapped),
+                    scratch_shapes=_scratch(nq, (bb, bk, lanes),
+                                            (bb, bk, lanes)),
+                    name="flash_dkv", **common)
+    if view == "packed":
+        # one cotangent for the packed array. `flash_dq` writes q's blocks
+        # of it and leaves the rest unwritten; `flash_dkv` fills that array
+        # in place: k's and v's blocks of a group are neighbours, one block
+        # of twice the lanes. Each gives the column sums of its blocks too
+        # (the projection's bias gradient, while the blocks are in VMEM),
+        # (row blocks, sequence blocks, 1, columns), summed here
+        width = geo.o_shape[2]
+        dqkv, dq_sums = pl.pallas_call(
+            functools.partial(_dq_kernel, sums=True, **kernel),
+            out_specs=[pl.BlockSpec((bb, bq, lanes), geo.q),
+                       pl.BlockSpec((1, 1, 1, lanes),
+                                    lambda b, g, i, j: (b, i, 0, g))],
+            out_shape=[_out_struct(q.shape, q.dtype, *args),
+                       _out_struct((geo.outer[0], nq, 1, width),
+                                   jnp.float32, *args)],
+            **dq_call)(*args)
+        dkv_call["in_specs"].append(pl.BlockSpec(memory_space=pl.ANY))
+        dqkv, dkv_sums = pl.pallas_call(
+            functools.partial(_dkv_packed_kernel, **kernel),
+            out_specs=[pl.BlockSpec((bb, bk, 2 * lanes),
+                                    lambda b, g, j, i: (b, j, g)),
+                       pl.BlockSpec((1, 1, 1, 2 * lanes),
+                                    lambda b, g, j, i: (b, j, 0, g))],
+            out_shape=[_out_struct(q.shape, q.dtype, *args),
+                       _out_struct((geo.outer[0], nk, 1, 2 * width),
+                                   jnp.float32, *args)],
+            input_output_aliases={len(args): 0},
+            **dkv_call)(*args, dqkv)
+        return dqkv, jnp.concatenate([dkv_sums.sum(axis=(0, 1, 2)),
+                                      dq_sums.sum(axis=(0, 1, 2))])
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, **kernel),
+        out_specs=pl.BlockSpec((bb, bq, lanes), geo.q),
+        out_shape=_out_struct(q.shape, q.dtype, *args), **dq_call)(*args)
+    # per attention head: a block of dk lies where o's would, along k
+    dkv_spec = pl.BlockSpec((bb, bk, lanes), geo.o)
     dkv_shape = (q.shape[0], Sk, q.shape[2])
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel),
-        grid=outer + (nk, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[dkv_spec, dkv_spec],
         out_shape=[_out_struct(dkv_shape, k.dtype, *args),
                    _out_struct(dkv_shape, v.dtype, *args)],
-        scratch_shapes=_scratch(nq, (bb, bk, lanes), (bb, bk, lanes)),
-        name="flash_dkv", **common)(*args)
+        **dkv_call)(*args)
     return dq, dk, dv
 
 
@@ -695,18 +840,28 @@ def _flash_bshd_fwd(q, k, v, H, Hkv, causal, sm_scale):
     return o, (q, k, v, o, lse)
 
 
+def _head_delta(do, o, H):
+    """delta_i = rowsum(dO_i * O_i) per head, laid out like lse, (B, H, 1,
+    Sq). The sum over a head's lanes is a product with a 0/1 matrix, so
+    that XLA fuses the multiply into its operand and reads dO and O once:
+    written as a reshape and a sum it first copied the (B, S, H*D) product
+    into a layout with the sequence on the lanes (0.16 ms a layer of
+    BERT-base on the v5e, 0.23 with the reduce)."""
+    width = o.shape[-1]
+    heads = (jnp.arange(width)[:, None] // (width // H)
+             == jnp.arange(H)[None, :]).astype(jnp.float32)
+    delta = jnp.einsum("bsw,wh->bhs", do.astype(jnp.float32)
+                       * o.astype(jnp.float32), heads,
+                       precision=lax.Precision.HIGHEST)
+    return delta[:, :, None, :]
+
+
 def _flash_bshd_bwd(H, Hkv, causal, sm_scale, res, do):
     q, k, v, o, lse = res
-    B, Sq, _ = q.shape
-    # delta_i = rowsum(dO_i * O_i) per head, laid out like lse: one fused
-    # elementwise+reduce in XLA and the transpose of a (B, Sq, H) array
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
-                    .reshape(B, Sq, H, -1), axis=-1)
-    delta = delta.transpose(0, 2, 1)[:, :, None, :]
-    dq, dk, dv = _backward("bshd", q, k, v, lse, delta, do, H, Hkv, causal,
-                           sm_scale, _interpret())
+    dq, dk, dv = _backward("bshd", q, k, v, lse, _head_delta(do, o, H), do,
+                           H, Hkv, causal, sm_scale, _interpret())
     if H != Hkv:
-        Sk = k.shape[1]
+        B, Sk = k.shape[:2]
         dk = dk.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
         dv = dv.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
     return (dq, dk.reshape(k.shape).astype(k.dtype),
@@ -740,6 +895,104 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None):
     o = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                v.transpose(0, 2, 1, 3), causal, sm_scale)
     return o.transpose(0, 2, 1, 3)
+
+
+# ------------------------------------------- the packed (B, S, 3*H*D) argument
+def _packed_lanes(H, D):
+    """Width of a head group in the packed columns: the kernels' block
+    where the heads fill one, else all of a projection."""
+    group = _lane_group(H, H, D)
+    return H * D if isinstance(group, str) else group[0]
+
+
+def pack_qkv(q, k, v, n_heads):
+    """q, k, v of one width H*D along their last axis (three projections'
+    weights, biases or results) as the one array `flash_attention_packed`
+    reads: columns [k_0 v_0 k_1 v_1 ... | q_0 q_1 ...] in head groups of
+    128 lanes. k's and v's blocks of a group are neighbours so that one
+    kernel's result block holds both; the gradient of a packed array comes
+    back to q, k and v through this function by autodiff."""
+    width = q.shape[-1]
+    lanes = _packed_lanes(n_heads, width // n_heads)
+    kv = jnp.stack([x.reshape(x.shape[:-1] + (width // lanes, lanes))
+                    for x in (k, v)], axis=-2)
+    return jnp.concatenate([kv.reshape(q.shape[:-1] + (2 * width,)), q],
+                           axis=-1)
+
+
+def _unpack_qkv(qkv, n_heads):
+    """q, k, v out of `pack_qkv`'s columns."""
+    width = qkv.shape[-1] // 3
+    lanes = _packed_lanes(n_heads, width // n_heads)
+    kv = qkv[..., :2 * width].reshape(qkv.shape[:-1]
+                                      + (width // lanes, 2, lanes))
+    return (qkv[..., 2 * width:],
+            kv[..., 0, :].reshape(qkv.shape[:-1] + (width,)),
+            kv[..., 1, :].reshape(qkv.shape[:-1] + (width,)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _flash_packed(qkv, bias, H, causal, sm_scale):
+    """On the packed view of `qkv + bias`, always through the kernels."""
+    return _flash_packed_fwd(qkv, bias, H, causal, sm_scale)[0]
+
+
+def _flash_packed_fwd(qkv, bias, H, causal, sm_scale):
+    qkv = qkv + bias        # XLA fuses it into the product that made qkv
+    o, lse = _forward("packed", qkv, qkv, qkv, H, H, causal, sm_scale,
+                      _interpret())
+    return o, (qkv, o, lse, bias)
+
+
+def _flash_packed_bwd(H, causal, sm_scale, res, do):
+    qkv, o, lse, bias = res
+    dqkv, sums = _backward("packed", qkv, qkv, qkv, lse,
+                           _head_delta(do, o, H), do, H, H, causal, sm_scale,
+                           _interpret())
+    return dqkv, sums.astype(bias.dtype)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+def flash_attention_packed(qkv, n_heads, causal=False, sm_scale=None,
+                           bias=None):
+    """Self-attention on the packed result of ONE q|k|v projection:
+    `qkv` (B, S, 3*H*D) in `pack_qkv`'s column order, every head with its
+    own k and v; `bias` (3*H*D,), packed likewise, is the projection's and
+    is added here. Returns (B, S, H*D) in qkv's dtype.
+
+    The three kernels read q's, k's and v's head-group blocks where the
+    projection left them, and the backward returns one (B, S, 3*H*D)
+    cotangent that `flash_dq` and `flash_dkv` both wrote into, so the
+    projection's dx and dW each read one plain array: no slice on the way
+    in, no concatenation on the way out. The bias rides in so that its
+    gradient can ride out: the kernels sum the cotangent's columns while
+    its blocks are in VMEM, which spares one more pass over it. A shape
+    whose heads fill no 128-lane group is split and sent to
+    `flash_attention_bshd`, counted
+    (`ops.pallas.fallback.flash_packed.<reason>`).
+
+    Under a mesh that shards the projection's columns (a `model` axis over
+    1) GSPMD gathers `qkv` around the Mosaic calls, as it gathers q, k and v
+    around `flash_attention_bshd`'s."""
+    B, S, width = qkv.shape
+    D = width // (3 * n_heads)
+    if sm_scale is None:
+        sm_scale = D ** -0.5
+    causal, sm_scale = bool(causal), float(sm_scale)
+    if bias is None:
+        bias = jnp.zeros((width,), qkv.dtype)
+    if _pallas_on():
+        tile = _choose_tile("packed", B, n_heads, n_heads, S, S, D,
+                            qkv.dtype.itemsize)
+        if isinstance(tile, _Tile):
+            note_dispatch("flash_packed")
+            return _flash_packed(qkv, bias, n_heads, causal, sm_scale)
+        note_fallback("flash_packed", tile)
+    q, k, v = (x.reshape(B, S, n_heads, D)
+               for x in _unpack_qkv(qkv + bias, n_heads))
+    return flash_attention_bshd(q, k, v, causal, sm_scale).reshape(B, S, -1)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, sm_scale=None):

@@ -167,11 +167,13 @@ def test_matmul_precision_rides_into_the_kernels():
             jaxpr = jax.make_jaxpr(jax.grad(
                 lambda x: fa.flash_attention_bshd(x, x, x).astype(
                     jnp.float32).sum()))(q).jaxpr
-        found = {str(e.params["precision"]) for e in _eqns(jaxpr)
-                 if e.primitive.name == "dot_general"}
-        assert sum(e.primitive.name == "pallas_call"
-                   for e in _eqns(jaxpr)) == 3
-        return found
+        calls = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+        assert len(calls) == 3
+        # the kernels' own products; `_head_delta`'s sum over a head's
+        # lanes is a product too, and exact on purpose
+        return {str(e.params["precision"]) for call in calls
+                for e in _eqns(call.params["jaxpr"])
+                if e.primitive.name == "dot_general"}
 
     highest = str((jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST))
     default = str((jax.lax.Precision.DEFAULT, jax.lax.Precision.DEFAULT))
@@ -214,6 +216,86 @@ def test_a_shape_the_head_group_cannot_take_falls_back_counted():
     assert moved(before, "ops.pallas.fallback") == 0
 
 
+PACKED_CASES = [
+    # B, S, H, D, causal, the fallback's reason: the packed entry
+    (2, 128, 4, 64, False, None),     # one k-block, two head groups
+    (4, 128, 2, 128, True, None),     # a head a group, 4 rows a step
+    (2, 128, 8, 32, False, None),     # four heads a group
+    (2, 200, 2, 64, True, None),      # two k-blocks, the second ragged
+    (2, 200, 2, 64, False, None),
+    (1, 640, 2, 64, True, None),      # 384-row blocks through scratch
+    (2, 64, 4, 16, False, "head_group"),    # bert_tiny's heads
+    (1, 128, 3, 64, True, "head_group"),    # an odd head count at D = 64
+    (1, 128, 2, 96, False, "head_dim"),
+]
+
+
+@pytest.mark.parametrize("B,S,H,D,causal,reason", PACKED_CASES)
+def test_packed_entry_matches_the_three_operand_entry(B, S, H, D, causal,
+                                                      reason):
+    """`flash_attention_packed` on `pack_qkv(q, k, v)` and a packed bias
+    against `flash_attention_bshd` on the same q, k, v with their biases
+    added: the output, the cotangent of the packed array unpacked into dq,
+    dk and dv (the same kernel bodies on the same blocks, so to the last
+    bits), and the bias gradient, which the kernels sum from their blocks.
+    A shape whose heads fill no lane group is split and counted."""
+    q, k, v, w = _operands("bshd", B, H, H, S, S, D, "float32", 5)
+    bias = _rand((3 * H * D,), 9)
+
+    def packed(q_, k_, v_, bias_):
+        qkv = fa.pack_qkv(*(x.reshape(B, S, H * D) for x in (q_, k_, v_)),
+                          n_heads=H)
+        assert qkv.shape == (B, S, 3 * H * D)
+        return fa.flash_attention_packed(qkv, H, causal, bias=bias_
+                                         ).reshape(q.shape)
+
+    def plain(q_, k_, v_, bias_):
+        bq, bk, bv = (b.reshape(H, D) for b in fa._unpack_qkv(bias_, H))
+        return fa.flash_attention_bshd(q_ + bq, k_ + bk, v_ + bv, causal)
+
+    def grads(f):
+        return jax.grad(lambda *x: jnp.sum(f(*x) * w),
+                        argnums=(0, 1, 2, 3))(q, k, v, bias)
+
+    before = _counters()
+    with jax.default_matmul_precision("highest"):
+        out, want = packed(q, k, v, bias), plain(q, k, v, bias)
+        g_packed, g_plain = grads(packed), grads(plain)
+    moved = {name: n - before.get(name, 0)
+             for name, n in _counters().items() if n != before.get(name, 0)}
+    if reason is None:
+        assert moved.get("ops.pallas.dispatch.flash_packed") == 2, moved
+        assert not any("fallback" in name for name in moved), moved
+    else:
+        assert moved.get(
+            "ops.pallas.fallback.flash_packed." + reason) == 2, moved
+        assert "ops.pallas.dispatch.flash_packed" not in moved, moved
+    onp.testing.assert_allclose(out, want, atol=1e-6, rtol=1e-6)
+    for got, ref, name in zip(g_packed, g_plain, ["dq", "dk", "dv"]):
+        onp.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5,
+                                    err_msg=name)
+    # a sum over B * S rows in another order; bk's is zero but for rounding
+    onp.testing.assert_allclose(
+        g_packed[3], g_plain[3], rtol=1e-5,
+        atol=1e-6 * float(jnp.abs(g_plain[3]).max()), err_msg="bias")
+
+
+def test_pack_qkv_lays_k_and_v_of_a_head_group_side_by_side():
+    """The column order the kernels' index maps rest on: blocks of a head
+    group's lanes, [k_0 v_0 k_1 v_1 ... | q_0 q_1 ...]; one block [k | v |
+    q] where the heads fill no group. `_unpack_qkv` is its inverse."""
+    H, D = 4, 64
+    q, k, v = (jnp.full((3, H * D), i) + jnp.arange(H * D) // 128 * 10
+               for i in (1.0, 2.0, 3.0))
+    packed = fa.pack_qkv(q, k, v, H)
+    assert packed.shape == (3, 3 * H * D)
+    assert packed[0, ::128].tolist() == [2.0, 3.0, 12.0, 13.0, 1.0, 11.0]
+    for got, want in zip(fa._unpack_qkv(packed, H), (q, k, v)):
+        onp.testing.assert_array_equal(got, want)
+    loose = fa.pack_qkv(q[:, :48], k[:, :48], v[:, :48], 3)    # D = 16
+    assert loose[0, ::48].tolist() == [2.0, 3.0, 1.0]
+
+
 # name: (view, B, H, Hkv, Sq, Sk, D, itemsize) ->
 #       (lanes, heads, block_b, block_q, block_k, grid steps a call)
 TILES = {
@@ -221,6 +303,9 @@ TILES = {
                        (128, 2, 8, 128, 128, 96)),
     "bert_base_s512": (("bshd", 32, 12, 12, 512, 512, 64, 4),
                        (128, 2, 1, 512, 512, 192)),
+    "bert_base_s128_packed": (("packed", 128, 12, 12, 128, 128, 64, 4),
+                              (128, 2, 8, 128, 128, 96)),
+    "odd_heads_packed": (("packed", 1, 3, 3, 128, 128, 64, 4), "head_group"),
     "bert_large_s512": (("bshd", 8, 16, 16, 512, 512, 64, 2),
                         (128, 2, 1, 512, 512, 64)),
     "llama_gqa_d128": (("bshd", 4, 32, 8, 2048, 2048, 128, 2),
@@ -252,8 +337,8 @@ def test_the_tile_follows_the_shape(name):
         return
     assert tile[:6] == want
     view, B, H, Hkv, Sq, Sk, D, itemsize = args
-    rows = B if view == "bshd" else B * H
-    groups = H // tile.heads if view == "bshd" else 1
+    rows = B * H if view == "bhsd" else B
+    groups = 1 if view == "bhsd" else H // tile.heads
     assert tile.steps == (rows // tile.block_b) * groups * (
         -(-Sq // tile.block_q)) * (-(-Sk // tile.block_k))
     assert tile.lanes == tile.heads * D and rows % tile.block_b == 0
@@ -357,21 +442,61 @@ def _pallas_calls(jaxpr, found, outer=""):
 
 
 @pytest.mark.parametrize("entry", ["flash_attention",
-                                   "flash_attention_bshd"])
+                                   "flash_attention_bshd",
+                                   "flash_attention_packed"])
 def test_the_three_kernels_carry_their_names(entry):
     """The names are what the device trace shows the kernels under
     (`%flash_fwd.1` in the compiled program), and the benchmark's
     `flash_*_ms_per_step` read them."""
-    q = _rand((1, 2, 128, 64) if entry == "flash_attention"
-              else (1, 128, 2, 64), 0)
-    grad = jax.grad(lambda q, k, v: getattr(fa, entry)(q, k, v).sum(),
-                    argnums=(0, 1, 2))
-    calls = _pallas_calls(jax.make_jaxpr(grad)(q, q, q).jaxpr, [])
+    if entry == "flash_attention_packed":
+        grad = jax.grad(lambda qkv: fa.flash_attention_packed(qkv, 2).sum())
+        jaxpr = jax.make_jaxpr(grad)(_rand((1, 128, 3 * 128), 0)).jaxpr
+    else:
+        q = _rand((1, 2, 128, 64) if entry == "flash_attention"
+                  else (1, 128, 2, 64), 0)
+        grad = jax.grad(lambda q, k, v: getattr(fa, entry)(q, k, v).sum(),
+                        argnums=(0, 1, 2))
+        jaxpr = jax.make_jaxpr(grad)(q, q, q).jaxpr
+    calls = _pallas_calls(jaxpr, [])
     assert [c.params["name"] for c, _ in calls] == [
         "flash_fwd", "flash_dq", "flash_dkv"]
+    if entry == "flash_attention_packed":
+        # the second backward call fills the first one's array in place
+        assert [dict(c.params["input_output_aliases"]) for c, _ in calls] \
+            == [{}, {}, {6: 0}]
 
 
-def test_in_a_train_step_a_kernel_keeps_its_own_name():
+def test_step_bytes_counts_the_blocks_a_mosaic_call_moves():
+    """`tools/step_bytes.py` counts a Mosaic call by the blocks its grid
+    moves: the packed array is handed over three times and a third of it
+    is read each time; `flash_dq` writes a third of the cotangent and
+    `flash_dkv`, which takes that array in place (`ANY`: no block of it is
+    moved), two thirds."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "step_bytes.py")
+    spec = importlib.util.spec_from_file_location("step_bytes", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    B, S, H, D = 16, 128, 4, 64
+    grad = jax.grad(lambda qkv: fa.flash_attention_packed(qkv, H).sum())
+    moved = tool.mosaic_traffic(
+        jax.make_jaxpr(grad)(_rand((B, S, 3 * H * D), 0)).jaxpr)
+    third = B * S * H * D * 4
+    stats, sums = B * H * S * 4, (B // 8) * H * D * 4
+    by_name = {key[0]: (key[1:], value) for key, value in moved.items()}
+    assert by_name["flash_fwd"] == (
+        ((3 * third,) * 3, (third, stats)), ([third] * 3, [third, stats]))
+    assert by_name["flash_dq"][1] == (
+        [third] * 4 + [stats] * 2, [third, sums])
+    whole, (reads, writes) = by_name["flash_dkv"]
+    assert whole[0][-1] == 3 * third and reads == (
+        [third] * 4 + [stats] * 2 + [0])
+    assert writes == [2 * third, 2 * sums]
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_in_a_train_step_a_kernel_keeps_its_own_name(packed):
     """XLA names an instruction after the last part of its `op_name`. The
     step's `forward` scope lies inside what is differentiated, so the part
     that `jvp` and `transpose` wrap is the scope and the kernel's stays
@@ -379,9 +504,11 @@ def test_in_a_train_step_a_kernel_keeps_its_own_name():
     chip. With the scope around `value_and_grad`, or with none, it would be
     `jvp(flash_dq)` and `%jvp_flash_dq_.1`."""
     from mxnet_tpu import parallel as par
-    q = _rand((2, 128, 2, 64), 0)
+    q = _rand((2, 128, 3 * 128) if packed else (2, 128, 2, 64), 0)
 
     def loss_fn(params, batch):
+        if packed:
+            return fa.flash_attention_packed(batch * params["w"], 2).sum()
         return fa.flash_attention_bshd(batch * params["w"], batch,
                                        batch).sum()
 

@@ -126,3 +126,85 @@ def test_bert_sharded_step():
         p, s, loss = step(p, s, batch)
         losses.append(float(loss))
     assert losses[-1] < losses[0]
+
+
+def _three_projection_layer(lp, x, cfg):
+    """A BERT encoder layer written plainly: three projections, the S x S
+    probabilities in the open, no kernel."""
+    from mxnet_tpu.models.bert import layer_norm
+    B, S, _ = x.shape
+    a = lp["attn"]
+    q, k, v = ((x @ a["w" + n] + a["b" + n])
+               .reshape(B, S, cfg.n_heads, cfg.head_dim) for n in "qkv")
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * cfg.head_dim ** -0.5
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+    x = layer_norm(x + (o.reshape(B, S, -1) @ a["wo"] + a["bo"]),
+                   lp["attn_norm"], cfg.norm_eps)
+    f = lp["ffn"]
+    h = jax.nn.gelu(x @ f["w1"] + f["b1"], approximate=True)
+    return layer_norm(x + (h @ f["w2"] + f["b2"]), lp["ffn_norm"],
+                      cfg.norm_eps)
+
+
+LAYER_LEAVES = {
+    "attn/wq": (256, 256), "attn/wk": (256, 256), "attn/wv": (256, 256),
+    "attn/wo": (256, 256), "attn/bq": (256,), "attn/bk": (256,),
+    "attn/bv": (256,), "attn/bo": (256,),
+    "attn_norm/gamma": (256,), "attn_norm/beta": (256,),
+    "ffn/w1": (256, 512), "ffn/b1": (512,), "ffn/w2": (512, 256),
+    "ffn/b2": (256,), "ffn_norm/gamma": (256,), "ffn_norm/beta": (256,)}
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_bert_layer_with_one_packed_projection_is_the_plain_layer(
+        remat, monkeypatch):
+    """`_encoder_layer` packs wq|wk|wv inside the step and runs the flash
+    kernels (interpreted here) on the packed result. Against the plain
+    layer at a width with a head-group tile (H = 4, D = 64), float32: the
+    hidden states and the gradient of each of the layer's 16 leaves under
+    its own name; the parameter tree is what it was."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import bert
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    cfg = BertConfig(vocab_size=64, dim=256, n_layers=1, n_heads=4,
+                     hidden_dim=512, max_seq_len=128, dtype=jnp.float32,
+                     remat=remat)
+    params = bert_init(jax.random.PRNGKey(0), cfg)
+    # biases and norms off their initial 0 and 1, so that each one matters
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    params = tree.unflatten([p + 0.05 * jax.random.normal(k, p.shape)
+                             for p, k in zip(leaves, keys)])
+    named = {"/".join(k.key for k in path): leaf.shape for path, leaf in
+             jax.tree_util.tree_leaves_with_path(params["layers"]["0"])}
+    assert named == LAYER_LEAVES
+    toks = jax.random.randint(jax.random.PRNGKey(2), (2, 128), 0, 64)
+    w = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 256))
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(
+                lambda p: jnp.sum(bert_forward(p, toks, cfg) * w))(params)
+
+    before = telemetry.snapshot()["counters"].get(
+        "ops.pallas.dispatch.flash_packed", 0)
+    loss, grads = run()
+    assert telemetry.snapshot()["counters"][
+        "ops.pallas.dispatch.flash_packed"] > before
+    monkeypatch.setattr(bert, "_encoder_layer", _three_projection_layer)
+    want_loss, want = run()
+    assert jax.tree_util.tree_structure(grads) == tree
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    got, ref = grads["layers"]["0"], want["layers"]["0"]
+    # bk's gradient is zero but for rounding (a constant added to every
+    # key's score leaves the softmax as it was), so a leaf is held to the
+    # largest gradient among the leaves of its rank
+    scale = {rank: max(float(jnp.abs(ref[n.split("/")[0]][n.split("/")[1]])
+                             .max())
+                       for n, shape in LAYER_LEAVES.items()
+                       if len(shape) == rank) for rank in (1, 2)}
+    for name, shape in LAYER_LEAVES.items():
+        group, leaf = name.split("/")
+        np.testing.assert_allclose(got[group][leaf], ref[group][leaf],
+                                   atol=2e-5 * scale[len(shape)], rtol=1e-3,
+                                   err_msg=name)

@@ -483,7 +483,11 @@ def test_runner_restart_budget_exhausts():
 
 def test_runner_recovers_from_stall(tmp_path):
     """A hang inside the step (dead collective) → watchdog StallError →
-    restore-and-replay, run completes."""
+    restore-and-replay, run completes. The deadline is 5 s, as in
+    `test_observability.py::test_runner_stall_flight_ledger` and for its
+    reason: the guarded steps compile for about 0.5 s on an idle machine,
+    and under the load of six test workers a deadline of 0.5 s made a
+    second stall of the compile after the restore (restarts 2, not 1)."""
     net, tr = _build_mlp()
     fused = gluon.FusedTrainStep(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), tr)
@@ -492,7 +496,7 @@ def test_runner_recovers_from_stall(tmp_path):
     with faults.inject("train.step:hang:3:30"):
         runner = rz.ResilientRunner.for_fused_step(
             fused, batch_fn, ckpt_dir=str(tmp_path / "ck"), ckpt_every=1,
-            max_restarts=2, step_deadline_s=0.5)
+            max_restarts=2, step_deadline_s=5.0)
         report = runner.run(4)
     assert report.restarts == 1
     assert _counter("resilience.stalls") > stalls0

@@ -26,11 +26,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_text(fn, *args):
+    """The text of `fn` compiled for the arguments' described chip, with
+    the persistent compile cache off and out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
 def test_mlm_head_keeps_one_tensor_of_the_logits_size(one_chip):
     """bert_base's head over a step's 16,384 positions: the forward writes
     the float32 logits and nothing else of their size, and the backward
     writes no `softmax - onehot` (XLA builds it inside both products)."""
-    from jax.experimental.compilation_cache import compilation_cache
     from mxnet_tpu.models.losses import linear_cross_entropy
     n, d, v = 16384, 768, 30522
 
@@ -39,15 +52,8 @@ def test_mlm_head_keeps_one_tensor_of_the_logits_size(one_chip):
 
     args = (shape((n, d), jnp.float32), shape((v, d), jnp.float32),
             shape((n,), jnp.int32), shape((n,), jnp.int32))
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(jax.value_and_grad(linear_cross_entropy, (0, 1))
-                       ).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-        compilation_cache.reset_cache()
+    text = _compiled_text(jax.value_and_grad(linear_cross_entropy, (0, 1)),
+                          *args)
     entry = text[text.index("ENTRY "):]
     # instructions of the entry computation whose result holds an (N, V)
     # tensor; a get-tuple-element only names a fusion's output again
@@ -78,7 +84,6 @@ def test_batch_norm_statistics_ride_in_the_convolutions_epilogue(one_chip):
     activation only to return per-channel vectors: the variance's second
     pass and the backward's zero sum (`jit(_var)`, four such fusions before
     PR 30) are gone."""
-    from jax.experimental.compilation_cache import compilation_cache
     import mxnet_tpu  # noqa: F401 — registers the ops
     from mxnet_tpu.ops import registry
     conv, bn, act = (registry.get(n).fn for n in
@@ -99,15 +104,7 @@ def test_batch_norm_statistics_ride_in_the_convolutions_epilogue(one_chip):
     vec = shape((c,), jnp.float32)
     args = (image, shape((c, c, 1, 1), jnp.bfloat16),
             shape((c, c, 3, 3), jnp.bfloat16)) + (vec,) * 7 + (image,)
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(jax.value_and_grad(loss, tuple(range(7)))
-                       ).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-        compilation_cache.reset_cache()
+    text = _compiled_text(jax.value_and_grad(loss, tuple(range(7))), *args)
     tool = _step_bytes()
     rows = tool.traffic(tool.entry_instructions(text))
     alone = [ins["name"] for ins, _, _ in tool.vector_passes(rows)]
@@ -118,3 +115,134 @@ def test_batch_norm_statistics_ride_in_the_convolutions_epilogue(one_chip):
                if ins["kind"] == "kOutput" and "transpose(" not in
                ins["op_name"] and "conv_general_dilated" in ins["op_name"]]
     assert forward == [[vec_bytes, vec_bytes, image_bytes]] * 2, forward
+
+
+@pytest.mark.parametrize("batch,seq", [(128, 128), (32, 512)])
+def test_bert_layer_has_one_packed_projection_the_kernels_read_in_place(
+        one_chip, monkeypatch, batch, seq):
+    """bert_base's encoder layer, forward and backward in float32 at both
+    cells' shapes, with the flash kernels on (their gate asks the default
+    backend, which is the CPU here): ONE forward product writes the packed
+    (B, S, 2304) q|k|v; the three Mosaic calls read it as it is; `flash_dkv`
+    fills the array `flash_dq` wrote, and that one cotangent is read by one
+    dx product and one dW product and by nothing else: the bias gradient
+    comes out of the kernels. Nothing of an activation's size is sliced,
+    copied, reduced or concatenated on the way."""
+    import sys
+    from mxnet_tpu.models import bert
+    fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
+    monkeypatch.setattr(fa, "_pallas_on", lambda: True)
+    cfg = bert.BertConfig(dtype=jnp.float32)
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    lp = jax.tree_util.tree_map(shape, jax.eval_shape(
+        lambda: bert.bert_init(jax.random.PRNGKey(0), cfg))["layers"]["0"])
+    x = shape(jax.ShapeDtypeStruct((batch, seq, cfg.dim), jnp.float32))
+    text = _compiled_text(jax.grad(
+        lambda lp, x, t: jnp.sum(bert._encoder_layer(lp, x, cfg) * t),
+        argnums=(0, 1)), lp, x, x)
+    entry = _step_bytes().entry_instructions(text)
+    packed_bytes = batch * seq * 3 * cfg.dim * 4
+    kernels = {ins["name"].lstrip("%").rsplit(".", 1)[0]: ins
+               for ins in entry if ins["opcode"] == "custom-call"
+               and "pallas_call" in ins["op_name"]}
+    assert sorted(kernels) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+    def sizes(ins):
+        return sorted(size for size, _ in ins["results"])
+
+    def packed_array_of(ins):
+        """The name the instruction's (B, S, 2304) result is read under:
+        its own, or that of the get-tuple-element that takes it out."""
+        if sizes(ins) == [packed_bytes]:
+            return ins["name"]
+        taken = [e["name"] for e in entry
+                 if e["opcode"] == "get-tuple-element"
+                 and e["operands"] == [ins["name"]]
+                 and sizes(e) == [packed_bytes]]
+        assert len(taken) == 1, (ins["name"], taken)
+        return taken[0]
+
+    def readers(name):
+        return [ins for ins in entry if name in ins["operands"]]
+
+    projections = [ins for ins in entry if ins["kind"] == "kOutput"
+                   and sizes(ins) == [packed_bytes]]
+    assert len(projections) == 1, [ins["name"] for ins in projections]
+    qkv = projections[0]["name"]
+    assert "transpose(" not in projections[0]["op_name"]
+    # q, k and v of all three kernels are the product's result itself, and
+    # nobody else reads it
+    for kernel in kernels.values():
+        assert kernel["operands"][:3] == [qkv] * 3, kernel["operands"]
+    assert sorted(ins["name"] for ins in readers(qkv)) == sorted(
+        ins["name"] for ins in kernels.values())
+    # the cotangent: written by flash_dq, filled in place by flash_dkv
+    dq = packed_array_of(kernels["flash_dq"])
+    assert [ins["name"] for ins in readers(dq)] == [
+        kernels["flash_dkv"]["name"]]
+    assert kernels["flash_dkv"]["operands"][-1] == dq
+    cotangent_readers = readers(packed_array_of(kernels["flash_dkv"]))
+    assert all(ins["kind"] == "kOutput" for ins in cotangent_readers), [
+        (ins["name"], ins["opcode"]) for ins in cotangent_readers]
+    assert sorted(sizes(ins)[-1] for ins in cotangent_readers) == sorted(
+        [batch * seq * cfg.dim * 4, cfg.dim * 3 * cfg.dim * 4])
+
+
+# entry, (B, S, H, Hkv, D), dtype, causal: shapes no cell runs, one of each
+# path through the kernels
+KERNEL_SHAPES = [
+    ("packed", (8, 512, 16, 16, 64), "bfloat16", False),   # bert_large
+    ("packed", (2, 1024, 8, 8, 128), "float32", True),     # k-blocks, scratch
+    ("packed", (6, 200, 4, 4, 64), "float32", False),      # ragged
+    ("bshd", (4, 2048, 32, 8, 128), "bfloat16", True),     # GQA, D on lanes
+    ("bshd", (2, 640, 4, 4, 64), "float32", True),         # 384-row blocks
+    ("bshd", (16, 64, 2, 2, 128), "float32", False),
+    ("bhsd", (1, 256, 8, 2, 64), "float32", True),         # GQA, a row a block
+    ("bhsd", (2, 1024, 4, 4, 64), "bfloat16", False),
+]
+
+
+@pytest.mark.parametrize("entry,dims,dtype,causal", KERNEL_SHAPES)
+def test_flash_kernels_compile_within_the_vmem_they_ask_for(
+        one_chip, monkeypatch, entry, dims, dtype, causal):
+    """Forward and backward kernels on each view, compiled by Mosaic for
+    the described v5e under the VMEM the kernels ask for: 16 MiB, or a
+    tile's estimate where that is more. The request is small on purpose
+    (XLA clears that much of the VMEM it keeps activations in), so a tile
+    whose estimate is too low has to fail here and not on the chip."""
+    import sys
+    import mxnet_tpu  # noqa: F401
+    fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
+    monkeypatch.setattr(fa, "_pallas_on", lambda: True)
+    B, S, H, Hkv, D = dims
+
+    def shape(*dims_):
+        return jax.ShapeDtypeStruct(dims_, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    if entry == "packed":
+        args = (shape(B, S, 3 * H * D), shape(3 * H * D))
+
+        def attend(qkv, bias):
+            return fa.flash_attention_packed(qkv, H, causal, bias=bias)
+    elif entry == "bshd":
+        args = (shape(B, S, H, D), shape(B, S, Hkv, D), shape(B, S, Hkv, D))
+
+        def attend(q, k, v):
+            return fa.flash_attention_bshd(q, k, v, causal)
+    else:
+        args = (shape(B, H, S, D), shape(B, Hkv, S, D), shape(B, Hkv, S, D))
+
+        def attend(q, k, v):
+            return fa.flash_attention(q, k, v, causal)
+    tile = fa._choose_tile(entry, B, H, Hkv, S, S, D,
+                           jnp.dtype(dtype).itemsize)
+    assert tile.vmem <= 2 * fa._VMEM_LIMIT, tile
+    text = _compiled_text(jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(args)))), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

@@ -12,13 +12,23 @@ cell) once per variant, each with one part of `parallel/flash_attention.py`
 put back to what it was, and reads a traced window of each with the
 benchmark's own reduction: the step, the three kernels and the copies.
 
-  change         the tree as it is: (B, S, H, D) in and out, head groups on
-                 the lanes, whole-sequence tiles
+  change         the tree as it is: one packed q|k|v projection, read and
+                 written in place by the kernels, head groups on the lanes,
+                 whole-sequence tiles
+  w_fused        ... with the packed weight and bias left for XLA to fuse
+                 into the product's operand, not written once before it
+  split          three projections a layer and `flash_attention_bshd` on
+                 their (B, S, H, D) results, as the layer was before the
+                 packed entry
   old_tile       ... with 128 x 128 tiles of one batch row a grid step
   tile:K=V;K=V   ... with those constants of the tile chooser set, e.g.
                  `tile:_MAX_ROWS=4` (what the chooser's constants are worth)
-  transposed     the layer transposes to (B, H, S, D) and back as it used
-                 to, around the same kernels on that view (D on the lanes)
+  a+b            several of these at once: `split+tile:_VMEM_LIMIT=50331648`
+                 is the layer and the kernels' VMEM request of before the
+                 packed entry
+  transposed     three projections, and the layer transposes to (B, H, S, D)
+                 and back as it used to, around the same kernels on that
+                 view (D on the lanes)
   parent         the transposing layer around the parent commit's kernels,
                  loaded from `--parent` (a `git archive` of it)
 
@@ -37,12 +47,15 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
-VARIANTS = ("change", "old_tile", "transposed", "parent")
+VARIANTS = ("change", "w_fused", "split", "old_tile", "transposed",
+            "parent")
 
 
-def transposing_layer(attention):
-    """`models.bert._encoder_layer` as it was before the (B, S, H, D) entry:
-    four transposes a layer forward, around `attention` on (B, H, S, D)."""
+def three_projection_layer(attention, transposed):
+    """`models.bert._encoder_layer` as it was before the packed entry:
+    three projections, and `attention` on their (B, S, H, D) results; or,
+    `transposed`, as it was before that entry: four transposes a layer
+    forward, around `attention` on (B, H, S, D)."""
     import jax
     from mxnet_tpu.models.bert import layer_norm
 
@@ -50,10 +63,13 @@ def transposing_layer(attention):
         B, S, _ = x.shape
         a = lp["attn"]
         q, k, v = ((x @ a["w" + n] + a["b" + n])
-                   .reshape(B, S, cfg.n_heads, cfg.head_dim)
-                   .transpose(0, 2, 1, 3) for n in "qkv")
+                   .reshape(B, S, cfg.n_heads, cfg.head_dim) for n in "qkv")
+        if transposed:
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         o = attention(q, k, v, causal=False)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
+        if transposed:
+            o = o.transpose(0, 2, 1, 3)
+        o = o.reshape(B, S, -1)
         x = layer_norm(x + (o @ a["wo"] + a["bo"]), lp["attn_norm"],
                        cfg.norm_eps)
         f = lp["ffn"]
@@ -69,24 +85,37 @@ def put_back(variant, parent_path):
     from mxnet_tpu.models import bert
     fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
     saved = {(fa, n): getattr(fa, n) for n in
-             ("_MAX_BLOCK", "_MAX_ROWS", "_STEP_SCORES")}
+             ("_MAX_BLOCK", "_MAX_ROWS", "_STEP_SCORES", "_VMEM_LIMIT")}
     saved[(bert, "_encoder_layer")] = bert._encoder_layer
-    if variant == "old_tile":
-        fa._MAX_BLOCK, fa._MAX_ROWS = 128, 1
-    elif variant.startswith("tile:"):
-        for pair in variant[len("tile:"):].split(";"):
-            name, value = pair.split("=")
-            if (fa, name) not in saved:
-                raise SystemExit("flash_parts: no constant %r" % name)
-            setattr(fa, name, int(value))
-    elif variant == "transposed":
-        bert._encoder_layer = transposing_layer(fa.flash_attention)
-    elif variant == "parent":
-        spec = importlib.util.spec_from_file_location(
-            "mxnet_tpu.parallel._flash_attention_parent", parent_path)
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
-        bert._encoder_layer = transposing_layer(parent.flash_attention)
+    saved[(bert, "_packed_projection")] = bert._packed_projection
+    for part in variant.split("+"):     # `split+tile:_MAX_ROWS=4`: both
+        if part == "old_tile":
+            fa._MAX_BLOCK, fa._MAX_ROWS = 128, 1
+        elif part.startswith("tile:"):
+            for pair in part[len("tile:"):].split(";"):
+                name, value = pair.split("=")
+                if (fa, name) not in saved:
+                    raise SystemExit("flash_parts: no constant %r" % name)
+                setattr(fa, name, int(value))
+        elif part == "w_fused":
+            bert._packed_projection = lambda a, n_heads: tuple(
+                fa.pack_qkv(*(a[kind + n] for n in "qkv"), n_heads)
+                for kind in "wb")
+        elif part == "split":
+            bert._encoder_layer = three_projection_layer(
+                fa.flash_attention_bshd, False)
+        elif part == "transposed":
+            bert._encoder_layer = three_projection_layer(
+                fa.flash_attention, True)
+        elif part == "parent":
+            spec = importlib.util.spec_from_file_location(
+                "mxnet_tpu.parallel._flash_attention_parent", parent_path)
+            parent = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(parent)
+            bert._encoder_layer = three_projection_layer(
+                parent.flash_attention, True)
+        elif part != "change":
+            raise SystemExit("flash_parts: no variant %r" % part)
 
     def undo():
         for (module, name), value in saved.items():
@@ -132,7 +161,7 @@ def main():
         loss.block_until_ready()
 
     for variant in variants:
-        undo = put_back(variant, opts.parent)
+        undo, ops = put_back(variant, opts.parent), {}
         try:
             before = dict(telemetry.snapshot()["counters"])
             start, batch = traffic.make(opts.seed, reference, cfg, mix)
@@ -173,9 +202,10 @@ def main():
                 kernels = {}
                 for key, s in dev["op_s"].items():
                     if key.startswith("mosaic/"):
-                        name = key[len("mosaic/"):].rsplit(".", 1)[0]
-                        kernels[name] = kernels.get(name, 0.0) + s * per
+                        kernel = key[len("mosaic/"):].rsplit(".", 1)[0]
+                        kernels[kernel] = kernels.get(kernel, 0.0) + s * per
                 line["kernel_ms"] = kernels
+                ops = {key: s * per for key, s in dev["op_s"].items()}
             runner.free()
             del runner
         except Exception as e:     # one variant refused: read the others
@@ -183,7 +213,9 @@ def main():
                     "error": repr(e)[-2000:]}
         finally:
             undo()
-        out.write(json.dumps(line) + "\n")
+        # every operation's time a step goes to the file alone: with the
+        # step's dumped module it says which product takes what
+        out.write(json.dumps(dict(line, op_ms=ops)) + "\n")
         out.flush()
         print(json.dumps(line), flush=True)
     out.close()

@@ -11,7 +11,11 @@ arguments where the step hands them to its AOT cache (`_maybe_aot`, before
 anything runs), and lowers them as `ShapeDtypeStruct`s placed on a described
 `v5e:2x2` device (a mesh of them for a cell on four chips): the TPU compiler
 that is installed here builds the program the chip would run, in about a
-minute for ResNet-50. The second form reads a module that a chip run dumped
+minute for ResNet-50. A `ShardedTrainStep`'s jitted function carries the
+shardings of the CPU's mesh, whatever its arguments say, so that step is
+built again for a mesh of described chips (`rebuild_for_mesh`), with the
+flash kernels on: their gate asks the default backend, which is the CPU
+here. The second form reads a module that a chip run dumped
 (`XLA_FLAGS="--xla_dump_to=... --xla_dump_hlo_as_text
 --xla_dump_hlo_module_re=jit_run"`, the Gluon step's module; the sharded
 step's is `jit_step_fn`); its instruction names are the trace's. A step compiled here has the same structure under other numbers.
@@ -22,8 +26,13 @@ fusion is a convolution or matrix product with what XLA fused around it;
 `transpose(` in an `op_name` is the backward pass), then the fusions that
 read one large tensor and return only vectors: passes that a producer's
 epilogue could have carried. Bytes are what the instruction's operands and
-results hold, so a tensor that three fusions read counts three times; the
-second set of columns leaves out every array that the compiler placed in
+results hold, so a tensor that three fusions read counts three times. A
+Mosaic call is the exception: it moves the blocks its grid asks for, which
+may be a third of an array it was handed three times, so where the step
+was traced here its operands and results count the blocks that change from
+one grid step to the next (`mosaic_traffic`; a dumped module has no grid to
+read, and its calls count whole arrays). The second set of columns leaves
+out every array that the compiler placed in
 the chip's fast memory (`S(1)` in its layout), which moves no HBM byte, and
 its sum over the chip's 819 GB/s is the least time the step's memory
 traffic can take. It proves structure and counts bytes; a time or a rate
@@ -83,11 +92,13 @@ def entry_instructions(text):
     return out
 
 
-def traffic(instructions):
+def traffic(instructions, mosaic=None):
     """(instruction, sizes of each operand, sizes of each result) for every
     instruction that moves data: a tuple, a get-tuple-element, a parameter,
     a bitcast or a constant only names what another one holds, and the
-    `-done` half of an asynchronous copy is counted at its `-start`."""
+    `-done` half of an asynchronous copy is counted at its `-start`.
+    `mosaic`, from `mosaic_traffic`, gives a Mosaic call the bytes its grid
+    moves in place of its whole operands and results."""
     held = {ins["name"]: ins["results"] for ins in instructions}
     rows = []
     for ins in instructions:
@@ -99,6 +110,15 @@ def traffic(instructions):
         reads = [held[name][0] for name in ins["operands"]
                  if len(held.get(name, ())) == 1]
         writes = ins["results"]
+        if ins["opcode"] == "custom-call" and mosaic:
+            moved = mosaic.get((ins["name"].lstrip("%").rsplit(".", 1)[0],
+                                tuple(n for n, _ in reads),
+                                tuple(n for n, _ in writes)))
+            if moved:
+                reads, writes = ([(n, n if hbm else 0)
+                                  for n, (_, hbm) in zip(by_grid, whole)]
+                                 for by_grid, whole in zip(moved,
+                                                           (reads, writes)))
         if ins["opcode"] == "copy-start":
             writes = writes[:1]     # (the copy, its source again, a context)
         elif ins["opcode"] == "slice-start":
@@ -119,6 +139,8 @@ def category(ins):
         if "optimizer" in ins["op_name"]:
             return "loop fusions, optimizer"
         return "loop fusions, " + ("backward" if back else "forward")
+    if ins["opcode"] == "custom-call" and "pallas_call" in ins["op_name"]:
+        return "Mosaic calls, " + ("backward" if back else "forward")
     return "other (%s)" % ins["opcode"]
 
 
@@ -131,11 +153,11 @@ def vector_passes(rows):
             and not any(w >= LARGE for w, _ in writes)]
 
 
-def report(text):
+def report(text, mosaic=None):
     """Print the table; return its counts."""
     say = print
     instructions = entry_instructions(text)
-    rows = traffic(instructions)
+    rows = traffic(instructions, mosaic)
     table = {}
     for ins, reads, writes in rows:
         row = table.setdefault(category(ins), [0, 0, 0, 0, 0])
@@ -171,13 +193,68 @@ def report(text):
             "table": table}
 
 
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr and of the jaxprs inside
+    it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+def mosaic_traffic(jaxpr):
+    """{(kernel's name, bytes of each operand, bytes of each result):
+    (bytes its grid reads of each operand, bytes it writes of each result)}
+    for the Pallas calls of a traced step. The pipeline moves a block when
+    its index changes from one grid step to the next (the grid runs with
+    its last dimension fastest); an operand left in `ANY` memory is moved
+    by the kernel's own copies, which are not seen here, and counts
+    nothing."""
+    import jax
+    import numpy as np
+    out = {}
+    for eqn in _pallas_calls(jaxpr):
+        mapping = eqn.params["grid_mapping"]
+        if mapping.num_index_operands:      # indices from scalar prefetch
+            continue
+        steps = np.indices(mapping.grid).reshape(
+            len(mapping.grid), -1).astype(np.int32)
+        whole, moved = [], []
+        for block in mapping.block_mappings:
+            aval = block.array_aval
+            item = np.dtype(aval.dtype).itemsize
+            whole.append(item * int(np.prod(aval.shape)))
+            if getattr(block.transformed_block_aval, "memory_space",
+                       None) is not None:
+                moved.append(0)
+                continue
+            index_map = block.index_map_jaxpr
+            index = np.stack(jax.vmap(lambda *i: jax.core.eval_jaxpr(
+                index_map.jaxpr, index_map.consts, *i))(*steps))
+            fetches = 1 + int(np.any(index[:, 1:] != index[:, :-1],
+                                     axis=0).sum())
+            moved.append(fetches * item
+                         * int(np.prod(block.block_aval.shape)))
+        n = mapping.num_inputs
+        out[(eqn.params["name"], tuple(whole[:n]), tuple(whole[n:]))] = (
+            moved[:n], moved[n:])
+    return out
+
+
 class _Captured(Exception):
     pass
 
 
 def capture_step(workload, seed):
     """(jitted, args) of the cell's step, from its runner built on the CPU
-    at the real size; nothing of the step runs."""
+    at the real size; nothing of the step runs. A `ShardedTrainStep` comes
+    as (the step object, args): its jitted function is bound to the CPU's
+    mesh and has to be built again for another."""
     sys.path[:0] = [ROOT, BENCH_DIR]
     from harness import runners, traffic as mixes
     from harness.spec import Cell
@@ -196,7 +273,7 @@ def capture_step(workload, seed):
         raise _Captured
 
     def sharded(self, params, opt_state, batch, step_num, sig):
-        got["step"] = (self._compiled, (params, opt_state, batch, step_num))
+        got["step"] = (self, (params, opt_state, batch, step_num))
         raise _Captured
 
     FusedTrainStep._maybe_aot, ShardedTrainStep._maybe_aot = fused, sharded
@@ -207,10 +284,12 @@ def capture_step(workload, seed):
     return cell, got["step"]
 
 
-def compile_for_v5e(jitted, args):
-    """The step compiled by the TPU compiler for described v5e chips: every
-    argument a shape on the described device, or on a mesh of them under
-    the partition it has here."""
+def compile_for_v5e(step, args):
+    """(the step compiled by the TPU compiler for described v5e chips, what
+    its Mosaic calls move): every argument a shape on the described device,
+    or on a mesh of them under the partition it has here. `step` is a
+    jitted function, or a `ShardedTrainStep`, which is built again for the
+    described mesh."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
     os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
@@ -223,15 +302,30 @@ def compile_for_v5e(jitted, args):
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    leaves = [x for x in jax.tree_util.tree_leaves(args)
-              if hasattr(x, "shape")]
-    meshes = [x.sharding.mesh for x in leaves
-              if isinstance(getattr(x, "sharding", None), NamedSharding)
-              and x.sharding.mesh.size > 1]
-    mesh = None
-    if meshes:      # the same axes over as many described chips
-        mesh = Mesh(np.array(topo.devices[:meshes[0].size]).reshape(
-            meshes[0].devices.shape), meshes[0].axis_names)
+
+    def described_mesh(here):   # the same axes over as many described chips
+        return Mesh(np.array(topo.devices[:here.size]).reshape(
+            here.devices.shape), here.axis_names)
+
+    rebuilt = not hasattr(step, "lower")
+    if not rebuilt:
+        jitted = step
+        leaves = [x for x in jax.tree_util.tree_leaves(args)
+                  if hasattr(x, "shape")]
+        meshes = [x.sharding.mesh for x in leaves
+                  if isinstance(getattr(x, "sharding", None), NamedSharding)
+                  and x.sharding.mesh.size > 1]
+        mesh = described_mesh(meshes[0]) if meshes else None
+    else:
+        import mxnet_tpu  # noqa: F401 — the package before its submodule
+        # the kernels' gate asks the default backend, which is the CPU
+        sys.modules["mxnet_tpu.parallel.flash_attention"]._pallas_on = (
+            lambda: True)
+        mesh = described_mesh(step.mesh)
+        described_step = step.rebuild_for_mesh(mesh)
+        described_step._batch_proto = step._batch_proto
+        jitted = described_step._build(*args[:2])
+        args = args[:3] + (np.int32(args[3]),)
 
     def described(x):
         if not hasattr(x, "shape"):
@@ -239,14 +333,16 @@ def compile_for_v5e(jitted, args):
         here = getattr(x, "sharding", None)
         if mesh is None:
             there = SingleDeviceSharding(topo.devices[0])
-        elif isinstance(here, NamedSharding) and here.mesh.size > 1:
+        elif isinstance(here, NamedSharding) and (
+                rebuilt or here.mesh.size > 1):
             there = NamedSharding(mesh, here.spec)
         else:       # held by one chip here: on every chip of the mesh
             there = NamedSharding(mesh, PartitionSpec())
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=there)
 
     jax.config.update("jax_enable_compilation_cache", False)
-    return jitted.lower(*jax.tree_util.tree_map(described, args)).compile()
+    traced = jitted.trace(*jax.tree_util.tree_map(described, args))
+    return traced.lower().compile(), mosaic_traffic(traced.jaxpr.jaxpr)
 
 
 def main(argv=None):
@@ -257,13 +353,14 @@ def main(argv=None):
     ap.add_argument("--keep", help="write the compiled module's text here")
     ap.add_argument("--json", help="write the counts here")
     opts = ap.parse_args(argv)
+    mosaic = None
     if opts.hlo:
         opener = gzip.open if opts.hlo.endswith(".gz") else open
         with opener(opts.hlo, "rt") as f:
             text = f.read()
     else:
-        cell, (jitted, args) = capture_step(opts.workload, opts.seed)
-        compiled = compile_for_v5e(jitted, args)
+        cell, (step, args) = capture_step(opts.workload, opts.seed)
+        compiled, mosaic = compile_for_v5e(step, args)
         print("compiled for a described v5e:2x2 (%d chip(s)); no chip ran "
               "anything" % cell.chips)
         print("memory: %s" % (compiled.memory_analysis(),))
@@ -271,7 +368,7 @@ def main(argv=None):
         if opts.keep:
             with open(opts.keep, "w") as f:
                 f.write(text)
-    counts = report(text)
+    counts = report(text, mosaic)
     if opts.json:
         with open(opts.json, "w") as f:
             json.dump(counts, f)
