@@ -12,6 +12,7 @@ from . import llama
 from . import bert
 from . import resnet
 from . import dlrm
+from . import qwen3_next
 from .losses import linear_cross_entropy
 from .llama import (LlamaConfig, llama_init, llama_forward, llama_loss,
                     llama_prefill_paged, llama_decode_paged,
@@ -19,14 +20,18 @@ from .llama import (LlamaConfig, llama_init, llama_forward, llama_loss,
 from .bert import BertConfig, bert_init, bert_forward, bert_mlm_loss
 from .resnet import ResNetConfig, resnet_init, resnet_forward, resnet_loss
 from .dlrm import DLRMConfig, dlrm_init, dlrm_forward, dlrm_loss
+from .qwen3_next import (Qwen3NextConfig, qwen3_next_init,
+                         qwen3_next_forward, qwen3_next_loss)
 
 __all__ = [
-    "llama", "bert", "resnet", "dlrm",
+    "llama", "bert", "resnet", "dlrm", "qwen3_next",
     "LlamaConfig", "llama_init", "llama_forward", "llama_loss",
     "llama_prefill_paged", "llama_decode_paged", "llama_chunk_paged",
     "llama_draft_loop", "init_kv_pools",
     "BertConfig", "bert_init", "bert_forward", "bert_mlm_loss",
     "ResNetConfig", "resnet_init", "resnet_forward", "resnet_loss",
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_loss",
+    "Qwen3NextConfig", "qwen3_next_init", "qwen3_next_forward",
+    "qwen3_next_loss",
     "linear_cross_entropy",
 ]

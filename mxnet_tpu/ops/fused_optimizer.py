@@ -49,7 +49,6 @@ perf evidence (the interpreter serializes the grid).
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as _np
 
@@ -72,15 +71,11 @@ _MAX_TILE_ROWS = 1024    # 1024x128 f32 tile = 512 KB; <=6 operand tiles
                          # + outputs stay well inside the 16 MB VMEM
 
 
-def _interpret():
-    return os.environ.get("MXNET_FLASH_INTERPRET", "0") == "1"
-
-
 def use_pallas_flat():
     """Is the Pallas optimizer path requested? Interpreter runs always take
     it (that is what they test); compiled runs need the TPU backend plus
     the MXNET_TPU_USE_PALLAS opt-in."""
-    if _interpret():
+    if _pstats.interpret():
         return True
     if jax.default_backend() != "tpu":
         return False
@@ -279,7 +274,7 @@ def _launch(kernel, tiles, scal, out_dtypes, tile_rows, grid, rows,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=_interpret(),
+        interpret=_pstats.interpret(),
         compiler_params=cparams,
     )(*tiles, scal)
 

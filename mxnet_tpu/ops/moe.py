@@ -1,0 +1,359 @@
+"""A mixture-of-experts layer for the chip that holds some of the experts.
+
+The layer is told which experts it holds (`first_expert`, and as many as its
+weights have): it routes over ALL experts, as every chip of the deployment
+does on its own tokens, and adds up what its own experts give. What the
+absent experts would add is another chip's to compute and to send; on one
+chip the layer runs without that exchange, and nothing stands in for it.
+
+    p = softmax(x W_r) over all experts, in float32 at `highest` (the choice
+        of ten in 512 is discrete: a rounded product flips it)
+    the top_k largest, their weights divided by their sum
+    E_e(x) = (silu(x G_e) * (x U_e)) D_e
+    routed(x) = sum over the chosen experts e held here of p_e E_e(x)
+
+Dispatch has static shapes and no per-expert capacity. The (token, expert)
+pairs that fall on held experts are laid out by expert in one buffer of
+`rows_bound + n_held * row_tile` rows in which every expert's rows start on
+a tile boundary (an expert without rows keeps one empty tile, so that its
+weight gradient is written). No pair is dropped, whatever the routing, while
+the pairs on held experts number at most `rows_bound`; `None` takes the most
+there can be, tokens x min(top_k, n_held). Past the bound the buffer cannot
+hold the pairs of the last experts: `Dispatch.n_dropped` counts them, and
+`moe_routed` then returns NaN for every token, so that the step's loss says
+at once that the layer computed another function (a larger bound cures it).
+The buffer is described by one expert id a row tile, which the kernels read
+through scalar prefetch.
+
+The grouped product is two Pallas kernels, named for the device trace:
+
+    moe_gmm    out[rows of e] = lhs[rows of e] @ rhs[e]      (and @ rhs[e].T,
+               the gradient to the rows)
+    moe_tgmm   out[e] = lhs[rows of e].T @ dy[rows of e]     (the gradient to
+               the weights)
+
+Both visit only the tiles that hold rows (`moe_gmm` writes zeros into the
+others, so nothing downstream reads an unwritten row) and take the weights as
+they are stored, casting a block to the rows' type in VMEM. `grouped_matmul`
+carries the `custom_vjp`. Where the kernels are on (`pallas_stats.pallas_on`)
+and refuse a shape or a type, the same function is a loop over experts in
+XLA, and counted (`ops.pallas.fallback.moe_gmm.<reason>`); off the TPU and
+without MXNET_FLASH_INTERPRET=1 no kernel was asked for, the loop runs and
+nothing is counted, as with the flash kernels.
+"""
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_stats
+from .pallas_stats import compiler_params, note_dispatch, note_fallback
+
+__all__ = ["route_top_k", "plan_dispatch", "grouped_matmul", "moe_routed",
+           "Dispatch"]
+
+F32 = jnp.float32
+ROW_TILE = 128          # rows of a tile: one pass of the MXU's 128 columns
+_BLOCK_ELEMENTS = 1 << 19   # of a weight block: 2 MB in float32
+
+
+# ------------------------------------------------------------------ routing
+def _largest(p, k):
+    """(values (T, k), indices (T, k)) of the k largest of each row of
+    p >= 0, largest first, the lower index first among equals: k rounds of
+    a row maximum. `lax.top_k` sorts whole rows on the TPU (8,192 rows of
+    512 with their indices, once a layer and once more where it is
+    recomputed); ten maxima read the 16 MB ten times."""
+    column = lax.broadcasted_iota(jnp.int32, p.shape, 1)
+    values, indices = [], []
+    for _ in range(k):
+        index = jnp.argmax(p, axis=-1).astype(jnp.int32)
+        values.append(jnp.max(p, axis=-1))
+        indices.append(index)
+        p = jnp.where(column == index[:, None], -1.0, p)
+    return jnp.stack(values, axis=-1), jnp.stack(indices, axis=-1)
+
+
+def route_top_k(x, w_router, top_k):
+    """(weights (T, top_k) float32 summing to 1 a token, expert ids (T,
+    top_k) int32) over all the experts `w_router` (d, n_experts) has."""
+    logits = jnp.dot(x.astype(F32), w_router.astype(F32),
+                     precision=lax.Precision.HIGHEST)
+    top, ids = _largest(jax.nn.softmax(logits, axis=-1), top_k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), ids
+
+
+class Dispatch(NamedTuple):
+    """One routing laid out in the buffer of R rows."""
+    row_pair: jax.Array     # (R,) the flat (token * top_k + slot) pair a
+    #                         row holds, 0 where it holds none
+    row_valid: jax.Array    # (R,) bool
+    tile_expert: jax.Array  # (R / row_tile,) the held expert of each tile
+    n_used: jax.Array       # (1,) the tiles in use
+    n_dropped: jax.Array    # () the pairs on held experts that found no
+    #                         row: 0 unless they number over `rows_bound`
+
+
+def plan_dispatch(ids, n_held, first_expert=0, rows_bound=None,
+                  row_tile=ROW_TILE):
+    """Where each routed pair goes. ids (T, top_k) expert ids over all
+    experts; the experts held are first_expert .. first_expert + n_held - 1.
+    A counting sort: a pair's place among its expert's pairs is a running
+    count, and one scatter of pair numbers fills the buffer (no sort: XLA
+    takes 15 s to compile one of this length for the TPU)."""
+    T, top_k = ids.shape
+    if rows_bound is None:
+        rows_bound = T * min(top_k, n_held)
+    n_tiles = -(-rows_bound // row_tile) + n_held
+    local = ids.reshape(-1) - first_expert
+    held = (local >= 0) & (local < n_held)
+    local = jnp.where(held, local, 0)
+    running = jnp.cumsum(held[:, None] & (local[:, None] == jnp.arange(
+        n_held)[None, :]), axis=0, dtype=jnp.int32)
+    counts = running[-1]
+    # every expert keeps a tile, the last ones too where the buffer is full
+    tile_end = jnp.minimum(
+        jnp.cumsum(jnp.maximum(1, -(-counts // row_tile))),
+        n_tiles - (n_held - 1 - jnp.arange(n_held)))
+    tiles = jnp.diff(tile_end, prepend=0)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+        n_held - 1).astype(jnp.int32)
+    within = jnp.take_along_axis(running, local[:, None], axis=1)[:, 0] - 1
+    fits = held & (within < (tiles * row_tile)[local])
+    row = jnp.where(fits, (tile_end - tiles)[local] * row_tile + within,
+                    n_tiles * row_tile)
+    pairs = jnp.arange(T * top_k, dtype=jnp.int32)
+    row_pair = jnp.full((n_tiles * row_tile,), -1, jnp.int32).at[row].set(
+        pairs, mode="drop", unique_indices=True)
+    valid = row_pair >= 0
+    return Dispatch(jnp.where(valid, row_pair, 0), valid, tile_expert,
+                    tile_end[-1:].astype(jnp.int32),
+                    jnp.sum(held & ~fits, dtype=jnp.int32))
+
+
+# ------------------------------------------------------------------ kernels
+def _column_block(cols, contraction):
+    """Columns of a weight block: all of a narrow matrix, else the multiple
+    of 128 dividing `cols` that keeps the block at `_BLOCK_ELEMENTS`."""
+    if cols % 128 or cols * contraction <= _BLOCK_ELEMENTS:
+        return cols
+    best = 128
+    for tn in range(128, cols + 1, 128):
+        if cols % tn == 0 and tn * contraction <= _BLOCK_ELEMENTS:
+            best = tn
+    return best
+
+
+def _gmm_kernel(tile_expert, n_used, lhs_ref, rhs_ref, out_ref, *,
+                transpose_rhs):
+    del tile_expert
+    used = pl.program_id(1) < n_used[0]
+
+    @pl.when(used)
+    def _():
+        dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+        out_ref[...] = lax.dot_general(
+            lhs_ref[...], rhs_ref[0].astype(lhs_ref.dtype), dims,
+            preferred_element_type=F32).astype(out_ref.dtype)
+
+    @pl.when(jnp.logical_not(used))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _tgmm_kernel(tile_expert, n_used, lhs_ref, dy_ref, out_ref):
+    i = pl.program_id(1)
+    first = (i == 0) | (tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)])
+
+    @pl.when(i < n_used[0])
+    def _():
+        # the transpose in float32, where Mosaic has one for every shape
+        lhs_t = lhs_ref[...].astype(F32).T.astype(lhs_ref.dtype)
+        acc = lax.dot_general(lhs_t, dy_ref[...], (((1,), (0,)), ((), ())),
+                              preferred_element_type=F32)
+
+        @pl.when(first)
+        def _():
+            out_ref[0] = acc.astype(out_ref.dtype)
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            out_ref[0] += acc.astype(out_ref.dtype)
+
+
+def _last_used(i, n_used):
+    """Tile i, or the last tile in use for the steps after it: no block is
+    fetched for them."""
+    return jnp.minimum(i, n_used[0] - 1)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _gmm(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile, interpret):
+    """out (R, N): each tile of lhs (R, K) times its expert's rhs[e] (K, N),
+    or times rhs[e].T where rhs is (E, N, K) and `transpose_rhs`."""
+    R, K = lhs.shape
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _column_block(N, K)
+
+    def rhs_map(c, i, tile_expert, n_used):
+        e = tile_expert[_last_used(i, n_used)]
+        return (e, c, 0) if transpose_rhs else (e, 0, c)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N // tn, R // row_tile),
+            in_specs=[pl.BlockSpec((row_tile, K), lambda c, i, t, n:
+                                   (_last_used(i, n), 0)),
+                      pl.BlockSpec((1, tn, K) if transpose_rhs
+                                   else (1, K, tn), rhs_map)],
+            out_specs=pl.BlockSpec((row_tile, tn), lambda c, i, t, n: (i, c))),
+        out_shape=jax.ShapeDtypeStruct((R, N), lhs.dtype),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
+        interpret=interpret,
+        name="moe_gmm",     # the HLO instruction, and so the device trace
+    )(tile_expert, n_used, lhs, rhs)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _tgmm(lhs, dy, tile_expert, n_used, n_experts, out_dtype, row_tile,
+          interpret):
+    """out (E, K, N): out[e] = lhs[rows of e].T @ dy[rows of e]."""
+    R, K = lhs.shape
+    N = dy.shape[1]
+    tn = _column_block(N, K)
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N // tn, R // row_tile),
+            in_specs=[pl.BlockSpec((row_tile, K), lambda c, i, t, n:
+                                   (_last_used(i, n), 0)),
+                      pl.BlockSpec((row_tile, tn), lambda c, i, t, n:
+                                   (_last_used(i, n), c))],
+            out_specs=pl.BlockSpec((1, K, tn), lambda c, i, t, n:
+                                   (t[_last_used(i, n)], 0, c))),
+        out_shape=jax.ShapeDtypeStruct((n_experts, K, N), out_dtype),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
+        interpret=interpret,
+        name="moe_tgmm",
+    )(tile_expert, n_used, lhs, dy)
+
+
+def _row_masks(tile_expert, n_used, row_tile, n_experts):
+    """(R, E) bool: the rows of each expert, tiles in use only."""
+    tile = jnp.arange(tile_expert.shape[0])
+    expert = jnp.where(tile < n_used[0], tile_expert, n_experts)
+    return (jnp.repeat(expert, row_tile)[:, None]
+            == jnp.arange(n_experts)[None, :])
+
+
+def _gmm_loop(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile):
+    """The grouped product as a loop over experts in XLA: every expert over
+    every row, kept where the row is its own."""
+    masks = _row_masks(tile_expert, n_used, row_tile, rhs.shape[0])
+    out = 0.0
+    for e in range(rhs.shape[0]):
+        w = rhs[e].astype(lhs.dtype)
+        y = jnp.dot(lhs, w.T if transpose_rhs else w,
+                    preferred_element_type=F32)
+        out = out + jnp.where(masks[:, e:e + 1], y, 0.0)
+    return out.astype(lhs.dtype)
+
+
+def _tgmm_loop(lhs, dy, tile_expert, n_used, n_experts, out_dtype, row_tile):
+    masks = _row_masks(tile_expert, n_used, row_tile, n_experts)
+    return jnp.stack([
+        jnp.dot(jnp.where(masks[:, e:e + 1], lhs, 0).T, dy,
+                preferred_element_type=F32)
+        for e in range(n_experts)]).astype(out_dtype)
+
+
+def _kernel_reason(lhs, rhs, row_tile):
+    """None where the kernels take the shapes, else a word for the fallback
+    counter."""
+    if not pallas_stats.pallas_on():
+        return "backend"
+    if lhs.shape[0] % row_tile or row_tile % 8:
+        return "row_tile"
+    if lhs.dtype.itemsize > rhs.dtype.itemsize:
+        return "dtype"
+    return None
+
+
+def _product(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile, pallas):
+    if pallas:
+        return _gmm(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile,
+                    pallas_stats.interpret())
+    return _gmm_loop(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _grouped(lhs, rhs, tile_expert, n_used, row_tile, pallas):
+    return _product(lhs, rhs, tile_expert, n_used, False, row_tile, pallas)
+
+
+def _grouped_fwd(lhs, rhs, tile_expert, n_used, row_tile, pallas):
+    return (_product(lhs, rhs, tile_expert, n_used, False, row_tile, pallas),
+            (lhs, rhs, tile_expert, n_used))
+
+
+def _grouped_bwd(row_tile, pallas, res, dy):
+    lhs, rhs, tile_expert, n_used = res
+    dy = dy.astype(lhs.dtype)
+    dlhs = _product(dy, rhs, tile_expert, n_used, True, row_tile, pallas)
+    if pallas:
+        drhs = _tgmm(lhs, dy, tile_expert, n_used, rhs.shape[0], rhs.dtype,
+                     row_tile, pallas_stats.interpret())
+    else:
+        drhs = _tgmm_loop(lhs, dy, tile_expert, n_used, rhs.shape[0],
+                          rhs.dtype, row_tile)
+    return dlhs, drhs, None, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(lhs, rhs, tile_expert, n_used, row_tile=ROW_TILE):
+    """Each row tile of lhs (R, K) times the weights of the expert it
+    belongs to, rhs (E, K, N): (R, N) in lhs's type, float32 out of the MXU,
+    zeros in the tiles past `n_used`. Differentiable in lhs and rhs; the
+    gradient to rhs has rhs's type (float32 weights: not rounded)."""
+    reason = _kernel_reason(lhs, rhs, row_tile)
+    if reason is None:
+        note_dispatch("moe_gmm")
+    elif reason != "backend":
+        note_fallback("moe_gmm", reason)
+    return _grouped(lhs, rhs, tile_expert, n_used, row_tile, reason is None)
+
+
+# ---------------------------------------------------------------- the layer
+def moe_routed(x, w_router, w_gate, w_up, w_down, top_k, first_expert=0,
+               rows_bound=None, row_tile=ROW_TILE):
+    """What the experts held here add for the tokens x (T, d): the sum over
+    a token's chosen experts e in first_expert .. first_expert + E - 1 of
+    p_e E_e(x), in x's type. w_router (d, n_experts) over all experts;
+    w_gate, w_up (E, d, f) and w_down (E, f, d) of the E held. NaN
+    throughout where the routing put more than `rows_bound` pairs here."""
+    T, d = x.shape
+    weights, ids = route_top_k(x, w_router, top_k)
+    plan = plan_dispatch(ids, w_gate.shape[0], first_expert, rows_bound,
+                         row_tile)
+    token = plan.row_pair // top_k
+    rows = x[token]
+    gate = grouped_matmul(rows, w_gate, plan.tile_expert, plan.n_used,
+                          row_tile)
+    up = grouped_matmul(rows, w_up, plan.tile_expert, plan.n_used, row_tile)
+    hidden = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
+    out = grouped_matmul(hidden, w_down, plan.tile_expert, plan.n_used,
+                         row_tile)
+    share = jnp.where(plan.row_valid, weights.reshape(-1)[plan.row_pair], 0.0)
+    # rows of one token lie in different experts' tiles: added up in float32
+    combined = jnp.zeros((T, d), F32).at[token].add(
+        out.astype(F32) * share[:, None])
+    return jnp.where(plan.n_dropped > 0, jnp.nan, combined).astype(x.dtype)

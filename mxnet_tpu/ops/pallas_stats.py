@@ -22,10 +22,29 @@ renders the table.
 from __future__ import annotations
 
 import contextlib
+import os
 import time
 
+import jax
+
 __all__ = ["note_dispatch", "note_fallback", "kernel_span",
-           "compiler_params", "use_pallas"]
+           "compiler_params", "use_pallas", "interpret", "pallas_on"]
+
+
+def interpret():
+    """MXNET_FLASH_INTERPRET=1: every kernel runs through the Pallas
+    interpreter (the CPU tests' arithmetic check)."""
+    return os.environ.get("MXNET_FLASH_INTERPRET", "0") == "1"
+
+
+def pallas_on():
+    """The gate of the kernels that are on by default (flash attention, the
+    grouped product of `ops/moe.py`), asked at every call: on the TPU and
+    under the interpreter. A tool that compiles for a described chip from a
+    CPU process turns it on here, for all of them at once."""
+    if os.environ.get("MXNET_FLASH_DISABLE", "0") == "1":
+        return False            # force the plain-XLA path (A/B probes)
+    return interpret() or jax.default_backend() == "tpu"
 
 
 def compiler_params(semantics, vmem_limit_bytes=None):

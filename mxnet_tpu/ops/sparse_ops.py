@@ -35,7 +35,6 @@ FCompute<tpu> hook) sees it like every other specialized op.
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as _np
 
@@ -56,16 +55,12 @@ _MAX_TILE_NNZ = 512        # 512 x D gradient rows streamed per grid step
 _VMEM_BUDGET = 8 << 20     # slab + one tile must fit well under 16 MB
 
 
-def _interpret():
-    return os.environ.get("MXNET_FLASH_INTERPRET", "0") == "1"
-
-
 def use_pallas_sparse():
     """Is the Pallas sparse path requested? Same gate shape as
     `fused_optimizer.use_pallas_flat`: interpreter runs always take it,
     compiled runs need the TPU backend plus the MXNET_TPU_USE_PALLAS
     opt-in."""
-    if _interpret():
+    if _pstats.interpret():
         return True
     if jax.default_backend() != "tpu":
         return False
@@ -137,7 +132,7 @@ def _segment_sum_pallas_impl(nnz, dim, num_segments, dtype):
             out_specs=pl.BlockSpec((seg_p, dim_p), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((seg_p, dim_p), dtype),
             compiler_params=_compiler_params(("arbitrary",)),
-            interpret=_interpret(),
+            interpret=_pstats.interpret(),
         )(ids_p, vals2d)
         return out[:num_segments, :dim]
     return impl

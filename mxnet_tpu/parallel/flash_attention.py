@@ -70,7 +70,6 @@ on CPU (the test suite uses this to pin kernel correctness without a chip).
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -79,6 +78,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..ops import pallas_stats
 from ..ops.pallas_stats import compiler_params, note_dispatch, note_fallback
 
 __all__ = ["flash_attention", "flash_attention_bshd",
@@ -97,10 +97,6 @@ _VMEM_LIMIT = 16 << 20  # what the kernels ask Mosaic for (its own default
 #                         has to clear what a Mosaic call asks for: asking
 #                         for 48 MiB cost BERT-base's step 1.9-4.9 ms of 98,
 #                         for 32 MiB 3.9 ms (PERF.md, PR 32)
-
-
-def _interpret():
-    return os.environ.get("MXNET_FLASH_INTERPRET", "0") == "1"
 
 
 def _ref_attention(q, k, v, causal, sm_scale):
@@ -732,19 +728,13 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
     return dq, dk, dv
 
 
-def _pallas_on():
-    if os.environ.get("MXNET_FLASH_DISABLE", "0") == "1":
-        return False            # force the plain-XLA path (A/B probes)
-    return _interpret() or jax.default_backend() == "tpu"
-
-
 # ------------------------------------------------- the (B, H, S, D) arguments
 def _pallas_forward(q, k, v, causal, sm_scale):
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     o, lse = _forward("bhsd", q.reshape(B * H, Sq, D),
                       k.reshape(B * Hkv, Sk, D), v.reshape(B * Hkv, Sk, D),
-                      H, Hkv, causal, sm_scale, _interpret())
+                      H, Hkv, causal, sm_scale, pallas_stats.interpret())
     return o.reshape(q.shape), lse.reshape(B, H, Sq)
 
 
@@ -764,7 +754,7 @@ def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale):
         "bhsd", q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
         v.reshape(B * Hkv, Sk, D), lse.reshape(B * H, 1, 1, Sq),
         delta.reshape(B * H, 1, 1, Sq), do.reshape(B * H, Sq, D), H, Hkv,
-        causal, sm_scale, _interpret())
+        causal, sm_scale, pallas_stats.interpret())
     group = H // Hkv
     dk = dk.reshape(B, Hkv, group, Sk, D)
     dv = dv.reshape(B, Hkv, group, Sk, D)
@@ -780,7 +770,7 @@ def _use_pallas(q, k):
     # head count would make the kv BlockSpec silently clamp to a wrong
     # head).
     B, H, Sq, D = q.shape
-    return _pallas_on() and isinstance(_choose_tile(
+    return pallas_stats.pallas_on() and isinstance(_choose_tile(
         "bhsd", B, H, k.shape[1], Sq, k.shape[2], D, q.dtype.itemsize), _Tile)
 
 
@@ -831,12 +821,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
 def _flash_bshd(q, k, v, H, Hkv, causal, sm_scale):
     """On the (B, S, H*D) view, always through the kernels."""
     return _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
-                    _interpret())[0]
+                    pallas_stats.interpret())[0]
 
 
 def _flash_bshd_fwd(q, k, v, H, Hkv, causal, sm_scale):
     o, lse = _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
-                      _interpret())
+                      pallas_stats.interpret())
     return o, (q, k, v, o, lse)
 
 
@@ -859,7 +849,7 @@ def _head_delta(do, o, H):
 def _flash_bshd_bwd(H, Hkv, causal, sm_scale, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _backward("bshd", q, k, v, lse, _head_delta(do, o, H), do,
-                           H, Hkv, causal, sm_scale, _interpret())
+                           H, Hkv, causal, sm_scale, pallas_stats.interpret())
     if H != Hkv:
         B, Sk = k.shape[:2]
         dk = dk.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
@@ -882,7 +872,7 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None):
     if sm_scale is None:
         sm_scale = D ** -0.5
     causal, sm_scale = bool(causal), float(sm_scale)
-    if _pallas_on():
+    if pallas_stats.pallas_on():
         tile = _choose_tile("bshd", B, H, Hkv, Sq, Sk, D, q.dtype.itemsize)
         if isinstance(tile, _Tile):
             note_dispatch("flash_bshd")
@@ -940,7 +930,7 @@ def _flash_packed(qkv, bias, H, causal, sm_scale):
 def _flash_packed_fwd(qkv, bias, H, causal, sm_scale):
     qkv = qkv + bias        # XLA fuses it into the product that made qkv
     o, lse = _forward("packed", qkv, qkv, qkv, H, H, causal, sm_scale,
-                      _interpret())
+                      pallas_stats.interpret())
     return o, (qkv, o, lse, bias)
 
 
@@ -948,7 +938,7 @@ def _flash_packed_bwd(H, causal, sm_scale, res, do):
     qkv, o, lse, bias = res
     dqkv, sums = _backward("packed", qkv, qkv, qkv, lse,
                            _head_delta(do, o, H), do, H, H, causal, sm_scale,
-                           _interpret())
+                           pallas_stats.interpret())
     return dqkv, sums.astype(bias.dtype)
 
 
@@ -983,7 +973,7 @@ def flash_attention_packed(qkv, n_heads, causal=False, sm_scale=None,
     causal, sm_scale = bool(causal), float(sm_scale)
     if bias is None:
         bias = jnp.zeros((width,), qkv.dtype)
-    if _pallas_on():
+    if pallas_stats.pallas_on():
         tile = _choose_tile("packed", B, n_heads, n_heads, S, S, D,
                             qkv.dtype.itemsize)
         if isinstance(tile, _Tile):

@@ -33,7 +33,11 @@ class MeshConfig:
            per-layer on use. Merged with `data` for plain DP when 1.
     model: tensor-parallel axis (Megatron column/row splits).
     seq:   sequence/context-parallel axis (ring attention).
-    expert: expert-parallel axis (MoE all_to_all).
+    expert: expert-parallel axis. Declared, and still unused: the one
+           expert layer (`ops/moe.py`, `models/qwen3_next.py`) is told
+           which experts its chip holds and runs on one chip without the
+           exchange; no `all_to_all` over this axis and no rule in
+           `parallel/sharding.py` places experts on it yet.
     """
     data: int = 1
     fsdp: int = 1

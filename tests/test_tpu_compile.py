@@ -128,10 +128,9 @@ def test_bert_layer_has_one_packed_projection_the_kernels_read_in_place(
     dx product and one dW product and by nothing else: the bias gradient
     comes out of the kernels. Nothing of an activation's size is sliced,
     copied, reduced or concatenated on the way."""
-    import sys
     from mxnet_tpu.models import bert
-    fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
-    monkeypatch.setattr(fa, "_pallas_on", lambda: True)
+    from mxnet_tpu.ops import pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
     cfg = bert.BertConfig(dtype=jnp.float32)
 
     def shape(x):
@@ -216,8 +215,9 @@ def test_flash_kernels_compile_within_the_vmem_they_ask_for(
     whose estimate is too low has to fail here and not on the chip."""
     import sys
     import mxnet_tpu  # noqa: F401
+    from mxnet_tpu.ops import pallas_stats
     fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
-    monkeypatch.setattr(fa, "_pallas_on", lambda: True)
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
     B, S, H, Hkv, D = dims
 
     def shape(*dims_):
@@ -246,3 +246,30 @@ def test_flash_kernels_compile_within_the_vmem_they_ask_for(
         lambda *a: attend(*a).astype(jnp.float32).sum(),
         argnums=tuple(range(len(args)))), *args)
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_grouped_product_kernels_compile_at_the_cells_shapes(
+        one_chip, monkeypatch, dtype):
+    """`moe_gmm` and `moe_tgmm` at `qwen3_next_ep16_s4096`'s shapes (12,288
+    rows of 2,048, 32 held experts of width 512, float32 weights cast a
+    block at a time), forward and backward, compiled by Mosaic for the
+    described v5e: the forward, the gradient to the rows and the gradient
+    to the weights are one named call each, behind the same gate as the
+    flash kernels."""
+    from mxnet_tpu.ops import moe, pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    args = (shape((12288, 2048), dtype), shape((32, 2048, 512), "float32"),
+            shape((96,), "int32"), shape((1,), "int32"))
+    text = _compiled_text(jax.grad(
+        lambda rows, w, tile_expert, n_used: (moe.grouped_matmul(
+            rows, w, tile_expert, n_used).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1)), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # the instructions carry the kernels' names, as the device trace does
+    calls = re.findall(r"%(moe_t?gmm)[.\d]* = \S+ custom-call\(", text)
+    assert sorted(calls) == ["moe_gmm", "moe_gmm", "moe_tgmm"], calls

@@ -317,10 +317,9 @@ def compile_for_v5e(step, args):
                   and x.sharding.mesh.size > 1]
         mesh = described_mesh(meshes[0]) if meshes else None
     else:
-        import mxnet_tpu  # noqa: F401 — the package before its submodule
+        from mxnet_tpu.ops import pallas_stats
         # the kernels' gate asks the default backend, which is the CPU
-        sys.modules["mxnet_tpu.parallel.flash_attention"]._pallas_on = (
-            lambda: True)
+        pallas_stats.pallas_on = lambda: True
         mesh = described_mesh(step.mesh)
         described_step = step.rebuild_for_mesh(mesh)
         described_step._batch_proto = step._batch_proto
