@@ -25,6 +25,36 @@ at once that the layer computed another function (a larger bound cures it).
 The buffer is described by one expert id a row tile, which the kernels read
 through scalar prefetch.
 
+The ladder. The buffer is as long as the worst routing needs, and most
+routings fill a small part of it: the held experts' rows lie one after
+another from row 0, so the rows in use are a prefix, `Dispatch.n_used` tiles
+long. Everything that touches the buffer (the row gather, the three grouped
+products, `silu(gate) * up`, the router's share, the float32 scatter-add, and
+their backward passes) therefore runs over a prefix of it, chosen on the
+device: `prefix_ladder` gives a few static lengths from shapes alone, the
+whole buffer and its halves for as long as a half still holds `_HEADROOM`
+times what uniform routing sends the held experts with every expert's
+alignment tile, and a `lax.switch` takes the smallest that holds `n_used`.
+The longest rung is the whole buffer, so no routing drops a pair that the
+buffer held; a buffer too short to halve has one rung and no conditional. A
+rung's body is
+`_routed_rows`, under `jax.named_scope("rows_<R>")` (every instruction of
+the compiled module says which rung it belongs to: `tools/moe_rungs.py`
+reads from a device trace how often each ran) and counted once a trace as
+`ops.moe.ladder.<R>`.
+
+The derivative of that switch is one `custom_vjp` (`_routed`) with a switch
+in each direction. Differentiating `lax.switch` as it stands makes every
+branch return the union of all branches' residuals, zero-filled where a
+branch has none: every rung would write buffers of the longest rung's size.
+So the forward rule keeps the layer's inputs and the plan and nothing else,
+and the backward rule is a switch whose branch takes `jax.vjp` of its own
+rung's body: it makes that rung's forward again and pulls the cotangent back
+through it. Under `jax.checkpoint`, which is how the decoder calls the
+layer, the recomputed forward switch is dead and the products run as often
+as without the ladder; differentiated without it, the rung's forward runs
+twice.
+
 The grouped product is two Pallas kernels, named for the device trace:
 
     moe_gmm    out[rows of e] = lhs[rows of e] @ rhs[e]      (and @ rhs[e].T,
@@ -61,6 +91,13 @@ __all__ = ["route_top_k", "plan_dispatch", "grouped_matmul", "moe_routed",
 F32 = jnp.float32
 ROW_TILE = 128          # rows of a tile: one pass of the MXU's 128 columns
 _BLOCK_ELEMENTS = 1 << 19   # of a weight block: 2 MB in float32
+# of the ladder's shortest rung over what uniform routing fills. A rung is a
+# copy of the layer's code in each direction, in every layer: about a second
+# of every start to restore and trace it (the benchmark's `setup_s`), and a
+# routing that sits at a rung's length flips between two rungs from step to
+# step. So the rungs are few, and the shortest stands well clear of where
+# routings sit (PERF.md section 6, PR 34, has the measurements).
+_HEADROOM = 4
 
 
 # ------------------------------------------------------------------ routing
@@ -332,6 +369,100 @@ def grouped_matmul(lhs, rhs, tile_expert, n_used, row_tile=ROW_TILE):
     return _grouped(lhs, rhs, tile_expert, n_used, row_tile, reason is None)
 
 
+# --------------------------------------------------------------- the ladder
+def prefix_ladder(n_tokens, top_k, n_held, n_experts, n_tiles,
+                  row_tile=ROW_TILE):
+    """The prefix lengths, in tiles and ascending, that the layer may work
+    over: the whole buffer (`n_tiles`), and its halves for as long as one
+    still holds `_HEADROOM` times what uniform routing over `n_experts`
+    sends the `n_held` held, with every held expert's alignment tile. From
+    shapes alone."""
+    expected = -(-n_tokens * top_k * n_held // (n_experts * row_tile)) + n_held
+    rungs = [n_tiles]
+    while rungs[-1] > 1 and -(-rungs[-1] // 2) >= _HEADROOM * expected:
+        rungs.append(-(-rungs[-1] // 2))
+    return tuple(reversed(rungs))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "top_k", "row_tile",
+                                             "gate"))
+def _routed_rows(x, weights, w_gate, w_up, w_down, plan, *, rows, top_k,
+                 row_tile, gate):
+    """(T, d) float32: the held experts' sum over the first `rows` rows of
+    the buffer, which hold every pair (the caller chose them so). `gate` is
+    what `pallas_stats` answered the caller: `grouped_matmul` asks it again
+    while this is traced, so it belongs to the trace's key. Jitted here so
+    that a model's layers share one trace of each rung."""
+    del gate
+    pallas_stats.note_rung(rows)
+    with jax.named_scope("rows_%d" % rows):
+        row_pair, row_valid = plan.row_pair[:rows], plan.row_valid[:rows]
+        tile_expert = plan.tile_expert[:rows // row_tile]
+        token = row_pair // top_k
+        picked = x[token]
+        gate_out = grouped_matmul(picked, w_gate, tile_expert, plan.n_used,
+                                  row_tile)
+        up = grouped_matmul(picked, w_up, tile_expert, plan.n_used, row_tile)
+        hidden = (jax.nn.silu(gate_out.astype(F32)) * up.astype(F32)
+                  ).astype(x.dtype)
+        out = grouped_matmul(hidden, w_down, tile_expert, plan.n_used,
+                             row_tile)
+        share = jnp.where(row_valid, weights.reshape(-1)[row_pair], 0.0)
+        # rows of one token lie in different experts' tiles: added up in
+        # float32
+        return jnp.zeros(x.shape, F32).at[token].add(
+            out.astype(F32) * share[:, None])
+
+
+def _rung_index(ladder, n_used, row_tile):
+    """The smallest of `ladder`'s prefixes (rows, ascending) that holds
+    `n_used` tiles."""
+    return jnp.sum(n_used[0] * row_tile > jnp.asarray(ladder[:-1], jnp.int32),
+                   dtype=jnp.int32)
+
+
+def _over_ladder(body, ladder, plan, top_k, row_tile, gate, *operands):
+    """`body` of the smallest of `ladder`'s rungs that holds the routing,
+    chosen on the device."""
+    return lax.switch(
+        _rung_index(ladder, plan.n_used, row_tile),
+        [functools.partial(body, rows=rows, top_k=top_k, row_tile=row_tile,
+                           gate=gate) for rows in ladder], *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _routed(x, weights, w_gate, w_up, w_down, plan, ladder, top_k, row_tile,
+            gate):
+    """`_routed_rows` over a prefix from `ladder` (rows, ascending)."""
+    return _over_ladder(_routed_rows, ladder, plan, top_k, row_tile, gate,
+                        x, weights, w_gate, w_up, w_down, plan)
+
+
+def _routed_fwd(x, weights, w_gate, w_up, w_down, plan, *static):
+    inputs = (x, weights, w_gate, w_up, w_down)
+    return _routed(*inputs, plan, *static), (inputs, plan)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "top_k", "row_tile",
+                                             "gate"))
+def _routed_rows_vjp(inputs, plan, dy, **static):
+    """The cotangents of `_routed_rows`'s five inputs over one rung: that
+    rung's forward made again, and `dy` pulled back through it. Jitted here
+    for the same reason: one trace of each rung's backward a model."""
+    _, vjp = jax.vjp(lambda *inputs: _routed_rows(*inputs, plan, **static),
+                     *inputs)
+    return vjp(dy)
+
+
+def _routed_bwd(ladder, top_k, row_tile, gate, res, dy):
+    inputs, plan = res
+    return _over_ladder(_routed_rows_vjp, ladder, plan, top_k, row_tile, gate,
+                        inputs, plan, dy) + (None,)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 # ---------------------------------------------------------------- the layer
 def moe_routed(x, w_router, w_gate, w_up, w_down, top_k, first_expert=0,
                rows_bound=None, row_tile=ROW_TILE):
@@ -340,20 +471,17 @@ def moe_routed(x, w_router, w_gate, w_up, w_down, top_k, first_expert=0,
     p_e E_e(x), in x's type. w_router (d, n_experts) over all experts;
     w_gate, w_up (E, d, f) and w_down (E, f, d) of the E held. NaN
     throughout where the routing put more than `rows_bound` pairs here."""
-    T, d = x.shape
+    n_held = w_gate.shape[0]
     weights, ids = route_top_k(x, w_router, top_k)
-    plan = plan_dispatch(ids, w_gate.shape[0], first_expert, rows_bound,
-                         row_tile)
-    token = plan.row_pair // top_k
-    rows = x[token]
-    gate = grouped_matmul(rows, w_gate, plan.tile_expert, plan.n_used,
-                          row_tile)
-    up = grouped_matmul(rows, w_up, plan.tile_expert, plan.n_used, row_tile)
-    hidden = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
-    out = grouped_matmul(hidden, w_down, plan.tile_expert, plan.n_used,
-                         row_tile)
-    share = jnp.where(plan.row_valid, weights.reshape(-1)[plan.row_pair], 0.0)
-    # rows of one token lie in different experts' tiles: added up in float32
-    combined = jnp.zeros((T, d), F32).at[token].add(
-        out.astype(F32) * share[:, None])
+    plan = plan_dispatch(ids, n_held, first_expert, rows_bound, row_tile)
+    ladder = tuple(tiles * row_tile for tiles in prefix_ladder(
+        x.shape[0], top_k, n_held, w_router.shape[1],
+        plan.tile_expert.shape[0], row_tile))
+    gate = (pallas_stats.pallas_on(), pallas_stats.interpret())
+    args = (x, weights, w_gate, w_up, w_down, plan)
+    if len(ladder) == 1:    # too short to halve: plain autodiff, no recompute
+        combined = _routed_rows(*args, rows=ladder[0], top_k=top_k,
+                                row_tile=row_tile, gate=gate)
+    else:
+        combined = _routed(*args, ladder, top_k, row_tile, gate)
     return jnp.where(plan.n_dropped > 0, jnp.nan, combined).astype(x.dtype)

@@ -27,7 +27,7 @@ import time
 
 import jax
 
-__all__ = ["note_dispatch", "note_fallback", "kernel_span",
+__all__ = ["note_dispatch", "note_fallback", "note_rung", "kernel_span",
            "compiler_params", "use_pallas", "interpret", "pallas_on"]
 
 
@@ -82,6 +82,14 @@ def note_fallback(kernel, reason):
         _telem.inc("ops.pallas.fallback")
         _telem.inc("ops.pallas.fallback.%s" % reason)
         _telem.inc("ops.pallas.fallback.%s.%s" % (kernel, reason))
+
+
+def note_rung(rows):
+    """Count one traced body of `ops/moe.py`'s ladder: the expert layer
+    over the first `rows` rows of its buffer (`ops.moe.ladder.<rows>`)."""
+    from .. import telemetry as _telem
+    if _telem.ENABLED:
+        _telem.inc("ops.moe.ladder.%d" % rows)
 
 
 @contextlib.contextmanager

@@ -1,6 +1,7 @@
 """`ops.moe`: routing, the dispatch without dropped pairs, the grouped
 products `moe_gmm` / `moe_tgmm` in interpret mode against a loop over
-experts, and the shares of a layer adding up to the uncut layer. Float32 on
+experts, the ladder of buffer prefixes against the layer over the whole
+buffer, and the shares of a layer adding up to the uncut layer. Float32 on
 the CPU at toy sizes."""
 import os
 import sys
@@ -139,6 +140,152 @@ def test_the_layer_says_so_where_the_bound_is_passed(rows_bound, over):
     assert bool(jnp.all(jnp.isfinite(out))) != over
 
 
+# ------------------------------------------------------------- the ladder
+# 256 tokens, two experts each of 128, the first two held, tiles of 8 rows: a
+# buffer of 64 + 2 tiles, of which uniform routing wants 1 + 2
+LADDER_SHAPE = dict(T=256, d=32, f=16, E=2, n_experts=128, top_k=2, row_tile=8)
+LADDER = (17, 33, 66)
+
+
+def _full_length(x, router, w_gate, w_up, w_down, top_k, rows_bound,
+                 row_tile):
+    """The layer over its whole buffer, whatever the routing filled: what
+    `moe_routed` was before the ladder, kept here as the reference."""
+    weights, ids = moe.route_top_k(x, router, top_k)
+    plan = moe.plan_dispatch(ids, w_gate.shape[0], 0, rows_bound, row_tile)
+    token = plan.row_pair // top_k
+    rows = x[token]
+    gate = moe.grouped_matmul(rows, w_gate, plan.tile_expert, plan.n_used,
+                              row_tile)
+    up = moe.grouped_matmul(rows, w_up, plan.tile_expert, plan.n_used,
+                            row_tile)
+    hidden = jax.nn.silu(gate) * up
+    out = moe.grouped_matmul(hidden, w_down, plan.tile_expert, plan.n_used,
+                             row_tile)
+    share = jnp.where(plan.row_valid, weights.reshape(-1)[plan.row_pair], 0.0)
+    combined = jnp.zeros(x.shape, x.dtype).at[token].add(
+        out * share[:, None])
+    return jnp.where(plan.n_dropped > 0, jnp.nan, combined), plan
+
+
+def _routed_to(on_first, on_second):
+    """Tokens x (T, d) and a router under which the first `on_first` tokens
+    choose held expert 0 and the first `on_second` held expert 1; a token's
+    other choices are experts that are not held. The router copies the
+    first d coordinates, and a token's two choices stand out in them."""
+    T, d, E = (LADDER_SHAPE[k] for k in ("T", "d", "E"))
+    x = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (T, d))
+    t = onp.arange(T)
+    first = onp.where(t < on_first, 0, E + t % (d - E))
+    second = onp.where(t < on_second, 1, E + (t + 1) % (d - E))
+    x = x.at[t, first].add(6.0).at[t, second].add(5.0)
+    return x, jnp.eye(d, LADDER_SHAPE["n_experts"])
+
+
+def _ladder_case(name):
+    if name == "uniform":
+        shape = LADDER_SHAPE
+        x = jax.random.normal(jax.random.PRNGKey(5), (shape["T"], shape["d"]))
+        return x, jax.random.normal(jax.random.PRNGKey(6),
+                                    (shape["d"], shape["n_experts"]))
+    # tiles: expert 0's rows over 8, and expert 1's or its one empty tile
+    return _routed_to(*{"exactly_17": (16 * 8, 0), "17_and_one": (129, 0),
+                        "exactly_33": (256, 0), "33_and_one": (256, 9),
+                        "all_held": (256, 256)}[name])
+
+
+@pytest.mark.parametrize("interpret", ["1", "0"])
+@pytest.mark.parametrize("routing,n_used,rung", [
+    ("uniform", None, 17), ("exactly_17", 17, 17), ("17_and_one", 18, 33),
+    ("exactly_33", 33, 33), ("33_and_one", 34, 66), ("all_held", 64, 66)])
+def test_every_rung_is_the_layer_over_the_whole_buffer(
+        monkeypatch, routing, n_used, rung, interpret):
+    """Value and gradients to x, the router and the three weight tensors,
+    over the prefix that the routing picks, against the one computation
+    over all 66 tiles: through the kernels (interpreted) and through the
+    loop over experts in XLA."""
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", interpret)
+    shape = LADDER_SHAPE
+    E, d, f, top_k, row_tile = (shape[k] for k in
+                                ("E", "d", "f", "top_k", "row_tile"))
+    x, router = _ladder_case(routing)
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    weights = [0.3 * jax.random.normal(k, s) for k, s in zip(
+        ks, [(E, d, f), (E, d, f), (E, f, d)])]
+    target = jax.random.normal(ks[3], x.shape)
+    _, plan = _full_length(x, router, *weights, top_k, None, row_tile)
+    assert moe.prefix_ladder(shape["T"], top_k, E, shape["n_experts"],
+                             plan.tile_expert.shape[0], row_tile) == LADDER
+    used = int(plan.n_used[0])
+    assert n_used in (None, used) and int(plan.n_dropped) == 0
+    assert min(r for r in LADDER if r >= used) == rung
+
+    def value_and_grads(layer):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(layer(*a) * target), argnums=range(5))(
+                x, router, *weights)
+    mine, mine_grads = value_and_grads(
+        lambda *a: moe.moe_routed(*a, top_k, row_tile=row_tile))
+    full, full_grads = value_and_grads(
+        lambda *a: _full_length(*a, top_k, None, row_tile)[0])
+    # once a trace, and every rung of the ladder is traced with the first
+    assert all(telemetry.counter("ops.moe.ladder.%d" % (r * row_tile)).value
+               for r in LADDER)
+    # float32 both ways; sums over fewer rows are added up in another order:
+    # some hundred terms, 1e-5 of the largest
+    onp.testing.assert_allclose(mine, full, rtol=1e-5)
+    for got, want in zip(mine_grads, full_grads):
+        largest = float(jnp.max(jnp.abs(want)))
+        assert largest > 0
+        onp.testing.assert_allclose(got, want, rtol=1e-5,
+                                    atol=1e-5 * largest)
+
+
+def test_the_ladder_comes_from_shapes_alone(monkeypatch):
+    """`qwen3_next_ep16_s4096`: 8,192 tokens, ten of 512 experts each, 32
+    held: uniform routing wants 40 + 32 tiles of the buffer's 672, and the
+    half of 336 holds four times that; a quarter would not. A buffer too
+    short to halve has one rung, and the layer then holds no conditional."""
+    assert moe.prefix_ladder(8192, 10, 32, 512, 672) == (336, 672)
+    assert moe.prefix_ladder(40, 1, 2, 4, 7, 8) == (7,)
+    assert moe.prefix_ladder(40, 3, 1, 16, 2) == (2,)
+    assert moe.prefix_ladder(256, 2, 2, 128, 67, 8) == (17, 34, 67)
+    picked = [int(moe._rung_index((136, 272, 544), jnp.array([n]), 8))
+              for n in (1, 17, 18, 34, 35, 68)]
+    assert picked == [0, 0, 1, 1, 2, 2]
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "0")    # no kernel's `cond`
+
+    def conds(T, n_experts):
+        args = (jnp.zeros((T, 16)), jnp.zeros((16, n_experts)),
+                jnp.zeros((2, 16, 8)), jnp.zeros((2, 16, 8)),
+                jnp.zeros((2, 8, 16)))
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: moe.moe_routed(
+            *a, top_k=1, row_tile=8).sum(), argnums=(0, 2)))(*args))
+        return text.count(" cond[")
+    assert conds(40, 4) == 0        # 5 + 2 tiles, 3 + 2 expected: one rung
+    assert conds(256, 32) == 2      # 32 + 2 tiles: forward and backward
+
+
+@pytest.mark.parametrize("routing,over", [("exactly_17", False),
+                                          ("all_held", True)])
+def test_a_passed_bound_is_nan_on_a_laddered_buffer(routing, over):
+    """A bound of 256 pairs under the ladder's shapes: 32 + 2 tiles and a
+    rung of 17. 128 pairs on held expert 0 fit that rung; 512 on the two
+    pass the bound, the buffer is then full (so the top rung runs) and the
+    layer is NaN throughout."""
+    shape = LADDER_SHAPE
+    E, d, f = shape["E"], shape["d"], shape["f"]
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    weights = [0.3 * jax.random.normal(k, s) for k, s in zip(
+        ks, [(E, d, f), (E, d, f), (E, f, d)])]
+    assert moe.prefix_ladder(256, 2, E, 128, 32 + E, 8) == (17, 34)
+    x, router = _ladder_case(routing)
+    out = moe.moe_routed(x, router, *weights, top_k=2, rows_bound=256,
+                         row_tile=8)
+    assert bool(jnp.all(jnp.isnan(out))) == over
+    assert bool(jnp.all(jnp.isfinite(out))) != over
+
+
 def test_router_weights_sum_to_one_over_the_ten_largest():
     x = jax.random.normal(jax.random.PRNGKey(0), (50, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (32, 64))
@@ -190,3 +337,42 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                                     rtol=1e-5, atol=1e-5)
         total = total + (got - shared)
     onp.testing.assert_allclose(total, whole, rtol=1e-5, atol=2e-5)
+
+
+def test_the_rungs_that_ran_are_read_from_a_module_and_its_events(
+        monkeypatch):
+    """`tools/moe_rungs.py` on the ladder's toy layer compiled here: both
+    conditionals found, forward and backward, every rung's instructions
+    filed under it by their `rows_<R>` scope; and from events of those
+    names, how often each rung ran and for how long."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "moe_rungs", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "moe_rungs.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "0")
+    shape = LADDER_SHAPE
+    E, d, f = shape["E"], shape["d"], shape["f"]
+    x, router = _ladder_case("uniform")
+    weights = [jnp.ones(s) for s in [(E, d, f), (E, d, f), (E, f, d)]]
+    text = jax.jit(jax.value_and_grad(lambda *a: moe.moe_routed(
+        *a, top_k=2, row_tile=8).sum(), argnums=(0, 2))).lower(
+            x, router, *weights).compile().as_text()
+    filed, direction = tool.rung_of_instruction(text)
+    assert sorted(direction.values()) == ["backward", "forward"]
+    assert {rows for _, rows in filed.values()} == {136, 264, 528}
+    forward = next(c for c, way in direction.items() if way == "forward")
+    names = {rows: [n for n, (c, r) in filed.items()
+                    if c == forward and r == rows] for rows in (136, 264)}
+    # three steps: the low rung twice, then the middle one; 2 and 5 ns an
+    # instruction
+    events = [(t, 2, n) for t in (0, 100) for n in names[136]]
+    events += [(200, 5, n) for n in names[264]]
+    report, rungs = tool.by_rung(events, filed, direction, 3, 8)
+    assert [r["conditional"] for r in report] == [forward]
+    assert report[0]["in_order"] == "17x2 33x1"
+    assert report[0]["runs_a_step"] == {"17": 2 / 3, "33": 1 / 3}
+    assert rungs["17"]["ms_a_step"] == pytest.approx(
+        2 * 2 * len(names[136]) * 1e-6 / 3)
+    assert rungs["33"]["runs_a_step"] == pytest.approx(1 / 3)

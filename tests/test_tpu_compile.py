@@ -26,18 +26,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_text(fn, *args):
-    """The text of `fn` compiled for the arguments' described chip, with
-    the persistent compile cache off and out of the way."""
+def _compiled(fn, *args):
+    """`fn` compiled for the arguments' described chip, with the persistent
+    compile cache off and out of the way."""
     from jax.experimental.compilation_cache import compilation_cache
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
         compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *args):
+    return _compiled(fn, *args).as_text()
 
 
 def test_mlm_head_keeps_one_tensor_of_the_logits_size(one_chip):
@@ -273,3 +277,63 @@ def test_grouped_product_kernels_compile_at_the_cells_shapes(
     # the instructions carry the kernels' names, as the device trace does
     calls = re.findall(r"%(moe_t?gmm)[.\d]* = \S+ custom-call\(", text)
     assert sorted(calls) == ["moe_gmm", "moe_gmm", "moe_tgmm"], calls
+
+
+def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
+        one_chip, monkeypatch):
+    """One expert layer at `qwen3_next_ep16_s4096`'s shapes (8,192 tokens of
+    2,048, ten of 512 experts each, 32 held of width 512: a buffer of 86,016
+    rows), value and gradient under `jax.checkpoint` as the decoder calls
+    it: TWO conditionals (the forward and the backward; the recomputed
+    forward is dead), a branch for each rung of (336, 672) tiles, every
+    branch with the kernels under their names (3 `moe_gmm` forward; 6 and 3
+    `moe_tgmm` backward, which makes its own forward again), and the
+    compiler's account of the temporaries not above that of the layer over
+    the whole buffer alone: the branches keep nothing for one another."""
+    from mxnet_tpu.ops import moe, pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+    T, d, f, held, n_experts, top_k = 8192, 2048, 512, 32, 512, 10
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    args = (shape((T, d), "bfloat16"), shape((d, n_experts), "float32"),
+            shape((held, d, f), "float32"), shape((held, d, f), "float32"),
+            shape((held, f, d), "float32"), shape((T, d), "bfloat16"))
+
+    def whole_buffer(x, router, w_gate, w_up, w_down):
+        weights, ids = moe.route_top_k(x, router, top_k)
+        plan = moe.plan_dispatch(ids, held)
+        return moe._routed_rows(x, weights, w_gate, w_up, w_down, plan,
+                                rows=plan.row_pair.shape[0], top_k=top_k,
+                                row_tile=moe.ROW_TILE, gate=None
+                                ).astype(x.dtype)
+
+    def compiled(layer):
+        layer = jax.checkpoint(layer)
+        return _compiled(jax.value_and_grad(
+            lambda *a: jnp.sum((layer(*a[:5]) * a[5]).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4)), *args)
+
+    laddered = compiled(lambda *a: moe.moe_routed(*a, top_k))
+    text = laddered.as_text()
+    branches = re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}",
+                          text)
+    assert len(branches) == 2, branches
+    calls = []
+    for conditional in branches:
+        names = [n.strip() for n in conditional.split(",")]
+        assert len(names) == 2, names
+        for name in names:
+            body = text[text.index("\n%s (" % name):]
+            body = body[:body.index("\n}\n")]
+            calls.append(sorted(re.findall(
+                r"%(moe_t?gmm)[.\d]* = \S+ custom-call\(", body)))
+    forward, backward = ["moe_gmm"] * 3, ["moe_gmm"] * 6 + ["moe_tgmm"] * 3
+    assert sorted(calls) == [forward] * 2 + [backward] * 2, calls
+    for rows in (43008, 86016):
+        assert "rows_%d/" % rows in text
+    alone = compiled(whole_buffer)
+    assert " conditional(" not in alone.as_text()
+    assert (laddered.memory_analysis().temp_size_in_bytes
+            <= alone.memory_analysis().temp_size_in_bytes)
