@@ -13,6 +13,7 @@ from . import bert
 from . import resnet
 from . import dlrm
 from . import qwen3_next
+from . import laguna
 from .losses import linear_cross_entropy
 from .llama import (LlamaConfig, llama_init, llama_forward, llama_loss,
                     llama_prefill_paged, llama_decode_paged,
@@ -22,9 +23,10 @@ from .resnet import ResNetConfig, resnet_init, resnet_forward, resnet_loss
 from .dlrm import DLRMConfig, dlrm_init, dlrm_forward, dlrm_loss
 from .qwen3_next import (Qwen3NextConfig, qwen3_next_init,
                          qwen3_next_forward, qwen3_next_loss)
+from .laguna import LagunaConfig, laguna_init, laguna_forward, laguna_loss
 
 __all__ = [
-    "llama", "bert", "resnet", "dlrm", "qwen3_next",
+    "llama", "bert", "resnet", "dlrm", "qwen3_next", "laguna",
     "LlamaConfig", "llama_init", "llama_forward", "llama_loss",
     "llama_prefill_paged", "llama_decode_paged", "llama_chunk_paged",
     "llama_draft_loop", "init_kv_pools",
@@ -33,5 +35,6 @@ __all__ = [
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_loss",
     "Qwen3NextConfig", "qwen3_next_init", "qwen3_next_forward",
     "qwen3_next_loss",
+    "LagunaConfig", "laguna_init", "laguna_forward", "laguna_loss",
     "linear_cross_entropy",
 ]

@@ -38,13 +38,13 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..ops.linear_attention import (INVERSE_NAME, causal_conv1d,
                                     gated_delta_rule, gated_rms_norm,
                                     l2_normalize)
 from ..ops.moe import moe_routed
 from ..parallel.flash_attention import flash_attention_bshd
+from .decoder_ops import dot as _dot, gated_hidden, rms_norm, rotary
 from .losses import linear_cross_entropy
 
 __all__ = ["Qwen3NextConfig", "qwen3_next_init", "qwen3_next_forward",
@@ -141,29 +141,15 @@ def qwen3_next_init(key, cfg: Qwen3NextConfig):
 
 
 def _norm(x, w, eps):
-    xf = x.astype(F32)
-    scale = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale * (1.0 + w)).astype(x.dtype)
-
-
-def _dot(x, w):
-    """x @ w, operands in x's type, float32 out of the MXU."""
-    return jnp.dot(x, w.astype(x.dtype),
-                   preferred_element_type=F32).astype(x.dtype)
+    return rms_norm(x, w, eps, offset=1.0)      # zero-centred weights
 
 
 def _rotary(x, cfg):
     """Rotary embedding on the first `partial_rotary_factor` of each head of
     x (B, S, H, D), pairs (i, i + half); the rest passes through."""
-    S, D = x.shape[1], x.shape[-1]
-    rot = int(D * cfg.partial_rotary_factor)
-    half = rot // 2
-    inv_freq = cfg.rope_theta ** (-jnp.arange(half, dtype=F32) * 2.0 / rot)
-    angle = jnp.arange(S, dtype=F32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    x1, x2 = x[..., :half].astype(F32), x[..., half:rot].astype(F32)
-    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return jnp.concatenate([turned.astype(x.dtype), x[..., rot:]], -1)
+    rot = int(x.shape[-1] * cfg.partial_rotary_factor)
+    return rotary(x, cfg.rope_theta ** (
+        -jnp.arange(rot // 2, dtype=F32) * 2.0 / rot))
 
 
 def _gated_attention(p, x, cfg):
@@ -206,8 +192,7 @@ def _moe(p, x, cfg):
     routed = moe_routed(x, p["router"], p["gate"], p["up"], p["down"],
                         cfg.experts_per_token, cfg.first_expert,
                         cfg.moe_rows_bound)
-    hidden = (jax.nn.silu(_dot(x, p["shared_gate_proj"]).astype(F32))
-              * _dot(x, p["shared_up"]).astype(F32)).astype(x.dtype)
+    hidden = gated_hidden(x, p["shared_gate_proj"], p["shared_up"])
     gate = jax.nn.sigmoid(jnp.dot(x, p["shared_gate"].astype(x.dtype),
                                   preferred_element_type=F32))
     shared = (_dot(hidden, p["shared_down"]).astype(F32) * gate

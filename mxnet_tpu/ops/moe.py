@@ -7,8 +7,9 @@ absent experts would add is another chip's to compute and to send; on one
 chip the layer runs without that exchange, and nothing stands in for it.
 
     p = softmax(x W_r) over all experts, in float32 at `highest` (the choice
-        of ten in 512 is discrete: a rounded product flips it)
-    the top_k largest, their weights divided by their sum
+        of ten in 512 is discrete: a rounded product flips it); or
+        p = sigmoid(x W_r), each expert by itself (`score="sigmoid"`)
+    the top_k largest, their weights divided by their sum, times `scale`
     E_e(x) = (silu(x G_e) * (x U_e)) D_e
     routed(x) = sum over the chosen experts e held here of p_e E_e(x)
 
@@ -117,13 +118,21 @@ def _largest(p, k):
     return jnp.stack(values, axis=-1), jnp.stack(indices, axis=-1)
 
 
-def route_top_k(x, w_router, top_k):
-    """(weights (T, top_k) float32 summing to 1 a token, expert ids (T,
-    top_k) int32) over all the experts `w_router` (d, n_experts) has."""
+_SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
+def route_top_k(x, w_router, top_k, score="softmax", scale=1.0):
+    """(weights (T, top_k) float32 summing to `scale` a token, expert ids
+    (T, top_k) int32) over all the experts `w_router` (d, n_experts) has.
+    `score` makes an expert's score of its logit: "softmax" over all
+    experts, or "sigmoid", each expert by itself."""
     logits = jnp.dot(x.astype(F32), w_router.astype(F32),
                      precision=lax.Precision.HIGHEST)
-    top, ids = _largest(jax.nn.softmax(logits, axis=-1), top_k)
-    return top / jnp.sum(top, axis=-1, keepdims=True), ids
+    top, ids = _largest(_SCORES[score](logits), top_k)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    # no product by 1: a softmax router's program stays the one it was
+    return (weights if scale == 1.0 else weights * scale), ids
 
 
 class Dispatch(NamedTuple):
@@ -465,14 +474,16 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 # ---------------------------------------------------------------- the layer
 def moe_routed(x, w_router, w_gate, w_up, w_down, top_k, first_expert=0,
-               rows_bound=None, row_tile=ROW_TILE):
+               rows_bound=None, row_tile=ROW_TILE, score="softmax",
+               scale=1.0):
     """What the experts held here add for the tokens x (T, d): the sum over
     a token's chosen experts e in first_expert .. first_expert + E - 1 of
     p_e E_e(x), in x's type. w_router (d, n_experts) over all experts;
-    w_gate, w_up (E, d, f) and w_down (E, f, d) of the E held. NaN
-    throughout where the routing put more than `rows_bound` pairs here."""
+    w_gate, w_up (E, d, f) and w_down (E, f, d) of the E held; `score` and
+    `scale` as `route_top_k` takes them. NaN throughout where the routing
+    put more than `rows_bound` pairs here."""
     n_held = w_gate.shape[0]
-    weights, ids = route_top_k(x, w_router, top_k)
+    weights, ids = route_top_k(x, w_router, top_k, score, scale)
     plan = plan_dispatch(ids, n_held, first_expert, rows_bound, row_tile)
     ladder = tuple(tiles * row_tile for tiles in prefix_ladder(
         x.shape[0], top_k, n_held, w_router.shape[1],

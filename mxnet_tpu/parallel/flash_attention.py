@@ -51,6 +51,14 @@ Mosaic wants a block's last two dimensions to be multiples of (8, 128) or
 the array's own. dk/dv are computed transposed (scores as (k, q)), so the
 statistics broadcast along sublanes and no product transposes an operand.
 
+A window (`flash_attention_bshd(..., window=W)`: position i sees the keys
+i - W < j <= i) runs the same three bodies as `swa_fwd`, `swa_dq` and
+`swa_dkv`. Their grid's inner dimension is not the sequence's blocks but the
+band's: `_band` gives the first and last inner block an outer block's band
+touches, the index maps (`_banded`) walk from the first and stay on the last,
+and a step past the last does nothing, so no block outside the band is
+fetched or computed. The tile is the causal kernels'.
+
 Products take their operands in the type they arrive in, with float32 out
 of the MXU; jax's matmul precision rides into the kernels on `dot_general`
 as into any other product of the program (float32 operands: one bfloat16
@@ -99,9 +107,10 @@ _VMEM_LIMIT = 16 << 20  # what the kernels ask Mosaic for (its own default
 #                         for 32 MiB 3.9 ms (PERF.md, PR 32)
 
 
-def _ref_attention(q, k, v, causal, sm_scale):
+def _ref_attention(q, k, v, causal, sm_scale, window=None):
     """Plain-XLA attention, fp32 softmax. Used for CPU fallback and as the
-    recompute body of the non-Pallas backward.
+    recompute body of the non-Pallas backward. With `window`, query i sees
+    the keys i - window < j <= i (on the causal diagonal's offset).
 
     GQA runs as a grouped einsum over (kv_head, group) axes rather than
     jnp.repeat of K/V: no materialized copies, and the repeat's reshape+sum
@@ -112,10 +121,13 @@ def _ref_attention(q, k, v, causal, sm_scale):
     qg = q.reshape(B, Hkv, g, Sq, D)
     logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * sm_scale
-    if causal:
+    if causal or window is not None:
         qi = lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0) + (Sk - Sq)
         ki = lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
-        logits = jnp.where(ki <= qi, logits, _NEG_INF)
+        seen = ki <= qi
+        if window is not None:
+            seen = seen & (ki > qi - window)
+        logits = jnp.where(seen, logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v)
     return out.reshape(B, H, Sq, D)
@@ -195,15 +207,18 @@ def _choose_tile(view, B, H, Hkv, Sq, Sk, D, itemsize):
 
 
 # --------------------------------------------------------------- in a kernel
-def _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal, q_axis=0):
-    """Mask logits for causal structure and for keys past the true
-    sequence end (non-divisible block grids read garbage there). `q_axis`
-    is the dimension of `s` the queries run along."""
+def _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal, q_axis=0,
+                 window=None):
+    """Mask logits for causal structure, for keys behind the window and for
+    keys past the true sequence end (non-divisible block grids read garbage
+    there). `q_axis` is the dimension of `s` the queries run along."""
     qi = lax.broadcasted_iota(jnp.int32, s.shape, q_axis) + q_start
     ki = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) + k_start
     valid = ki < seq_k
     if causal:
         valid = valid & (ki <= qi + (seq_k - seq_q))
+    if window is not None:
+        valid = valid & (ki > qi + (seq_k - seq_q) - window)
     return jnp.where(valid, s, _NEG_INF)
 
 
@@ -279,16 +294,79 @@ def _runs(causal, q_start, k_start, block_q, seq_q, seq_k):
         k_start <= q_start + (seq_k - seq_q) + block_q - 1)
 
 
+def _band(outer, window, tile, seq_q, seq_k, over_keys):
+    """(first, last) of the inner sweep's blocks that the band of `window`
+    keys behind the causal diagonal leaves outer block `outer`: the key
+    blocks its queries see where `over_keys` (forward, dq), else the query
+    blocks that see its keys (dk/dv). `outer` is a Python int or a grid
+    index; every numerator is kept at 0 or above, so the division is the
+    scalar core's own."""
+    off = seq_k - seq_q
+    if over_keys:
+        size, inner, rows = tile.block_q, tile.block_k, seq_k
+        lo, hi = outer * size + off - window + 1, outer * size + size - 1 + off
+    else:
+        size, inner, rows = tile.block_k, tile.block_q, seq_q
+        lo = outer * size - off
+        hi = outer * size + size - 1 - off + window - 1
+    if isinstance(outer, int):
+        return max(lo, 0) // inner, max(min(hi, rows - 1), 0) // inner
+    return (lax.div(jnp.maximum(lo, 0), jnp.int32(inner)),
+            lax.div(jnp.clip(hi, 0, rows - 1), jnp.int32(inner)))
+
+
+def _band_blocks(window, tile, seq_q, seq_k, over_keys):
+    """Blocks in the inner sweep of a windowed call: the most that the band
+    leaves any outer block."""
+    n_outer = -(-(seq_q if over_keys else seq_k)
+                // (tile.block_q if over_keys else tile.block_k))
+    spans = [_band(o, window, tile, seq_q, seq_k, over_keys)
+             for o in range(n_outer)]
+    return max(last - first + 1 for first, last in spans)
+
+
+def _band_step(outer, t, window, tile, seq_q, seq_k, over_keys):
+    """(first row of the inner block that step `t` of outer block `outer`'s
+    sweep works on, whether the band holds it, whether the sweep is one
+    block long). The sweep starts on the band's first block; the steps past
+    its last block (an outer block at the sequence's start has fewer) do
+    nothing, and their index maps (`_banded`) stay on the last block, so
+    nothing is fetched for them."""
+    first, last = _band(outer, window, tile, seq_q, seq_k, over_keys)
+    block = first + t
+    return (block * (tile.block_k if over_keys else tile.block_q),
+            block <= last,
+            _band_blocks(window, tile, seq_q, seq_k, over_keys) == 1)
+
+
+def _banded(window, tile, seq_q, seq_k, over_keys):
+    """What takes an index map of (row block, head group, q-block, k-block)
+    to that of a windowed call, whose grid is (.., outer block, step of the
+    band's sweep)."""
+    def at(index_map):
+        def banded(b, g, outer, t):
+            first, last = _band(outer, window, tile, seq_q, seq_k, over_keys)
+            inner = jnp.minimum(first + t, last)
+            return (index_map(b, g, outer, inner) if over_keys
+                    else index_map(b, g, inner, outer))
+        return banded
+    return at
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
-                sm_scale, causal, seq_q, seq_k):
+                sm_scale, causal, seq_q, seq_k, window=None):
     """One (row block, head group, q-block, k-block) grid step. The grid's
-    last dim is the sequential K sweep; with more than one block in it the
-    accumulators live in VMEM scratch across it."""
+    last dim is the sequential K sweep (under `window`, over the band's
+    blocks alone); with more than one block in it the accumulators live in
+    VMEM scratch across it."""
     bq, bk = tile.block_q, tile.block_k
     single = seq_k <= bk
     j = pl.program_id(3)
     q_start, k_start = pl.program_id(2) * bq, j * bk
-    masked = causal or seq_k % bk != 0
+    masked = causal or seq_k % bk != 0 or window is not None
+    if window is not None:
+        k_start, in_band, single = _band_step(pl.program_id(2), j, window,
+                                              tile, seq_q, seq_k, True)
     if not single:
         acc, m_sc, l_sc = scratch
 
@@ -312,7 +390,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
             s = _dot(_scaled(_own_lanes(q, h, tile), sm_scale), k,
                      transpose_b=True)
             if masked:
-                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal)
+                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal,
+                                 window=window)
             m_new = jnp.max(s, axis=1, keepdims=True)
             if not single:
                 m_prev = m_sc[r, h][:, :1]
@@ -344,7 +423,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
         l_sc[...] = jnp.zeros_like(l_sc)
 
     # causal: skip blocks strictly above the (offset) diagonal
-    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k)
+             if window is None else in_band)
     def _step():
         _each_row(tile.block_b, step)
 
@@ -356,7 +436,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, tile,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-               *scratch, tile, sm_scale, causal, seq_q, seq_k, sums=False):
+               *scratch, tile, sm_scale, causal, seq_q, seq_k, sums=False,
+               window=None):
     """dq = sum_j dS_ij K_j — grid (row block, head group, q-block,
     k-block), K sweep sequential, dq accumulated in VMEM where the sweep has
     more than one block. With `sums`, one more result: the column sums of
@@ -366,7 +447,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     single = seq_k <= bk
     j = pl.program_id(3)
     q_start, k_start = pl.program_id(2) * bq, j * bk
-    masked = causal or seq_k % bk != 0
+    masked = causal or seq_k % bk != 0 or window is not None
+    if window is not None:
+        k_start, in_band, single = _band_step(pl.program_id(2), j, window,
+                                              tile, seq_q, seq_k, True)
     if sums:
         sum_ref, *scratch = scratch
     if not single:
@@ -385,7 +469,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             delta = dl_ref[r, h, 0][:, None]
             s = _dot(_own_lanes(q, h, tile), k, transpose_b=True)
             if masked:
-                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal)
+                s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal,
+                                 window=window)
             p = jnp.exp(s - lse)
             dp = _dot(_own_lanes(do, h, tile), v, transpose_b=True)
             dq.append(_dot((p * (dp - delta)).astype(k.dtype), k))
@@ -407,7 +492,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k)
+             if window is None else in_band)
     def _step():
         _each_row(tile.block_b, step)
 
@@ -422,7 +508,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, *scratch, tile, sm_scale, causal, seq_q,
-                seq_k, sums=False):
+                seq_k, sums=False, window=None):
     """dk/dv for one K-block — grid (row block, head group, k-block,
     q-block), Q sweep sequential. Scores are computed transposed, (k, q):
     the row statistics then broadcast along sublanes as they arrive, and
@@ -434,7 +520,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     single = seq_q <= bq
     i = pl.program_id(3)
     q_start, k_start = i * bq, pl.program_id(2) * bk
-    masked = causal or seq_k % bk != 0
+    masked = causal or seq_k % bk != 0 or window is not None
+    if window is not None:
+        q_start, in_band, single = _band_step(pl.program_id(2), i, window,
+                                              tile, seq_q, seq_k, False)
     ragged_q = seq_q % bq != 0
     if sums:
         dk_sum_ref, dv_sum_ref, *scratch = scratch
@@ -462,7 +551,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             s = _dot(_own_lanes(k, h, tile), q, transpose_b=True)
             if masked:
                 s = _bounds_mask(s, q_start, k_start, seq_q, seq_k, causal,
-                                 q_axis=1)
+                                 q_axis=1, window=window)
             p = jnp.exp(s - lse)
             if ragged_q:
                 # queries past seq_q carry no probability mass (lse
@@ -496,7 +585,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k))
+    @pl.when(_runs(causal, q_start, k_start, bq, seq_q, seq_k)
+             if window is None else in_band)
     def _step():
         _each_row(tile.block_b, step)
 
@@ -602,23 +692,39 @@ def _params(tile):
                            vmem_limit_bytes=max(_VMEM_LIMIT, tile.vmem))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8))
-def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret):
+def _windowed(kernel, window, tile, Sq, Sk, over_keys):
+    """(kernel keywords with the window, blocks of the inner sweep or None
+    for the whole sequence, what takes an index map to the call's, prefix of
+    the call's name)."""
+    if window is None:
+        return kernel, None, lambda index_map: index_map, "flash"
+    return (dict(kernel, window=window),
+            _band_blocks(window, tile, Sq, Sk, over_keys),
+            _banded(window, tile, Sq, Sk, over_keys), "swa")
+
+
+@functools.partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8, 9))
+def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret,
+             window=None):
     """o, and the logsumexp in the statistics' shape, of the view's 3-D
     operands. Jitted so that a model's layers share one trace and one
-    lowering of the kernel."""
+    lowering of the kernel. With `window` the K sweep is the band's blocks
+    (`_band`), reached through the index maps, and the call is `swa_fwd`."""
     geo = _geometry(view, q, k, H, Hkv)
     tile = geo.tile
     Sq, Sk = q.shape[1], k.shape[1]
     bb, bq, bk, lanes = tile.block_b, tile.block_q, tile.block_k, tile.lanes
     nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Sk, bk)
+    kernel, band, at, name = _windowed(
+        dict(tile=tile, sm_scale=sm_scale, causal=causal, seq_q=Sq,
+             seq_k=Sk), window, tile, Sq, Sk, True)
+    nk = band or nk
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, tile=tile, sm_scale=sm_scale,
-                          causal=causal, seq_q=Sq, seq_k=Sk),
+        functools.partial(_fwd_kernel, **kernel),
         grid=geo.outer + (nq, nk),
         in_specs=[pl.BlockSpec((bb, bq, lanes), geo.q),
-                  pl.BlockSpec((bb, bk, lanes), geo.k),
-                  pl.BlockSpec((bb, bk, lanes), geo.v)],
+                  pl.BlockSpec((bb, bk, lanes), at(geo.k)),
+                  pl.BlockSpec((bb, bk, lanes), at(geo.v))],
         out_specs=[pl.BlockSpec((bb, bq, lanes), geo.o),
                    pl.BlockSpec((bb, tile.heads, 1, bq), geo.row)],
         out_shape=[_out_struct(geo.o_shape, q.dtype, q, k, v),
@@ -628,7 +734,8 @@ def _forward(view, q, k, v, H, Hkv, causal, sm_scale, interpret):
                                 (bb, tile.heads, bq, _LANES)),
         interpret=interpret,
         compiler_params=_params(tile),
-        name="flash_fwd",   # the HLO instruction, and so the device trace
+        name=name + "_fwd",     # the HLO instruction, and so the device
+        #                         trace: flash_fwd, or swa_fwd under a window
     )(q, k, v)
 
 
@@ -644,14 +751,15 @@ def _dkv_packed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                 *scratch, tile=tile, sums=True, **kernel)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(0, 7, 8, 9, 10, 11, 12))
 def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
-              interpret):
+              interpret, window=None):
     """dq like q, and dk, dv PER ATTENTION HEAD (like q along the heads,
     like k along the sequence), from the row statistics (lse, delta) in the
     statistics' shape; under "packed" the one cotangent of the packed
     array, which both kernels wrote into, and its column sums in float32.
-    Jitted as `_forward` is."""
+    Jitted as `_forward` is. With `window`, `swa_dq` sweeps the band's key
+    blocks and `swa_dkv` the query blocks that see a key block."""
     geo = _geometry(view, q, k, H, Hkv)
     tile = geo.tile
     Sq, Sk = q.shape[1], k.shape[1]
@@ -674,13 +782,20 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
     def swapped(index_map):
         return lambda b, g, j, i: index_map(b, g, i, j)
 
-    dq_call = dict(grid=geo.outer + (nq, nk), in_specs=in_specs(),
-                   scratch_shapes=_scratch(nk, (bb, bq, lanes)),
-                   name="flash_dq", **common)
-    dkv_call = dict(grid=geo.outer + (nk, nq), in_specs=in_specs(swapped),
-                    scratch_shapes=_scratch(nq, (bb, bk, lanes),
+    dq_kernel, band_k, over_keys, name = _windowed(kernel, window, tile, Sq,
+                                                   Sk, True)
+    dkv_kernel, band_q, over_queries, _ = _windowed(kernel, window, tile, Sq,
+                                                    Sk, False)
+    dq_call = dict(grid=geo.outer + (nq, band_k or nk),
+                   in_specs=in_specs(over_keys),
+                   scratch_shapes=_scratch(band_k or nk, (bb, bq, lanes)),
+                   name=name + "_dq", **common)
+    dkv_call = dict(grid=geo.outer + (nk, band_q or nq),
+                    in_specs=in_specs(swapped if window is None
+                                      else over_queries),
+                    scratch_shapes=_scratch(band_q or nq, (bb, bk, lanes),
                                             (bb, bk, lanes)),
-                    name="flash_dkv", **common)
+                    name=name + "_dkv", **common)
     if view == "packed":
         # one cotangent for the packed array. `flash_dq` writes q's blocks
         # of it and leaves the rest unwritten; `flash_dkv` fills that array
@@ -690,7 +805,7 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
         # (row blocks, sequence blocks, 1, columns), summed here
         width = geo.o_shape[2]
         dqkv, dq_sums = pl.pallas_call(
-            functools.partial(_dq_kernel, sums=True, **kernel),
+            functools.partial(_dq_kernel, sums=True, **dq_kernel),
             out_specs=[pl.BlockSpec((bb, bq, lanes), geo.q),
                        pl.BlockSpec((1, 1, 1, lanes),
                                     lambda b, g, i, j: (b, i, 0, g))],
@@ -700,7 +815,7 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
             **dq_call)(*args)
         dkv_call["in_specs"].append(pl.BlockSpec(memory_space=pl.ANY))
         dqkv, dkv_sums = pl.pallas_call(
-            functools.partial(_dkv_packed_kernel, **kernel),
+            functools.partial(_dkv_packed_kernel, **dkv_kernel),
             out_specs=[pl.BlockSpec((bb, bk, 2 * lanes),
                                     lambda b, g, j, i: (b, j, g)),
                        pl.BlockSpec((1, 1, 1, 2 * lanes),
@@ -713,14 +828,14 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
         return dqkv, jnp.concatenate([dkv_sums.sum(axis=(0, 1, 2)),
                                       dq_sums.sum(axis=(0, 1, 2))])
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel),
+        functools.partial(_dq_kernel, **dq_kernel),
         out_specs=pl.BlockSpec((bb, bq, lanes), geo.q),
         out_shape=_out_struct(q.shape, q.dtype, *args), **dq_call)(*args)
     # per attention head: a block of dk lies where o's would, along k
     dkv_spec = pl.BlockSpec((bb, bk, lanes), geo.o)
     dkv_shape = (q.shape[0], Sk, q.shape[2])
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kernel),
+        functools.partial(_dkv_kernel, **dkv_kernel),
         out_specs=[dkv_spec, dkv_spec],
         out_shape=[_out_struct(dkv_shape, k.dtype, *args),
                    _out_struct(dkv_shape, v.dtype, *args)],
@@ -729,22 +844,25 @@ def _backward(view, q, k, v, lse, delta, do, H, Hkv, causal, sm_scale,
 
 
 # ------------------------------------------------- the (B, H, S, D) arguments
-def _pallas_forward(q, k, v, causal, sm_scale):
+def _pallas_forward(q, k, v, causal, sm_scale, window=None):
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     o, lse = _forward("bhsd", q.reshape(B * H, Sq, D),
                       k.reshape(B * Hkv, Sk, D), v.reshape(B * Hkv, Sk, D),
-                      H, Hkv, causal, sm_scale, pallas_stats.interpret())
+                      H, Hkv, causal, sm_scale, pallas_stats.interpret(),
+                      window)
     return o.reshape(q.shape), lse.reshape(B, H, Sq)
 
 
-def _pallas_backward(q, k, v, o, lse, do, causal, sm_scale):
+def _pallas_backward(q, k, v, o, lse, do, causal, sm_scale, window=None):
     # delta_i = rowsum(dO_i * O_i): one fused elementwise+reduce in XLA
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    return _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale)
+    return _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
+                                  window)
 
 
-def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale):
+def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale,
+                           window=None):
     """dq/dk/dv kernels from precomputed (lse, delta), each (B, H, Sq).
     Split out so ring attention can run per-block backwards against the
     GLOBAL logsumexp."""
@@ -754,7 +872,7 @@ def _pallas_backward_inner(q, k, v, lse, delta, do, causal, sm_scale):
         "bhsd", q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
         v.reshape(B * Hkv, Sk, D), lse.reshape(B * H, 1, 1, Sq),
         delta.reshape(B * H, 1, 1, Sq), do.reshape(B * H, Sq, D), H, Hkv,
-        causal, sm_scale, pallas_stats.interpret())
+        causal, sm_scale, pallas_stats.interpret(), window)
     group = H // Hkv
     dk = dk.reshape(B, Hkv, group, Sk, D)
     dv = dv.reshape(B, Hkv, group, Sk, D)
@@ -774,30 +892,32 @@ def _use_pallas(q, k):
         "bhsd", B, H, k.shape[1], Sq, k.shape[2], D, q.dtype.itemsize), _Tile)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, sm_scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, sm_scale, window=None):
     if _use_pallas(q, k):
-        o, _ = _pallas_forward(q, k, v, causal, sm_scale)
+        o, _ = _pallas_forward(q, k, v, causal, sm_scale, window)
         return o
-    return _ref_attention(q, k, v, causal, sm_scale)
+    return _ref_attention(q, k, v, causal, sm_scale, window)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale):
+def _flash_fwd(q, k, v, causal, sm_scale, window):
     if _use_pallas(q, k):
-        o, lse = _pallas_forward(q, k, v, causal, sm_scale)
+        o, lse = _pallas_forward(q, k, v, causal, sm_scale, window)
         return o, (q, k, v, o, lse)
-    return _ref_attention(q, k, v, causal, sm_scale), (q, k, v, None, None)
+    return (_ref_attention(q, k, v, causal, sm_scale, window),
+            (q, k, v, None, None))
 
 
-def _flash_bwd(causal, sm_scale, res, g):
+def _flash_bwd(causal, sm_scale, window, res, g):
     q, k, v, o, lse = res
     if lse is not None:
-        return _pallas_backward(q, k, v, o, lse, g, causal, sm_scale)
+        return _pallas_backward(q, k, v, o, lse, g, causal, sm_scale, window)
     # non-Pallas path: rematerialized backward under XLA (differentiates
     # the recompute; reference keeps the full S^2 prob matrix in HBM
     # instead — src/operator/contrib/transformer.cc backward)
     _, vjp = jax.vjp(
-        lambda q_, k_, v_: _ref_attention(q_, k_, v_, causal, sm_scale),
+        lambda q_, k_, v_: _ref_attention(q_, k_, v_, causal, sm_scale,
+                                          window),
         q, k, v)
     return vjp(g)
 
@@ -805,28 +925,40 @@ def _flash_bwd(causal, sm_scale, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None):
+def _window(window, causal):
+    """(causal, window) as the kernels take them: a window looks back from
+    the causal diagonal, so it implies `causal`."""
+    if window is None:
+        return bool(causal), None
+    if int(window) < 1:
+        raise ValueError("window must be at least 1, got %r" % (window,))
+    return True, int(window)
+
+
+def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
     """Fused scaled-dot-product attention.
 
-    q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D), H divisible by Hkv.
+    q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D), H divisible by Hkv. With
+    `window`, position i sees the keys i - window < j <= i and no others.
     Returns (B, H, Sq, D) in q's dtype.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _flash(q, k, v, bool(causal), float(sm_scale))
+    causal, window = _window(window, causal)
+    return _flash(q, k, v, causal, float(sm_scale), window)
 
 
 # ------------------------------------------------- the (B, S, H, D) arguments
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bshd(q, k, v, H, Hkv, causal, sm_scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bshd(q, k, v, H, Hkv, causal, sm_scale, window=None):
     """On the (B, S, H*D) view, always through the kernels."""
     return _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
-                    pallas_stats.interpret())[0]
+                    pallas_stats.interpret(), window)[0]
 
 
-def _flash_bshd_fwd(q, k, v, H, Hkv, causal, sm_scale):
+def _flash_bshd_fwd(q, k, v, H, Hkv, causal, sm_scale, window):
     o, lse = _forward("bshd", q, k, v, H, Hkv, causal, sm_scale,
-                      pallas_stats.interpret())
+                      pallas_stats.interpret(), window)
     return o, (q, k, v, o, lse)
 
 
@@ -846,10 +978,11 @@ def _head_delta(do, o, H):
     return delta[:, :, None, :]
 
 
-def _flash_bshd_bwd(H, Hkv, causal, sm_scale, res, do):
+def _flash_bshd_bwd(H, Hkv, causal, sm_scale, window, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _backward("bshd", q, k, v, lse, _head_delta(do, o, H), do,
-                           H, Hkv, causal, sm_scale, pallas_stats.interpret())
+                           H, Hkv, causal, sm_scale, pallas_stats.interpret(),
+                           window)
     if H != Hkv:
         B, Sk = k.shape[:2]
         dk = dk.reshape(B, Sk, Hkv, H // Hkv, -1).sum(axis=3)
@@ -861,29 +994,36 @@ def _flash_bshd_bwd(H, Hkv, causal, sm_scale, res, do):
 _flash_bshd.defvjp(_flash_bshd_fwd, _flash_bshd_bwd)
 
 
-def flash_attention_bshd(q, k, v, causal=False, sm_scale=None):
+def flash_attention_bshd(q, k, v, causal=False, sm_scale=None, window=None):
     """Fused scaled-dot-product attention on the layout a projection
     leaves: q (B, Sq, H, D); k, v (B, Sk, Hkv, D), H divisible by Hkv.
     Returns (B, Sq, H, D) in q's dtype, with no transpose on the way in or
     out where the heads fill 128-lane groups; a shape that does not is
-    transposed to `flash_attention`'s layout and counted."""
+    transposed to `flash_attention`'s layout and counted.
+
+    With `window`, position i sees the keys i - window < j <= i: the same
+    kernel bodies under the names `swa_fwd`, `swa_dq` and `swa_dkv`, whose
+    inner sweep visits the blocks that the band touches and no others
+    (counted as `flash_window`)."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    causal, sm_scale = bool(causal), float(sm_scale)
+    (causal, window), sm_scale = _window(window, causal), float(sm_scale)
+    dispatch, fallback = (("flash_bshd", "flash") if window is None
+                          else ("flash_window", "flash_window"))
     if pallas_stats.pallas_on():
         tile = _choose_tile("bshd", B, H, Hkv, Sq, Sk, D, q.dtype.itemsize)
         if isinstance(tile, _Tile):
-            note_dispatch("flash_bshd")
+            note_dispatch(dispatch)
             o = _flash_bshd(q.reshape(B, Sq, H * D),
                             k.reshape(B, Sk, Hkv * D),
                             v.reshape(B, Sk, Hkv * D), H, Hkv, causal,
-                            sm_scale)
+                            sm_scale, window)
             return o.reshape(q.shape)
-        note_fallback("flash", tile)
+        note_fallback(fallback, tile)
     o = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-               v.transpose(0, 2, 1, 3), causal, sm_scale)
+               v.transpose(0, 2, 1, 3), causal, sm_scale, window)
     return o.transpose(0, 2, 1, 3)
 
 

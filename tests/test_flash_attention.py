@@ -546,3 +546,100 @@ def test_the_encoder_layer_transposes_no_rank_4_array(heads, dim):
     both = transposed_ranks(jax.grad(
         lambda lp, x: bert._encoder_layer(lp, x, cfg).sum(), argnums=(0, 1)))
     assert all(rank < 4 for rank in forward + both), (forward, both)
+
+
+WINDOW_CASES = [
+    # layout, B, H, Hkv, S, D, window: position i sees i - window < j <= i
+    ("bshd", 1, 6, 1, 768, 128, 200),     # ratio 6 at head 128, under a block
+    ("bshd", 1, 9, 1, 768, 128, 384),     # ratio 9, the window a block long
+    ("bshd", 2, 2, 2, 640, 128, 500),     # over a block
+    ("bshd", 1, 2, 1, 600, 128, 100),     # a sequence no block divides
+    ("bshd", 1, 2, 2, 300, 64, 77),       # two heads a lane group
+    ("bshd", 1, 1, 1, 768, 128, 1),       # one block a sweep: no scratch
+    ("bshd", 1, 4, 2, 256, 16, 50),       # falls back to (B, H, S, D)
+    ("bhsd", 1, 4, 2, 384, 64, 130),
+]
+
+
+@pytest.mark.parametrize("layout,B,H,Hkv,S,D,window", WINDOW_CASES)
+def test_windowed_kernels_match_reference(layout, B, H, Hkv, S, D, window):
+    """The three kernels under a window, forward and the three gradients,
+    against the plain reference with the band as a mask."""
+    sc = D ** -0.5
+    q, k, v, t = _operands(layout, B, H, Hkv, S, S, D, "float32", 40)
+
+    def swap(x):
+        return x.transpose(0, 2, 1, 3) if layout == "bshd" else x
+
+    def kernels(q, k, v):
+        if layout == "bshd":
+            return fa.flash_attention_bshd(q, k, v, True, sc, window)
+        return fa.flash_attention(q, k, v, True, sc, window)
+
+    def plain(q, k, v):
+        return swap(fa._ref_attention(swap(q), swap(k), swap(v), True, sc,
+                                      window))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda *a: (kernels(*a) * t).sum(),
+                                 (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(lambda *a: (plain(*a) * t).sum(),
+                                  (0, 1, 2))(q, k, v)
+        out, out_want = kernels(q, k, v), plain(q, k, v)
+    onp.testing.assert_allclose(out, out_want,
+                                **_tolerance("float32", "highest", False))
+    for g, w in zip(got[1], want[1]):
+        onp.testing.assert_allclose(g, w,
+                                    **_tolerance("float32", "highest", True))
+
+
+def test_a_window_as_long_as_the_sequence_is_causal():
+    q, k, v, _ = _operands("bshd", 1, 2, 1, 640, 640, 128, "float32", 50)
+    with jax.default_matmul_precision("highest"):
+        windowed = fa.flash_attention_bshd(q, k, v, window=640)
+        causal = fa.flash_attention_bshd(q, k, v, causal=True)
+    onp.testing.assert_allclose(windowed, causal, atol=1e-6, rtol=1e-6)
+    with pytest.raises(ValueError):
+        fa.flash_attention_bshd(q, k, v, window=0)
+
+
+def test_the_windowed_grid_is_the_band():
+    """At the new cell's shape (4,096 positions in 512-row blocks, a window
+    of 512) the inner sweep is two blocks long where the causal sweep is
+    eight: 16 grid steps a head of which the first query block's second
+    stays on its only block (15 block pairs computed, none fetched outside
+    the band); `swa_dkv` likewise over the query blocks. The calls carry
+    the `swa_` names and are counted as `flash_window`."""
+    from mxnet_tpu import telemetry
+    B, S, H, Hkv, D, window = 1, 4096, 9, 1, 128, 512
+    tile = fa._choose_tile("bshd", B, H, Hkv, S, S, D, 2)
+    assert (tile.block_q, tile.block_k) == (512, 512)
+    for over_keys in (True, False):
+        assert fa._band_blocks(window, tile, S, S, over_keys) == 2
+    spans = [fa._band(i, window, tile, S, S, True) for i in range(8)]
+    assert spans == [(0, 0)] + [(i - 1, i) for i in range(1, 8)]
+    assert sum(last - first + 1 for first, last in spans) == 15
+    assert [fa._band(j, window, tile, S, S, False) for j in range(8)] == [
+        (j, j + 1) for j in range(7)] + [(7, 7)]
+    # the index map of a step past the band's end stays on its last block
+    at = fa._banded(window, tile, S, S, True)(lambda b, g, i, j: (b, j, g))
+    assert [int(at(0, 0, jnp.int32(0), jnp.int32(t))[1])
+            for t in (0, 1)] == [0, 0]
+    assert [int(at(0, 0, jnp.int32(5), jnp.int32(t))[1])
+            for t in (0, 1)] == [4, 5]
+
+    shape = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16)
+    before = dict(telemetry.snapshot()["counters"])
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention_bshd(
+            q, k, v, window=window).astype(jnp.float32).sum(),
+        (0, 1, 2)))(shape, kv, kv)
+    after = telemetry.snapshot()["counters"]
+    assert after.get("ops.pallas.dispatch.flash_window", 0) == before.get(
+        "ops.pallas.dispatch.flash_window", 0) + 1
+    calls = {eqn.params["name"]: eqn.params["grid_mapping"].grid
+             for eqn in _eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"}
+    assert calls == {"swa_fwd": (1, 9, 8, 2), "swa_dq": (1, 9, 8, 2),
+                     "swa_dkv": (1, 9, 8, 2)}

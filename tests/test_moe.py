@@ -296,6 +296,54 @@ def test_router_weights_sum_to_one_over_the_ten_largest():
         assert set(onp.asarray(ids[t])) == set(onp.argsort(-probs[t])[:10])
 
 
+@pytest.mark.parametrize("score,scale", [("softmax", 1.0), ("sigmoid", 2.5),
+                                         ("sigmoid", 1.0)])
+def test_a_score_and_a_scale_against_a_plain_top_k(score, scale):
+    """`route_top_k`'s ten maxima against `lax.top_k` of the plain score:
+    the same experts, in the same order, their weights the chosen scores
+    over their sum times the scale."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (60, 32))
+    w = jax.random.normal(jax.random.PRNGKey(3), (32, 64))
+    weights, ids = moe.route_top_k(x, w, 10, score=score, scale=scale)
+    plain = (jax.nn.sigmoid(x @ w) if score == "sigmoid"
+             else jax.nn.softmax(x @ w, -1))
+    top, top_ids = jax.lax.top_k(plain, 10)
+    onp.testing.assert_array_equal(ids, top_ids)
+    onp.testing.assert_allclose(
+        weights, scale * top / top.sum(-1, keepdims=True), rtol=1e-6)
+    onp.testing.assert_allclose(weights.sum(-1), scale, rtol=1e-6)
+
+
+def test_fewer_experts_held_than_a_token_chooses_drops_no_pair():
+    """8 held experts under `top_k` 10 of 16: a token sends the held ones at
+    most 8 pairs, the buffer has tokens x 8 rows and a tile an expert, and
+    every pair on a held expert has its row; the layer is the sum over the
+    held experts of a plain loop."""
+    T, d, f, E, held, top_k, row_tile = 48, 32, 24, 16, 8, 10, 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(ks[0], (T, d))
+    router = jax.random.normal(ks[1], (d, E))
+    gate, up = (0.3 * jax.random.normal(k, (held, d, f)) for k in ks[2:4])
+    down = 0.3 * jax.random.normal(ks[4], (held, f, d))
+    weights, ids = moe.route_top_k(x, router, top_k, "sigmoid", 2.5)
+    plan = moe.plan_dispatch(ids, held, first_expert=4, row_tile=row_tile)
+    assert plan.row_pair.shape[0] == T * held + held * row_tile
+    on_held = (onp.asarray(ids) >= 4) & (onp.asarray(ids) < 4 + held)
+    # with 10 of 16 chosen at least 2 of the 8 held are chosen by every token
+    assert on_held.sum(-1).min() >= 2 and on_held.sum(-1).max() <= held
+    rows = onp.asarray(plan.row_pair)[onp.asarray(plan.row_valid)]
+    assert sorted(rows) == sorted(onp.flatnonzero(on_held.reshape(-1)))
+    assert int(plan.n_dropped) == 0
+    got = moe.moe_routed(x, router, gate, up, down, top_k, first_expert=4,
+                         row_tile=row_tile, score="sigmoid", scale=2.5)
+    want = jnp.zeros_like(x)
+    for e in range(held):
+        share = jnp.sum(jnp.where(ids == 4 + e, weights, 0.0), -1)
+        want = want + share[:, None] * (
+            (jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 def _toy(n_held, first):
     cfg = {"hidden_size": 32, "n_experts": n_held, "first_expert": first,
            "n_experts_published": 16, "num_experts_per_tok": 3,

@@ -337,3 +337,121 @@ def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
     assert " conditional(" not in alone.as_text()
     assert (laddered.memory_analysis().temp_size_in_bytes
             <= alone.memory_analysis().temp_size_in_bytes)
+
+
+def _cell_loss(config, batch):
+    """(loss function, parameter shapes, batch shapes) of a benchmark
+    configuration at its real size, from shapes alone."""
+    import importlib
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for path in (bench, root):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    cfg.pop("rehearse", None)
+    leaves = importlib.import_module(
+        "reference." + cfg["reference"]).leaves(cfg)
+    tree = {}
+    for name, dims, _ in leaves:
+        node = tree
+        *path, last = name.split(".")
+        for part in path:
+            node = node.setdefault(part, {})
+        node[last] = jax.ShapeDtypeStruct(tuple(dims), jnp.float32)
+    loss = importlib.import_module("programs." + cfg["program"]).loss_fn(cfg)
+    names = (("tokens", "targets", "mask") if cfg["program"] == "bert"
+             else ("tokens",))
+    return loss, tree, {k: jax.ShapeDtypeStruct(batch, jnp.int32)
+                        for k in names}
+
+
+def _lowered_for_tpu(config, batch):
+    """(the jaxpr, the module lowered for the TPU) of a configuration's loss
+    and gradient, as text that no address, path or line number is part of:
+    a Mosaic call's serialized body carries the kernel's source locations
+    (`enable_debug_info` in jax's `tpu_custom_call`) and is cut out of the
+    module; the jaxpr holds the same kernel, grid and index maps without
+    them."""
+    loss, tree, batch = _cell_loss(config, batch)
+    traced = jax.jit(jax.value_and_grad(loss)).trace(tree, batch)
+    jaxpr = re.sub(r" at \S+:\d+", "", re.sub(r"0x[0-9a-f]+", "0x",
+                                              str(traced.jaxpr)))
+    module = traced.lower(lowering_platforms=("tpu",)).as_text()
+    return jaxpr, re.sub(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22', "", module)
+
+
+def _digest(text):
+    import hashlib
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# what PR 34's tree gives (`git checkout c4da908`, this test copied over):
+# a PR that means to change BERT's or Qwen3-Next's step replaces the pair it
+# changes and says so; one that does not has moved a step it shares
+PARENT_STEPS = {
+    ("bert_base", (128, 128)): ("191c8e56347535ff", "888006aa458dc3be"),
+    ("bert_base", (32, 512)): ("ee54c9a0df876a52", "edf2a4a9d0901870"),
+    ("qwen3_next_80b_a3b_ep16", (2, 4096)): ("f19b2d6ce673f62e",
+                                             "473103da6aa9f622"),
+}
+
+
+@pytest.mark.parametrize("config,batch", sorted(PARENT_STEPS))
+def test_the_older_cells_steps_lower_to_what_the_parent_gave(
+        monkeypatch, config, batch):
+    """BERT's and Qwen3-Next's loss and gradient at their cells' sizes, with
+    the kernels on: the jaxpr (kernel bodies, grids and index maps included)
+    and the module lowered for the TPU are, digest for digest, what the tree
+    before the windowed kernels and the router's score gave. `window=None`
+    and `score="softmax"` change nothing."""
+    from mxnet_tpu.ops import pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+    jaxpr, module = _lowered_for_tpu(config, batch)
+    assert module.count("tpu_custom_call") >= 3
+    assert (_digest(jaxpr), _digest(module)) == PARENT_STEPS[config, batch]
+
+
+def test_laguna_step_lowers_with_windowed_and_causal_kernels(monkeypatch):
+    """`laguna_s_ep32_s4096`'s loss and gradient at its real size, lowered
+    for the TPU: the three sliding layers go through `swa_fwd`, `swa_dq`
+    and `swa_dkv`, the two full layers through `flash_fwd`, `flash_dq` and
+    `flash_dkv` (each lowered once: the layers of a kind share one jitted
+    call), and the four expert layers through the grouped products."""
+    from mxnet_tpu.ops import pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+    _, module = _lowered_for_tpu("laguna_s_2.1_ep32", (2, 4096))
+    assert set(re.findall(r'kernel_name = "(\w+)"', module)) == {
+        "swa_fwd", "swa_dq", "swa_dkv", "flash_fwd", "flash_dq", "flash_dkv",
+        "moe_gmm", "moe_tgmm"}
+
+
+@pytest.mark.parametrize("heads,window", [(18, 512), (12, None)])
+def test_laguna_attention_kernels_compile_at_the_cells_shapes(
+        one_chip, monkeypatch, heads, window):
+    """Forward and backward at `laguna_s_ep32_s4096`'s shapes (2 x 4,096
+    positions, 2 key-value heads of 128 with 9 query heads each under the
+    window of 512, with 6 each under the causal mask alone), bfloat16,
+    compiled by Mosaic for the described v5e within the VMEM they ask for."""
+    import sys
+    import mxnet_tpu  # noqa: F401
+    from mxnet_tpu.ops import pallas_stats
+    fa = sys.modules["mxnet_tpu.parallel.flash_attention"]
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    args = (shape(2, 4096, heads, 128), shape(2, 4096, 2, 128),
+            shape(2, 4096, 2, 128))
+    text = _compiled_text(jax.grad(
+        lambda q, k, v: fa.flash_attention_bshd(
+            q, k, v, causal=True, window=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), *args)
+    prefix = "swa" if window else "flash"
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*? custom-call\(", text)
+    assert sorted(calls) == [prefix + "_dkv", prefix + "_dq",
+                             prefix + "_fwd"], calls
