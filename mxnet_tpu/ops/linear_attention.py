@@ -22,6 +22,12 @@ float32 and multiplied at `highest`. The decays, their running sums and the
 state are float32; every other product takes its operands in the inputs'
 type with float32 out of the MXU.
 
+Where the Pallas kernels are on (`pallas_stats.pallas_on`) and C is 64 or
+128, T and its backward are one kernel each, `gdn_inverse` and
+`gdn_inverse_bwd`: a group of chunks' (C, C) blocks is read into VMEM once,
+taken through every product there and written once. Elsewhere the same
+products are XLA's, each a pass over every block in HBM.
+
 Beside it: the causal depthwise convolution in front of the rule and the
 gated RMSNorm behind it.
 """
@@ -33,6 +39,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+
+from . import pallas_stats
+from .pallas_stats import compiler_params, note_dispatch, note_fallback
 
 __all__ = ["causal_conv1d", "l2_normalize", "gated_rms_norm",
            "gated_delta_rule", "gated_delta_rule_recurrent"]
@@ -73,23 +83,9 @@ def _bmm(a, b, spec, precision=None):
 INVERSE_NAME = "gdn_inverse"    # for a `jax.checkpoint` policy that keeps it
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _unit_lower_inverse(a, dtype):
-    """``(I + a)^-1`` of strictly lower-triangular (..., C, C) float32
-    blocks, rounded to `dtype`. By nilpotence it is ``(I + N)(I + N^2)(I +
-    N^4)...`` with ``N = -a``, kept as ``I + m`` in float32 and multiplied
-    at `highest`, whatever the program's matmul precision: the powers of N
-    cancel in the sum, and a squaring doubles the relative error of its
-    operand, so one bfloat16 pass (2^-9) would leave the highest powers
-    with a tenth of themselves. The products are passes over every chunk's
-    (C, C) block in HBM, so the precision costs little: 7.6 against 7.5 ms
-    for 2 x 32 heads of 4,096 positions on a v5e.
-
-    Its backward is the inverse's own, ``-T^T G T^T``: two products where
-    autodiff through the ten above makes twenty, each a pass over every
-    chunk's (C, C) block in HBM. The result carries `INVERSE_NAME`, so that
-    a recomputing caller can keep it (34 MB a layer at 2 x 32 heads and
-    4,096 positions) and not make the ten again."""
+def _inverse_xla(a, dtype):
+    """The factors as batched XLA products: ``I + m`` in float32, ten
+    products for C = 64, each a pass over every block in HBM."""
     C, hi = a.shape[-1], lax.Precision.HIGHEST
     n = -a
     m = n
@@ -101,14 +97,134 @@ def _unit_lower_inverse(a, dtype):
     return (jnp.eye(C, dtype=F32) + m).astype(dtype)
 
 
+def _inverse_bwd_xla(t, g):
+    left = _bmm(t, g, "...ji,...jk->...ik").astype(t.dtype)
+    return -_bmm(left, t, "...ij,...kj->...ik")
+
+
+_LANES = 128                # the MXU's width: two blocks of 64 side by side
+_GROUP = {64: 32, 128: 8}   # C: blocks a grid step (16 MiB of VMEM hold them)
+
+
+def _inverse_kernel(a_ref, t_ref):
+    """A group's blocks from `a` to T in VMEM. The same factors as
+    `_inverse_xla`, ordered so that a level's two products share their
+    right operand and run as one, stacked on rows: ``[N^p; I + M] N^p``
+    gives ``N^2p`` and ``(I + M) N^p``. The refs hold the blocks `pack` at
+    a time, (G / pack, pack, C, C): where C is half the MXU's width a pair
+    lies side by side on lanes, (C, 2C), against its block-diagonal
+    (2C, 2C), so every product is 128 wide."""
+    _, pack, C, _ = a_ref.shape
+    hi = lax.Precision.HIGHEST
+    row = lax.broadcasted_iota(jnp.int32, (C, pack * C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, pack * C), 1)
+
+    def times(x, y):    # x (G, rows, W) by the block-diagonal of y (G, C, W)
+        if pack > 1:
+            y = jnp.concatenate([jnp.where(col // C == p, y, 0.0)
+                                 for p in range(pack)], axis=-2)
+        return jnp.einsum("gij,gjk->gik", x, y, precision=hi,
+                          preferred_element_type=F32)
+
+    n = -jnp.concatenate([a_ref[:, p] for p in range(pack)], axis=-1)
+    t = (row == col % C).astype(F32) + n                # I + N
+    n = times(n, n)
+    power = 4
+    while power < C:
+        both = times(jnp.concatenate([n, t], axis=-2), n)
+        n, t = both[:, :C], t + both[:, C:]
+        power *= 2
+    t = t + times(t, n)
+    for p in range(pack):
+        t_ref[:, p] = t[:, :, p * C:(p + 1) * C].astype(t_ref.dtype)
+
+
+def _inverse_bwd_kernel(t_ref, g_ref, da_ref):
+    da_ref[...] = _inverse_bwd_xla(t_ref[...], g_ref[...])
+
+
+def _grouped_blocks(x):
+    """(..., C, C) as (M', C, C), M' the next multiple of the group: zero
+    blocks behind the last (their inverse is I, and nobody reads it)."""
+    x = x.reshape((-1,) + x.shape[-2:])
+    return jnp.pad(x, ((0, -x.shape[0] % _GROUP[x.shape[-1]]), (0, 0),
+                       (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _inverse_pallas(a, dtype, interpret):
+    blocks = _grouped_blocks(a)
+    M, C, _ = blocks.shape
+    pack, group = _LANES // C, _GROUP[C]
+    spec = pl.BlockSpec((group // pack, pack, C, C), lambda i: (i, 0, 0, 0))
+    t = pl.pallas_call(
+        _inverse_kernel, grid=(M // group,), in_specs=[spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((M // pack, pack, C, C), dtype),
+        compiler_params=compiler_params(("parallel",)),
+        interpret=interpret,
+        name="gdn_inverse",     # the HLO instruction, and so the device trace
+    )(blocks.reshape(M // pack, pack, C, C))
+    return t.reshape(M, C, C)[:a.size // (C * C)].reshape(a.shape)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _inverse_bwd_pallas(t, g, interpret):
+    blocks = _grouped_blocks(t)
+    M, C, _ = blocks.shape
+    spec = pl.BlockSpec((_GROUP[C], C, C), lambda i: (i, 0, 0))
+    da = pl.pallas_call(
+        _inverse_bwd_kernel, grid=(M // _GROUP[C],), in_specs=[spec, spec],
+        out_specs=spec, out_shape=jax.ShapeDtypeStruct(blocks.shape, F32),
+        compiler_params=compiler_params(("parallel",)),
+        interpret=interpret, name="gdn_inverse_bwd",
+    )(blocks, _grouped_blocks(g))
+    return da[:t.size // (C * C)].reshape(t.shape)
+
+
+def _takes_kernel(blocks, kernel):
+    """Whether `kernel` runs over these (..., C, C) blocks, counted once a
+    trace: a dispatch, or where the kernels are on and have no group for
+    this C a fallback to the XLA products."""
+    if not pallas_stats.pallas_on():
+        return False
+    if blocks.shape[-1] in _GROUP:
+        note_dispatch(kernel)
+        return True
+    note_fallback("gdn_inverse", "chunk")
+    return False
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, dtype):
+    """``(I + a)^-1`` of strictly lower-triangular (..., C, C) float32
+    blocks, rounded to `dtype`. By nilpotence it is ``(I + N)(I + N^2)(I +
+    N^4)...`` with ``N = -a``, kept in float32 and multiplied at `highest`,
+    whatever the program's matmul precision: the powers of N cancel in the
+    sum, and a squaring doubles the relative error of its operand, so one
+    bfloat16 pass (2^-9) would leave the highest powers with a tenth of
+    themselves. As XLA's the products are passes over every chunk's (C, C)
+    block in HBM, 7.6 ms for 2 x 32 heads of 4,096 positions on a v5e at
+    either precision; the kernel keeps a block in VMEM through all of them.
+
+    Its backward is the inverse's own, ``-T^T G T^T``: two products where
+    autodiff through the ten above makes twenty. The result carries
+    `INVERSE_NAME`, so that a recomputing caller can keep it (34 MB a layer
+    at 2 x 32 heads and 4,096 positions) and not make the ten again."""
+    if _takes_kernel(a, "gdn_inverse"):
+        return _inverse_pallas(a, dtype, pallas_stats.interpret())
+    return _inverse_xla(a, dtype)
+
+
 def _inverse_fwd(a, dtype):
     t = checkpoint_name(_unit_lower_inverse(a, dtype), INVERSE_NAME)
     return t, t
 
 
 def _inverse_bwd(dtype, t, g):
-    left = _bmm(t, g.astype(t.dtype), "...ji,...jk->...ik").astype(t.dtype)
-    return (-_bmm(left, t, "...ij,...kj->...ik"),)
+    g = g.astype(t.dtype)
+    if _takes_kernel(t, "gdn_inverse_bwd"):
+        return (_inverse_bwd_pallas(t, g, pallas_stats.interpret()),)
+    return (_inverse_bwd_xla(t, g),)
 
 
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)

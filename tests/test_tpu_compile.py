@@ -2,6 +2,7 @@
 makes of the main path's pieces at their real sizes. Proves structure, never
 numerics or speed. The topology is described inside a fixture and only in
 this file, so that one xdist worker alone loads the TPU's library."""
+import functools
 import os
 import re
 
@@ -339,6 +340,27 @@ def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
             <= alone.memory_analysis().temp_size_in_bytes)
 
 
+# the cell's 4,096 chunks of 64 a layer; as many positions in chunks of 128
+@pytest.mark.parametrize("dims", [(2, 32, 64, 64, 64), (2, 32, 32, 128, 128)])
+def test_chunk_inverse_kernels_compile_at_the_cells_shape(one_chip,
+                                                          monkeypatch, dims):
+    """`gdn_inverse` and `gdn_inverse_bwd` over `qwen3_next_ep16_s4096`'s
+    chunks (2 x 32 heads of 4,096 positions, float32 in, bfloat16 out),
+    compiled by Mosaic for the described v5e within the 16 MiB of VMEM it
+    gives a kernel that asks for none: one named call a direction, and no
+    product of XLA's over the blocks."""
+    from mxnet_tpu.ops import linear_attention as la, pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+    args = (jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip))
+    text = _compiled_text(jax.grad(
+        lambda a, w: jnp.sum((la._unit_lower_inverse(a, jnp.bfloat16) * w
+                              ).astype(jnp.float32))), *args)
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*? custom-call\(", text)
+    assert sorted(calls) == ["gdn_inverse", "gdn_inverse_bwd"], calls
+    assert " convolution(" not in text and " dot(" not in text
+
+
 def _cell_loss(config, batch):
     """(loss function, parameter shapes, batch shapes) of a benchmark
     configuration at its real size, from shapes alone."""
@@ -369,6 +391,7 @@ def _cell_loss(config, batch):
                         for k in names}
 
 
+@functools.lru_cache(maxsize=None)
 def _lowered_for_tpu(config, batch):
     """(the jaxpr, the module lowered for the TPU) of a configuration's loss
     and gradient, as text that no address, path or line number is part of:
@@ -391,12 +414,14 @@ def _digest(text):
 
 # what PR 34's tree gives (`git checkout c4da908`, this test copied over):
 # a PR that means to change BERT's or Qwen3-Next's step replaces the pair it
-# changes and says so; one that does not has moved a step it shares
+# changes and says so; one that does not has moved a step it shares. PR 36
+# put the delta rule's chunk inverse into the kernels `gdn_inverse` and
+# `gdn_inverse_bwd`: Qwen3-Next's pair is that tree's, BERT's stay PR 34's
 PARENT_STEPS = {
     ("bert_base", (128, 128)): ("191c8e56347535ff", "888006aa458dc3be"),
     ("bert_base", (32, 512)): ("ee54c9a0df876a52", "edf2a4a9d0901870"),
-    ("qwen3_next_80b_a3b_ep16", (2, 4096)): ("f19b2d6ce673f62e",
-                                             "473103da6aa9f622"),
+    ("qwen3_next_80b_a3b_ep16", (2, 4096)): ("46f5b904029e80d0",
+                                             "cf6b0e2f01516336"),
 }
 
 
@@ -413,6 +438,25 @@ def test_the_older_cells_steps_lower_to_what_the_parent_gave(
     jaxpr, module = _lowered_for_tpu(config, batch)
     assert module.count("tpu_custom_call") >= 3
     assert (_digest(jaxpr), _digest(module)) == PARENT_STEPS[config, batch]
+
+
+def test_qwen3_next_step_keeps_its_chunk_inverses_in_the_kernels(monkeypatch):
+    """`qwen3_next_ep16_s4096`'s loss and gradient at its real size, lowered
+    for the TPU: each kernel of the chunk inverse is lowered once and called
+    by the three Gated DeltaNet layers (three times forward, where the
+    result is kept for the backward pass and not made again, three times
+    backward), and none of the ten float32 products at `highest` over the
+    (2, 32, 64, 64, 64) blocks is left in the step."""
+    from mxnet_tpu.ops import pallas_stats
+    monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
+    jaxpr, module = _lowered_for_tpu("qwen3_next_80b_a3b_ep16", (2, 4096))
+    for kernel, call in (("gdn_inverse", "_inverse_pallas"),
+                         ("gdn_inverse_bwd", "_inverse_bwd_pallas")):
+        assert module.count('kernel_name = "%s"' % kernel) == 1
+        assert len(re.findall(r"call @%s\b" % call, module)) == 3
+    assert not re.search(
+        r"f32\[2,32,64,64,64\] = dot_general\[\s+dimension_numbers=[^\n]*\s+"
+        r"precision=\(Precision\.HIGHEST", jaxpr)
 
 
 def test_laguna_step_lowers_with_windowed_and_causal_kernels(monkeypatch):
