@@ -57,6 +57,9 @@ from .block import (_AUX_COLLECTOR, _TRACE_STATE, _flatten, _regroup,
 
 __all__ = ["FusedTrainStep"]
 
+# the compiled step's HLO module: jit names it after `run` (`make_program`)
+STEP_MODULE = "jit_run"
+
 
 # ---------------------------------------------------------------------------
 # per-optimizer split: host-side scalar schedule vs traced device update.
@@ -791,6 +794,9 @@ class FusedTrainStep:
             _telem.inc("fused_step.compile", builds)
             _telem.note_compile(
                 "fused_step:%s" % getattr(self._net, "name", "net"))
+        # under a profiler session the step's scope map is read when the
+        # session ends (`telemetry.module_scopes()`); else nothing
+        _telem.note_step_program(STEP_MODULE, rebuilt=bool(builds))
         if pallas_before is not None:
             # unconditionally: a recompile that fuses ZERO kernels (gate
             # turned off, shapes fell back) must not leave a stale count

@@ -119,25 +119,31 @@ def _encoder_layer(lp, x, cfg):
     # activation either way. The bias is added inside the entry, which
     # returns its gradient from the kernels and spares a pass over the
     # cotangent
-    w, b = _packed_projection(a, cfg.n_heads)
-    o = flash_attention_packed(x @ w, cfg.n_heads, bias=b)
-    x = layer_norm(x + (o @ a["wo"] + a["bo"]), lp["attn_norm"], cfg.norm_eps)
-    f = lp["ffn"]
-    h = jax.nn.gelu(x @ f["w1"] + f["b1"], approximate=True)
-    return layer_norm(x + (h @ f["w2"] + f["b2"]), lp["ffn_norm"],
-                      cfg.norm_eps)
+    # the scopes name a layer's two halves in the compiled step
+    # (`telemetry.module_scopes()`); they change no operation
+    with jax.named_scope("attention"):
+        w, b = _packed_projection(a, cfg.n_heads)
+        o = flash_attention_packed(x @ w, cfg.n_heads, bias=b)
+        x = layer_norm(x + (o @ a["wo"] + a["bo"]), lp["attn_norm"],
+                       cfg.norm_eps)
+    with jax.named_scope("ffn"):
+        f = lp["ffn"]
+        h = jax.nn.gelu(x @ f["w1"] + f["b1"], approximate=True)
+        return layer_norm(x + (h @ f["w2"] + f["b2"]), lp["ffn_norm"],
+                          cfg.norm_eps)
 
 
 def bert_forward(params, tokens, cfg: BertConfig, token_types=None):
     """tokens (B,S) int32 → hidden states (B,S,D) in cfg.dtype."""
     B, S = tokens.shape
-    x = params["word_embed"][tokens]
-    x = x + params["position_embed"][None, :S]
-    if token_types is None:
-        x = x + params["token_type_embed"][0][None, None]
-    else:
-        x = x + params["token_type_embed"][token_types]
-    x = layer_norm(x, params["embed_norm"], cfg.norm_eps)
+    with jax.named_scope("embedding"):
+        x = params["word_embed"][tokens]
+        x = x + params["position_embed"][None, :S]
+        if token_types is None:
+            x = x + params["token_type_embed"][0][None, None]
+        else:
+            x = x + params["token_type_embed"][token_types]
+        x = layer_norm(x, params["embed_norm"], cfg.norm_eps)
     layer = (jax.checkpoint(_encoder_layer, static_argnums=(2,))
              if cfg.remat else _encoder_layer)
     for i in range(cfg.n_layers):

@@ -255,24 +255,30 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     q, k = (chunks(jnp.repeat(x, rep, axis=2) if rep > 1 else x)
             for x in (q, k))
     v, g, beta = chunks(v), chunks(g.astype(F32)), chunks(beta.astype(F32))
-    gsum = jnp.cumsum(g, axis=-1)                       # (B, Hv, N, C)
-    lower = jnp.tril(jnp.ones((C, C), bool))
-    diff = gsum[..., :, None] - gsum[..., None, :]
-    # exp only where i >= j: above the diagonal the difference is positive
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
-    kk = _bmm(k, k, "bhnid,bhnjd->bhnij")
-    a = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
-                  beta[..., None] * kk * decay, 0.0)
-    t = _unit_lower_inverse(a, dt)
-    gamma = jnp.exp(gsum)[..., None]
-    k_beta = k.astype(F32) * beta[..., None]
-    u = _bmm(t, (v.astype(F32) * beta[..., None]).astype(dt),
-             "bhnij,bhnjd->bhnid")
-    w = _bmm(t, (k_beta * gamma).astype(dt), "bhnij,bhnjd->bhnid").astype(dt)
-    attn = (_bmm(q, k, "bhnid,bhnjd->bhnij") * decay).astype(dt)
-    q_in = (q.astype(F32) * gamma).astype(dt)
-    last = gsum[..., -1:]
-    k_out = (k.astype(F32) * jnp.exp(last - gsum)[..., None]).astype(dt)
+    # two scopes for the compiled step's map (`telemetry.module_scopes()`):
+    # what is local to a chunk, and the scan over chunks with what it
+    # carries; the chunking above and below them is the layer's own
+    with jax.named_scope("delta_chunk"):
+        gsum = jnp.cumsum(g, axis=-1)                   # (B, Hv, N, C)
+        lower = jnp.tril(jnp.ones((C, C), bool))
+        diff = gsum[..., :, None] - gsum[..., None, :]
+        # exp only where i >= j: above the diagonal the difference is
+        # positive
+        decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+        kk = _bmm(k, k, "bhnid,bhnjd->bhnij")
+        a = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
+                      beta[..., None] * kk * decay, 0.0)
+        t = _unit_lower_inverse(a, dt)
+        gamma = jnp.exp(gsum)[..., None]
+        k_beta = k.astype(F32) * beta[..., None]
+        u = _bmm(t, (v.astype(F32) * beta[..., None]).astype(dt),
+                 "bhnij,bhnjd->bhnid")
+        w = _bmm(t, (k_beta * gamma).astype(dt),
+                 "bhnij,bhnjd->bhnid").astype(dt)
+        attn = (_bmm(q, k, "bhnid,bhnjd->bhnij") * decay).astype(dt)
+        q_in = (q.astype(F32) * gamma).astype(dt)
+        last = gsum[..., -1:]
+        k_out = (k.astype(F32) * jnp.exp(last - gsum)[..., None]).astype(dt)
 
     def step(state, xs):
         u_n, w_n, attn_n, q_n, k_n, decay_n = xs
@@ -285,10 +291,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
 
     def by_chunk(x):
         return jnp.moveaxis(x, 2, 0)
-    xs = tuple(by_chunk(x) for x in (u, w, attn, q_in, k_out,
-                                     jnp.exp(last)[..., None]))
-    _, o = lax.scan(step, jnp.zeros((B, Hv, dk, dv), F32), xs)
-    o = jnp.moveaxis(o, 0, 2)                           # (B, Hv, N, C, dv)
+    with jax.named_scope("delta_scan"):
+        xs = tuple(by_chunk(x) for x in (u, w, attn, q_in, k_out,
+                                         jnp.exp(last)[..., None]))
+        _, o = lax.scan(step, jnp.zeros((B, Hv, dk, dv), F32), xs)
+        o = jnp.moveaxis(o, 0, 2)                       # (B, Hv, N, C, dv)
     return jnp.moveaxis(o, 1, 3).reshape(B, N * C, Hv, dv)[:, :S]
 
 

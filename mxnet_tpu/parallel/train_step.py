@@ -28,6 +28,9 @@ from .sharding import ShardingRules, shard_pytree
 
 __all__ = ["ShardedTrainStep", "sgd_init", "adam_init"]
 
+# the compiled step's HLO module: jit names it after `step_fn` (`_build`)
+STEP_MODULE = "jit_step_fn"
+
 
 def _tmap(f, *trees):
     return jax.tree_util.tree_map(f, *trees)
@@ -347,7 +350,8 @@ class ShardedTrainStep:
                         "ShardedTrainStep", len(self._sig_seen),
                         _retrace_reason((True, sig), (True, prev)))
         pallas_before = None
-        if self._compiled is None:
+        fresh = self._compiled is None
+        if fresh:
             self._batch_proto = batch
             self._compiled = self._build(params, opt_state)
             self._aot = self._maybe_aot(params, opt_state, batch, step_num,
@@ -363,16 +367,21 @@ class ShardedTrainStep:
         with _telem.span("train_step.launch", "phase"):
             step_num = jnp.asarray(step_num, jnp.int32)
             if self._aot is not None and sig == self._aot_sig:
-                return self._aot(params, opt_state, batch, step_num)
-            # counted from jit's own cache, so the counter says what XLA
-            # built: the program builds again when the batch's signature
-            # changes, and when a parameter's or a state's dtype does
-            built = self._compiled._cache_size()
-            out = self._compiled(params, opt_state, batch, step_num)
-            builds = self._compiled._cache_size() - built
+                out = self._aot(params, opt_state, batch, step_num)
+                builds = 0
+            else:
+                # counted from jit's own cache, so the counter says what XLA
+                # built: the program builds again when the batch's signature
+                # changes, and when a parameter's or a state's dtype does
+                built = self._compiled._cache_size()
+                out = self._compiled(params, opt_state, batch, step_num)
+                builds = self._compiled._cache_size() - built
         if builds:
             _telem.inc("train_step.compile", builds)
             _telem.note_compile("ShardedTrainStep")
+        # under a profiler session the step's scope map is read when the
+        # session ends (`telemetry.module_scopes()`); else nothing
+        _telem.note_step_program(STEP_MODULE, rebuilt=fresh or bool(builds))
         if pallas_before is not None:
             # unconditional: a zero-kernel recompile must clear a stale
             # count from an earlier gated-on program

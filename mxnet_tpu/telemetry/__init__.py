@@ -31,7 +31,15 @@ reports through it). Instrumented hot paths:
   `jit`) from jax's own duration events, one set per program built;
   `jit.xla` is a compile or a restore from the persistent cache;
 * memory — best-effort `memory.*.bytes_in_use` watermark gauges from the
-  PjRt allocator (memory.py).
+  PjRt allocator (memory.py);
+* the compiled step's scopes — under a profiler session both train steps
+  call `note_step_program`, which reads the loaded step's optimized module
+  once, on a thread of its own, when the session is over;
+  `module_scopes()` then maps every instruction of the step to its opcode,
+  its `op_name` (the `jax.named_scope`s it was traced under) and its place
+  in the module, which is what tells a device trace's `fusion.183` that it
+  is the backward of the `ffn` block (hlo_scopes.py). Without a session a
+  step pays one `is_enabled()` call.
 
 One clock with the profiler: `span()` also enters a
 `jax.profiler.TraceAnnotation`, so under any profiler session
@@ -91,7 +99,9 @@ Observability v3 — the per-request / per-step / per-fleet evidence layer:
 """
 from __future__ import annotations
 
+import _thread
 import json
+import logging
 import os
 import threading
 import time
@@ -106,6 +116,8 @@ from .trace import (TraceBuffer, write_chrome_trace,
                     write_merged_chrome_trace)
 from . import memory as _memory
 
+_LOG = logging.getLogger("mxnet_tpu.telemetry")
+
 __all__ = ["enabled", "enable", "disable", "registry", "counter", "gauge",
            "histogram", "inc", "set_gauge", "observe", "span", "step_span",
            "record_span",
@@ -114,6 +126,7 @@ __all__ = ["enabled", "enable", "disable", "registry", "counter", "gauge",
            "aggregate_snapshot", "merge_snapshots", "aggregate_trace",
            "sample_memory", "maybe_sample_memory",
            "note_compile", "recent_compiles", "device_report",
+           "note_step_program", "module_scopes",
            "trace_id", "set_trace_id", "safe_rank", "local_trace_dump",
            "step_event", "step_quantiles", "flight_records",
            "request_traces", "overlap_report",
@@ -391,6 +404,115 @@ def recent_compiles(limit=None):
     return events
 
 
+# ---------------------------------------------------------------- scopes
+# A profiler session's trace names instructions; the module that a step
+# compiled says under which scopes each was traced. {module name:
+# _StepScopes}, the newest last.
+_SCOPES_LIMIT = 4
+_SESSION_POLL_S = 0.2   # how often a waiting worker asks if the session is on
+_scopes = {}
+_scopes_lock = threading.Lock()
+_session_on = getattr(TraceAnnotation, "is_enabled", lambda: False)
+
+
+class _StepScopes:
+    """A step program's scope map on its way: a worker reads it once the
+    session is over and sets `done`; `scopes` is what it read, None where
+    it could not (the log says why)."""
+    __slots__ = ("done", "scopes")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.scopes = None
+
+
+def _step_module(module_name):
+    """The optimized HLO module of the newest loaded program of that name
+    (the runtime lists the newest first), or None. The runtime's list and
+    the executable are this call's alone, gone when it returns: a kept
+    reference would keep the step's reserved temporaries loaded after the
+    step is dropped."""
+    for executable in jax.devices()[0].client.live_executables():
+        module = executable.hlo_modules()[0]
+        if module.name == module_name:
+            return module
+    return None
+
+
+def _read_scopes(module_name, entry):
+    """The worker. It sleeps through the session: the runtime takes seconds
+    to hand over a step's optimized module (2.6 s for BERT-base's, 4.7 s
+    for Qwen3-Next's on a v5e, PERF.md), and a window that dispatches steps
+    meanwhile reads 1-2 ms a call longer. Then it takes the module, which
+    holds nothing of the executable, and parses its text. A step that was
+    dropped before the worker woke has no map, and the log says so."""
+    try:
+        while _session_on():
+            time.sleep(_SESSION_POLL_S)
+        if _scopes.get(module_name) is not entry:   # rebuilt or reset
+            return
+        # not before: the import takes the interpreter 3 ms, which a thread
+        # that dispatches a window's first steps would wait for
+        from . import hlo_scopes
+        module = _step_module(module_name)
+        if module is None:
+            _LOG.warning("no scope map of %s: no loaded program bears the "
+                         "name", module_name)
+        else:
+            entry.scopes = hlo_scopes.parse(module.to_string())
+    except Exception:  # noqa: BLE001 - a record, never a failed step
+        _LOG.warning("no scope map of %s", module_name, exc_info=True)
+    finally:
+        entry.done.set()
+
+
+def note_step_program(module_name, rebuilt=False):
+    """Called by a train step after it launched the program whose module
+    is `module_name` (`jit_step_fn`, `jit_run`); `rebuilt` where that call
+    built or restored it. Under a profiler session, and only there, the
+    first such call starts the thread that reads the loaded program's scope
+    map for `module_scopes()` when the session ends; a rebuilt program's
+    is read again. Without a session (`TraceAnnotation.is_enabled()`, false
+    on a jax that lacks it) nothing is read: a rebuilt program only forgets
+    its map."""
+    if not ENABLED:
+        return
+    if rebuilt:
+        with _scopes_lock:
+            _scopes.pop(module_name, None)
+    if not _session_on() or module_name in _scopes:
+        return
+    with _scopes_lock:
+        if module_name in _scopes:
+            return
+        entry = _scopes[module_name] = _StepScopes()
+        for stale in list(_scopes)[:-_SCOPES_LIMIT]:
+            del _scopes[stale]
+    # the low-level start returns at once; `Thread.start()` waits for the
+    # new thread to run, about a millisecond of the call that dispatches
+    try:
+        _thread.start_new_thread(_read_scopes, (module_name, entry))
+    except RuntimeError:    # no thread to be had: no map, and nobody waits
+        _LOG.warning("no scope map of %s", module_name, exc_info=True)
+        entry.done.set()
+
+
+def module_scopes():
+    """{module name: {instruction: Instr(opcode, op_name, computation,
+    calls)}} (`hlo_scopes.parse`) of the step programs that ran under a
+    profiler session, the newest four. It outlives the step (nothing of the
+    executable is kept) and is cleared by `reset()`. Ask after the session:
+    a map whose worker is at its read is waited for, seconds for a large
+    step, and while a session is on, the programs it saw have none yet."""
+    with _scopes_lock:
+        entries = dict(_scopes)
+    if not _session_on():
+        for entry in entries.values():
+            entry.done.wait()
+    return {name: entry.scopes for name, entry in entries.items()
+            if entry.scopes is not None}
+
+
 # ---------------------------------------------------------------- memory
 def device_report():
     """Best-effort per-device PjRt state (allocator stats + live-buffer
@@ -452,13 +574,15 @@ def compile_report():
 
 
 def reset():
-    """Drop all metrics, recorded spans, the compile ring, the flight
-    recorder, the request-trace ring, the anomaly windows, the memory
-    ledger, and the profiling state (does not change ENABLED)."""
+    """Drop all metrics, recorded spans, the compile ring, the steps' scope
+    maps, the flight recorder, the request-trace ring, the anomaly windows,
+    the memory ledger, and the profiling state (does not change ENABLED)."""
     registry.reset()
     _trace.clear()
     with _compiles_lock:
         del _compiles[:]
+    with _scopes_lock:
+        _scopes.clear()
     from . import anomaly as _anomaly, flight as _flight
     from . import ledger as _ledger, profiling as _profiling
     from . import request_trace as _reqtrace

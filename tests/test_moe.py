@@ -407,7 +407,7 @@ def test_the_rungs_that_ran_are_read_from_a_module_and_its_events(
     text = jax.jit(jax.value_and_grad(lambda *a: moe.moe_routed(
         *a, top_k=2, row_tile=8).sum(), argnums=(0, 2))).lower(
             x, router, *weights).compile().as_text()
-    filed, direction = tool.rung_of_instruction(text)
+    filed, direction = tool.rung_of_instruction(tool.hlo_scopes.parse(text))
     assert sorted(direction.values()) == ["backward", "forward"]
     assert {rows for _, rows in filed.values()} == {136, 264, 528}
     forward = next(c for c, way in direction.items() if way == "forward")

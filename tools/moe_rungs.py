@@ -10,10 +10,12 @@ which. But every instruction of a rung's body carries `rows_<R>` in its
 `op_name` (the body's `jax.named_scope`), each `lax.switch` is one
 `conditional` of the compiled module with a branch a rung, and the device
 trace names every instruction that ran. So: build the cell's own runner
-(its weights and batch from the seed), take its first three steps, read the
-step's compiled module from the loaded executable, trace a short window,
-and file each traced operation under the (conditional, rung) whose branch
-computation holds it.
+(its weights and batch from the seed), take its first three steps, trace a
+short window, take the step's compiled module as the step itself read it
+under the profiler's session (`mxnet_tpu.telemetry.module_scopes()`), and
+file each traced operation under the (conditional, rung) whose branch
+computation holds it. A container inside a branch (a `while`, a `call`) is
+filed nowhere: its own event covers operations that are filed already.
 
 Printed, and written to `chiprun_out/moe_rungs/<workload>_<seed>.json`: the
 trace-time counters `ops.moe.ladder.<R>` (a rung that compiled); for every
@@ -34,67 +36,53 @@ import tempfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
-_HEADER = re.compile(r"^(?:ENTRY )?(%?[\w.\-]+) \(.*\) -> .* \{$")
-_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = ")
-_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=(%[\w.\-]+)")
-_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+sys.path.insert(0, ROOT)
+from mxnet_tpu.parallel.train_step import STEP_MODULE  # noqa: E402
+from mxnet_tpu.telemetry import hlo_scopes  # noqa: E402
 _SCOPE = re.compile(r"/rows_(\d+)/")
 
 
-def computations(text):
-    """{computation: [instruction lines]} of a compiled module's text."""
-    out, lines = {}, None
-    for line in text.splitlines():
-        m = _HEADER.match(line)
-        if m:
-            lines = out.setdefault(m.group(1).lstrip("%"), [])
-        elif line == "}":
-            lines = None
-        elif lines is not None:
-            lines.append(line)
-    return out
-
-
-def rung_of_instruction(text):
+def rung_of_instruction(instrs):
     """({instruction: (conditional, rows)}, {conditional: "forward" |
-    "backward"}): every instruction of a branch computation of a
+    "backward"}) of a compiled module as `mxnet_tpu.telemetry.hlo_scopes.
+    parse` gives it: every instruction of a branch computation of a
     conditional whose branches carry `rows_<R>` scopes, and of what that
     branch calls, filed under the branch's rung."""
-    comps = computations(text)
+    held = collections.defaultdict(list)    # computation -> its instructions
+    for name, instr in instrs.items():
+        held[instr.computation].append(name)
 
-    def reach(name, seen):
-        if name in seen or name not in comps:
+    def reach(computation, seen):
+        if computation in seen or computation not in held:
             return
-        seen.add(name)
-        for line in comps[name]:
-            for callee in _CALLED.findall(line):
-                reach(callee.lstrip("%"), seen)
+        seen.add(computation)
+        for name in held[computation]:
+            for callee in instrs[name].calls:
+                reach(callee, seen)
 
     filed, direction = {}, {}
-    for lines in comps.values():
-        for line in lines:
-            branches = _BRANCHES.search(line)
-            if not branches or " conditional(" not in line:
-                continue
-            by_rows = {}
-            for branch in branches.group(1).split(","):
-                inside = set()
-                reach(branch.strip().lstrip("%"), inside)
-                body = [ln for name in inside for ln in comps[name]]
-                rows = collections.Counter(
-                    int(r) for ln in body for r in _SCOPE.findall(ln))
-                if rows:
-                    by_rows[rows.most_common(1)[0][0]] = body
-            if len(by_rows) < 2:    # an interpreted kernel's `pl.when`
-                continue
-            conditional = _INSTR.match(line).group(1)
-            direction[conditional] = (
-                "backward" if "transpose(" in line else "forward")
-            for rung, body in by_rows.items():
-                for ln in body:
-                    m = _INSTR.match(ln)
-                    if m:
-                        filed[m.group(1)] = (conditional, rung)
+    for conditional, instr in instrs.items():
+        if instr.opcode != "conditional":
+            continue
+        by_rows = {}
+        for branch in instr.calls:
+            inside = set()
+            reach(branch, inside)
+            body = [name for computation in inside
+                    for name in held[computation]]
+            rows = collections.Counter(
+                int(r) for name in body
+                for r in _SCOPE.findall(instrs[name].op_name))
+            if rows:
+                by_rows[rows.most_common(1)[0][0]] = body
+        if len(by_rows) < 2:    # an interpreted kernel's `pl.when`
+            continue
+        direction[conditional] = (
+            "backward" if "transpose(" in instr.op_name else "forward")
+        for rung, body in by_rows.items():
+            for name in body:
+                if instrs[name].opcode not in hlo_scopes.CONTAINERS:
+                    filed[name] = (conditional, rung)
     return filed, direction
 
 
@@ -151,16 +139,6 @@ def by_rung(events, filed, direction, steps, row_tile):
                     for rows, (runs, ms) in sorted(totals.items())}
 
 
-def step_module_text(name="jit_step_fn"):
-    """The optimized HLO of the loaded step, from the runtime."""
-    import jax
-    for executable in jax.devices()[0].client.live_executables():
-        module = executable.hlo_modules()[0]
-        if module.name == name:
-            return module.to_string()
-    raise SystemExit("moe_rungs: no loaded program %r" % name)
-
-
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -173,7 +151,7 @@ def main():
     ap.add_argument("--rehearse", action="store_true")
     opts = ap.parse_args()
 
-    sys.path[:0] = [ROOT, BENCH_DIR]
+    sys.path.insert(0, BENCH_DIR)
     import run as bench
     cell, devices, _ = bench.start(opts.workload, opts.rehearse)
     import jax
@@ -187,7 +165,6 @@ def main():
                                  cell.rehearse)
     del start, batch
     losses = [float(runner.call()) for _ in range(3)]
-    filed, direction = rung_of_instruction(step_module_text())
 
     def wait(loss):
         loss.block_until_ready()
@@ -202,6 +179,12 @@ def main():
         found = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
                                        "*.xplane.pb"))
         data = trace_reduce.load(found[0]) if found else None
+    # the step read its own module when it saw the profiler's session
+    instrs = telemetry.module_scopes().get(STEP_MODULE)
+    if instrs is None:
+        raise SystemExit("moe_rungs: the traced window left no scope map of "
+                         "%r" % STEP_MODULE)
+    filed, direction = rung_of_instruction(instrs)
     events, steps = [], 0
     for plane in (data.planes if data is not None else ()):
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
@@ -213,7 +196,7 @@ def main():
                           for ev in line.events]
             elif line.name == "XLA Modules":
                 steps = sum(1 for ev in line.events
-                            if ev.name.startswith("jit_step_fn"))
+                            if ev.name.startswith(STEP_MODULE))
         break       # the first chip
     conditionals, rungs = by_rung(events, filed, direction, steps,
                                   moe.ROW_TILE)
