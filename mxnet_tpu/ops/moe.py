@@ -26,35 +26,43 @@ at once that the layer computed another function (a larger bound cures it).
 The buffer is described by one expert id a row tile, which the kernels read
 through scalar prefetch.
 
-The ladder. The buffer is as long as the worst routing needs, and most
+The chunks. The buffer is as long as the worst routing needs, and most
 routings fill a small part of it: the held experts' rows lie one after
 another from row 0, so the rows in use are a prefix, `Dispatch.n_used` tiles
 long. Everything that touches the buffer (the row gather, the three grouped
 products, `silu(gate) * up`, the router's share, the float32 scatter-add, and
-their backward passes) therefore runs over a prefix of it, chosen on the
-device: `prefix_ladder` gives a few static lengths from shapes alone, the
-whole buffer and its halves for as long as a half still holds `_HEADROOM`
-times what uniform routing sends the held experts with every expert's
-alignment tile, and a `lax.switch` takes the smallest that holds `n_used`.
-The longest rung is the whole buffer, so no routing drops a pair that the
-buffer held; a buffer too short to halve has one rung and no conditional. A
-rung's body is
-`_routed_rows`, under `jax.named_scope("rows_<R>")` (every instruction of
-the compiled module says which rung it belongs to: `tools/moe_rungs.py`
-reads from a device trace how often each ran) and counted once a trace as
-`ops.moe.ladder.<R>`.
+their backward passes) therefore walks it in chunks of `CHUNK_TILES` tiles,
+for as many chunks as hold `n_used` tiles (`_trips`): one `lax.fori_loop` a
+direction whose bound is read on the device, so one copy of the layer's code
+whatever the routing, and work that follows `n_used` in steps of a chunk.
+The last chunk reaches the buffer's end (the plan is padded to whole chunks
+with tiles that are never in use), so no routing drops a pair that the
+buffer held. Inside a trip the two scatter-adds into (T, d) sums (the
+tokens' sum forward, their cotangent backward) go piece by piece of
+`_SCATTER_TILES` tiles, for as many pieces as hold the chunk's tiles in use
+(`_added_in_pieces`): XLA's scatter-add passes over all of its operand once
+it is handed more than 1,024 rows. A buffer no longer than one chunk is
+`_routed_rows` over all of it under plain autodiff, with no loop. A chunk's
+body runs under `jax.named_scope("rows_<C * row_tile>")` (every instruction
+of the compiled module says that it belongs to the buffer's rows:
+`tools/moe_rungs.py` reads from a device trace how many trips each loop
+made) and is counted once a trace as `ops.moe.chunk.<rows>`.
 
-The derivative of that switch is one `custom_vjp` (`_routed`) with a switch
-in each direction. Differentiating `lax.switch` as it stands makes every
-branch return the union of all branches' residuals, zero-filled where a
-branch has none: every rung would write buffers of the longest rung's size.
-So the forward rule keeps the layer's inputs and the plan and nothing else,
-and the backward rule is a switch whose branch takes `jax.vjp` of its own
-rung's body: it makes that rung's forward again and pulls the cotangent back
-through it. Under `jax.checkpoint`, which is how the decoder calls the
-layer, the recomputed forward switch is dead and the products run as often
-as without the ladder; differentiated without it, the rung's forward runs
-twice.
+The derivative of the loop is one `custom_vjp` (`_routed`) with a loop in
+each direction: a `while` with a traced bound has no transpose. The forward
+rule keeps the layer's inputs and the plan and nothing else; the backward
+rule makes a chunk's forward again and pulls the cotangent back through it
+by hand, chunk by chunk. It carries the float32 cotangent of the tokens, the
+router's weights' cotangent and the three weight gradients. An expert's rows
+may lie on both sides of a chunk's edge, so `moe_tgmm` there writes into the
+gradient it is handed (`input_output_aliases`): an expert's first tile in
+the whole buffer starts its block, every later tile adds to it, in whichever
+chunk it lies, and a chunk leaves the blocks of the experts it does not
+visit as they were. Every held expert has a tile in use, so the loop writes
+every block and the gradients start as they are allocated, not zero-filled.
+Under `jax.checkpoint`, which is how the decoder calls the layer, the
+recomputed forward loop is dead and the products run as often as without
+the loop.
 
 The grouped product is two Pallas kernels, named for the device trace:
 
@@ -92,14 +100,13 @@ __all__ = ["route_top_k", "plan_dispatch", "grouped_matmul", "moe_routed",
 F32 = jnp.float32
 ROW_TILE = 128          # rows of a tile: one pass of the MXU's 128 columns
 _BLOCK_ELEMENTS = 1 << 19   # of a weight block: 2 MB in float32
-# of the ladder's shortest rung over what uniform routing fills. A rung is a
-# copy of the layer's code in each direction, in every layer: about a second
-# of every start to restore and trace it (the benchmark's `setup_s`), and a
-# routing that sits at a rung's length flips between two rungs from step to
-# step. So the rungs are few, and the shortest stands well clear of where
-# routings sit (PERF.md section 6, PR 34, has the measurements).
-_HEADROOM = 4
-
+# tiles of a chunk of the buffer: what the layer works over is `n_used` rounded
+# up to whole chunks, so a routing at a chunk's edge flips the step's time by
+# a chunk's work from step to step; against that, a trip's fixed cost (twelve
+# kernel launches, the slices of the plan). PERF.md section 6, PR 38, has the
+# lengths that were read on the chip.
+CHUNK_TILES = 32
+_SCATTER_TILES = 8      # of a piece of a chunk's scatter-add: 1,024 rows
 
 # ------------------------------------------------------------------ routing
 def _largest(p, k):
@@ -214,9 +221,15 @@ def _gmm_kernel(tile_expert, n_used, lhs_ref, rhs_ref, out_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-def _tgmm_kernel(tile_expert, n_used, lhs_ref, dy_ref, out_ref):
+def _tgmm_kernel(tile_expert, n_used, *refs, into):
+    if into:
+        continues, lhs_ref, dy_ref, before_ref, out_ref = refs
+    else:
+        lhs_ref, dy_ref, out_ref = refs
     i = pl.program_id(1)
     first = (i == 0) | (tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)])
+    # tile 0 goes on with the expert whose earlier rows another call summed
+    resumes = (i == 0) & (continues[0] != 0) if into else False
 
     @pl.when(i < n_used[0])
     def _():
@@ -225,7 +238,12 @@ def _tgmm_kernel(tile_expert, n_used, lhs_ref, dy_ref, out_ref):
         acc = lax.dot_general(lhs_t, dy_ref[...], (((1,), (0,)), ((), ())),
                               preferred_element_type=F32)
 
-        @pl.when(first)
+        if into:
+            @pl.when(resumes)
+            def _():
+                out_ref[0] = before_ref[0] + acc.astype(out_ref.dtype)
+
+        @pl.when(first & jnp.logical_not(resumes))
         def _():
             out_ref[0] = acc.astype(out_ref.dtype)
 
@@ -269,26 +287,40 @@ def _gmm(lhs, rhs, tile_expert, n_used, transpose_rhs, row_tile, interpret):
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _tgmm(lhs, dy, tile_expert, n_used, n_experts, out_dtype, row_tile,
-          interpret):
-    """out (E, K, N): out[e] = lhs[rows of e].T @ dy[rows of e]."""
+          interpret, into=None, continues=None):
+    """out (E, K, N): out[e] = lhs[rows of e].T @ dy[rows of e]; the blocks
+    of experts without a tile in use are not written. With `into` (E, K, N)
+    the result is written into that array: the blocks of the experts these
+    rows do not visit stay, and where `continues` (1,) is not 0 the expert
+    of tile 0 adds to its block in place of starting it."""
     R, K = lhs.shape
     N = dy.shape[1]
     tn = _column_block(N, K)
+    scalars, operands = [tile_expert, n_used], [lhs, dy]
+    in_specs = [pl.BlockSpec((row_tile, K), lambda c, i, t, n, *_:
+                             (_last_used(i, n), 0)),
+                pl.BlockSpec((row_tile, tn), lambda c, i, t, n, *_:
+                             (_last_used(i, n), c))]
+    if into is not None:
+        scalars.append(continues)
+        operands.append(into)
+        # tile 0's expert alone is read, once a column block
+        in_specs.append(pl.BlockSpec((1, K, tn), lambda c, i, t, n, *_:
+                                     (t[0], 0, c)))
     return pl.pallas_call(
-        _tgmm_kernel,
+        functools.partial(_tgmm_kernel, into=into is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(N // tn, R // row_tile),
-            in_specs=[pl.BlockSpec((row_tile, K), lambda c, i, t, n:
-                                   (_last_used(i, n), 0)),
-                      pl.BlockSpec((row_tile, tn), lambda c, i, t, n:
-                                   (_last_used(i, n), c))],
-            out_specs=pl.BlockSpec((1, K, tn), lambda c, i, t, n:
+            num_scalar_prefetch=len(scalars), grid=(N // tn, R // row_tile),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, K, tn), lambda c, i, t, n, *_:
                                    (t[_last_used(i, n)], 0, c))),
         out_shape=jax.ShapeDtypeStruct((n_experts, K, N), out_dtype),
+        input_output_aliases=({} if into is None else
+                              {len(scalars) + len(operands) - 1: 0}),
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         name="moe_tgmm",
-    )(tile_expert, n_used, lhs, dy)
+    )(*scalars, *operands)
 
 
 def _row_masks(tile_expert, n_used, row_tile, n_experts):
@@ -349,17 +381,28 @@ def _grouped_fwd(lhs, rhs, tile_expert, n_used, row_tile, pallas):
             (lhs, rhs, tile_expert, n_used))
 
 
-def _grouped_bwd(row_tile, pallas, res, dy):
-    lhs, rhs, tile_expert, n_used = res
+def _pulled_back(lhs, rhs, dy, tile_expert, n_used, row_tile, pallas,
+                 into=None, continues=None):
+    """(the cotangent of lhs, that of rhs) of the grouped product from its
+    result's cotangent dy. With `into`, rhs's is summed into that array, as
+    `_tgmm` says."""
     dy = dy.astype(lhs.dtype)
     dlhs = _product(dy, rhs, tile_expert, n_used, True, row_tile, pallas)
     if pallas:
         drhs = _tgmm(lhs, dy, tile_expert, n_used, rhs.shape[0], rhs.dtype,
-                     row_tile, pallas_stats.interpret())
+                     row_tile, pallas_stats.interpret(), into, continues)
     else:
         drhs = _tgmm_loop(lhs, dy, tile_expert, n_used, rhs.shape[0],
                           rhs.dtype, row_tile)
-    return dlhs, drhs, None, None
+        if into is not None:
+            drhs = into + drhs
+    return dlhs, drhs
+
+
+def _grouped_bwd(row_tile, pallas, res, dy):
+    lhs, rhs, tile_expert, n_used = res
+    return _pulled_back(lhs, rhs, dy, tile_expert, n_used, row_tile,
+                        pallas) + (None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
@@ -378,73 +421,171 @@ def grouped_matmul(lhs, rhs, tile_expert, n_used, row_tile=ROW_TILE):
     return _grouped(lhs, rhs, tile_expert, n_used, row_tile, reason is None)
 
 
-# --------------------------------------------------------------- the ladder
-def prefix_ladder(n_tokens, top_k, n_held, n_experts, n_tiles,
-                  row_tile=ROW_TILE):
-    """The prefix lengths, in tiles and ascending, that the layer may work
-    over: the whole buffer (`n_tiles`), and its halves for as long as one
-    still holds `_HEADROOM` times what uniform routing over `n_experts`
-    sends the `n_held` held, with every held expert's alignment tile. From
-    shapes alone."""
-    expected = -(-n_tokens * top_k * n_held // (n_experts * row_tile)) + n_held
-    rungs = [n_tiles]
-    while rungs[-1] > 1 and -(-rungs[-1] // 2) >= _HEADROOM * expected:
-        rungs.append(-(-rungs[-1] // 2))
-    return tuple(reversed(rungs))
+# --------------------------------------------------------------- the chunks
+def _gated(gate_out, up):
+    return (jax.nn.silu(gate_out.astype(F32)) * up.astype(F32)
+            ).astype(gate_out.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "top_k", "row_tile",
-                                             "gate"))
-def _routed_rows(x, weights, w_gate, w_up, w_down, plan, *, rows, top_k,
-                 row_tile, gate):
-    """(T, d) float32: the held experts' sum over the first `rows` rows of
-    the buffer, which hold every pair (the caller chose them so). `gate` is
-    what `pallas_stats` answered the caller: `grouped_matmul` asks it again
-    while this is traced, so it belongs to the trace's key. Jitted here so
-    that a model's layers share one trace of each rung."""
+def _rows_forward(x, weights, w_gate, w_up, w_down, rows, top_k, row_tile):
+    """Over the rows of the buffer that `rows`, a `Dispatch`, describes:
+    (each row's token (R,), its share of it (R,), what its expert gives it
+    (R, d) float32), and the products' operands on the way (the rows picked,
+    the gate's and the up projection's results, the hidden rows)."""
+    token = rows.row_pair // top_k
+    picked = x[token]
+    gate_out = grouped_matmul(picked, w_gate, rows.tile_expert, rows.n_used,
+                              row_tile)
+    up = grouped_matmul(picked, w_up, rows.tile_expert, rows.n_used, row_tile)
+    hidden = _gated(gate_out, up)
+    out = grouped_matmul(hidden, w_down, rows.tile_expert, rows.n_used,
+                         row_tile)
+    share = jnp.where(rows.row_valid, weights.reshape(-1)[rows.row_pair], 0.0)
+    return (token, share, out.astype(F32)), (picked, gate_out, up, hidden)
+
+
+def _added_in_pieces(token, rows, n_used, row_tile, combined):
+    """`combined` (T, d) float32 with `rows` (R, d) added at `token` (R,),
+    for the first `n_used` (1,) tiles of rows: piece by piece of
+    `_SCATTER_TILES` tiles, as many as hold them. Up to 1,024 rows XLA's
+    scatter-add for the TPU costs what its rows cost (0.14 ms at d = 2,048);
+    one row more and it passes over all of `combined` first (0.43 ms and
+    0.09 ms a thousand rows), which was half of a trip."""
+    piece = _SCATTER_TILES * row_tile
+    # a slice that started short of a piece before the end would be moved
+    # back, and add rows twice
+    assert rows.shape[0] % piece == 0, (rows.shape, piece)
+
+    def add(i, combined):
+        return combined.at[lax.dynamic_slice(token, (i * piece,), (piece,))
+                           ].add(lax.dynamic_slice(
+                               rows, (i * piece, 0), (piece, rows.shape[1])))
+    return lax.fori_loop(0, _trips(n_used, _SCATTER_TILES), add, combined)
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "row_tile", "gate"))
+def _routed_rows(x, weights, w_gate, w_up, w_down, plan, *, top_k, row_tile,
+                 gate):
+    """(T, d) float32: the held experts' sum over the whole buffer, for a
+    buffer no longer than a chunk. `gate` is what `pallas_stats` answered
+    the caller: `grouped_matmul` asks it again while this is traced, so it
+    belongs to the trace's key. Jitted here so that a model's layers share
+    one trace."""
     del gate
-    pallas_stats.note_rung(rows)
-    with jax.named_scope("rows_%d" % rows):
-        row_pair, row_valid = plan.row_pair[:rows], plan.row_valid[:rows]
-        tile_expert = plan.tile_expert[:rows // row_tile]
-        token = row_pair // top_k
-        picked = x[token]
-        gate_out = grouped_matmul(picked, w_gate, tile_expert, plan.n_used,
-                                  row_tile)
-        up = grouped_matmul(picked, w_up, tile_expert, plan.n_used, row_tile)
-        hidden = (jax.nn.silu(gate_out.astype(F32)) * up.astype(F32)
-                  ).astype(x.dtype)
-        out = grouped_matmul(hidden, w_down, tile_expert, plan.n_used,
-                             row_tile)
-        share = jnp.where(row_valid, weights.reshape(-1)[row_pair], 0.0)
+    with jax.named_scope("rows_%d" % plan.row_pair.shape[0]):
+        token, share, out = _rows_forward(x, weights, w_gate, w_up, w_down,
+                                          plan, top_k, row_tile)[0]
         # rows of one token lie in different experts' tiles: added up in
         # float32
-        return jnp.zeros(x.shape, F32).at[token].add(
-            out.astype(F32) * share[:, None])
+        return jnp.zeros(x.shape, F32).at[token].add(out * share[:, None])
 
 
-def _rung_index(ladder, n_used, row_tile):
-    """The smallest of `ladder`'s prefixes (rows, ascending) that holds
-    `n_used` tiles."""
-    return jnp.sum(n_used[0] * row_tile > jnp.asarray(ladder[:-1], jnp.int32),
-                   dtype=jnp.int32)
+def _trips(n_used, chunk):
+    """The chunks of `chunk` tiles that hold the first `n_used` (1,) tiles."""
+    return (n_used[0] + chunk - 1) // chunk
 
 
-def _over_ladder(body, ladder, plan, top_k, row_tile, gate, *operands):
-    """`body` of the smallest of `ladder`'s rungs that holds the routing,
-    chosen on the device."""
-    return lax.switch(
-        _rung_index(ladder, plan.n_used, row_tile),
-        [functools.partial(body, rows=rows, top_k=top_k, row_tile=row_tile,
-                           gate=gate) for rows in ladder], *operands)
+def _whole_chunks(plan, chunk, row_tile):
+    """`plan` with its buffer made a whole number of chunks long, by tiles
+    past `n_used`: a slice that started short of a chunk before the end
+    would be moved back, and count rows twice."""
+    tiles = -plan.tile_expert.shape[0] % chunk
+    return plan._replace(
+        row_pair=jnp.pad(plan.row_pair, (0, tiles * row_tile)),
+        row_valid=jnp.pad(plan.row_valid, (0, tiles * row_tile)),
+        tile_expert=jnp.pad(plan.tile_expert, (0, tiles), mode="edge"))
+
+
+def _chunk_of(plan, c, chunk, row_tile):
+    """(chunk c of `plan` as a `Dispatch` of its own, (1,) whether its
+    first tile goes on with the expert of the tile before it)."""
+    first, rows = c * chunk, chunk * row_tile
+    tile_expert = lax.dynamic_slice(plan.tile_expert, (first,), (chunk,))
+    before = lax.dynamic_index_in_dim(
+        plan.tile_expert, jnp.maximum(first - 1, 0), keepdims=False)
+    continues = (c > 0) & (before == tile_expert[0])
+    return Dispatch(
+        lax.dynamic_slice(plan.row_pair, (c * rows,), (rows,)),
+        lax.dynamic_slice(plan.row_valid, (c * rows,), (rows,)), tile_expert,
+        jnp.clip(plan.n_used - first, 0, chunk),
+        plan.n_dropped), continues.astype(jnp.int32)[None]
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "top_k", "row_tile",
+                                             "gate"))
+def _routed_chunks(x, weights, w_gate, w_up, w_down, plan, *, chunk, top_k,
+                   row_tile, gate):
+    """(T, d) float32: `_routed_rows` chunk by chunk over the chunks that
+    hold a pair."""
+    del gate
+    pallas_stats.note_chunk(chunk * row_tile)
+
+    def body(c, combined):
+        with jax.named_scope("rows_%d" % (chunk * row_tile)):
+            rows, _ = _chunk_of(plan, c, chunk, row_tile)
+            token, share, out = _rows_forward(
+                x, weights, w_gate, w_up, w_down, rows, top_k, row_tile)[0]
+            return _added_in_pieces(token, out * share[:, None], rows.n_used,
+                                    row_tile, combined)
+    return lax.fori_loop(0, _trips(plan.n_used, chunk), body,
+                         jnp.zeros(x.shape, F32))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "top_k", "row_tile",
+                                             "gate"))
+def _routed_chunks_vjp(inputs, plan, dy, *, chunk, top_k, row_tile, gate):
+    """The cotangents of `_routed_chunks`'s five inputs: chunk by chunk, the
+    chunk's forward made again and `dy` (T, d) float32 pulled back through
+    it. Jitted here for the same reason: one trace of the backward loop a
+    model."""
+    del gate
+    x, weights, w_gate, w_up, w_down = inputs
+    pallas_stats.note_chunk(chunk * row_tile)
+    pallas = _kernel_reason(
+        jax.ShapeDtypeStruct((chunk * row_tile,), x.dtype), w_gate,
+        row_tile) is None
+
+    def body(c, carried):
+        dx, dweights, dw_gate, dw_up, dw_down = carried
+        with jax.named_scope("rows_%d" % (chunk * row_tile)):
+            rows, continues = _chunk_of(plan, c, chunk, row_tile)
+            (token, share, out), (picked, gate_out, up, hidden) = (
+                _rows_forward(x, weights, w_gate, w_up, w_down, rows, top_k,
+                              row_tile))
+
+            def pulled_back(lhs, rhs, dy, into):
+                return _pulled_back(lhs, rhs, dy, rows.tile_expert,
+                                    rows.n_used, row_tile, pallas, into,
+                                    continues)
+            dy_rows = dy[token]
+            dweights = dweights.at[rows.row_pair].add(jnp.where(
+                rows.row_valid, jnp.sum(dy_rows * out, axis=-1), 0.0))
+            dhidden, dw_down = pulled_back(
+                hidden, w_down, dy_rows * share[:, None], dw_down)
+            dgate_out, dup = jax.vjp(_gated, gate_out, up)[1](dhidden)
+            by_gate, dw_gate = pulled_back(picked, w_gate, dgate_out, dw_gate)
+            by_up, dw_up = pulled_back(picked, w_up, dup, dw_up)
+            dx = _added_in_pieces(
+                token, by_gate.astype(F32) + by_up.astype(F32), rows.n_used,
+                row_tile, dx)
+        return dx, dweights, dw_gate, dw_up, dw_down
+    # every held expert has a tile in use, and its first tile starts its
+    # block: the kernels' gradients need no zeros to start from
+    start = (lambda w: lax.empty(w.shape, w.dtype)) if pallas else (
+        jnp.zeros_like)
+    dx, dweights, *dw = lax.fori_loop(
+        0, _trips(plan.n_used, chunk), body,
+        (jnp.zeros(x.shape, F32), jnp.zeros(weights.size, weights.dtype),
+         start(w_gate), start(w_up), start(w_down)))
+    return (dx.astype(x.dtype), dweights.reshape(weights.shape), *dw)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def _routed(x, weights, w_gate, w_up, w_down, plan, ladder, top_k, row_tile,
+def _routed(x, weights, w_gate, w_up, w_down, plan, chunk, top_k, row_tile,
             gate):
-    """`_routed_rows` over a prefix from `ladder` (rows, ascending)."""
-    return _over_ladder(_routed_rows, ladder, plan, top_k, row_tile, gate,
-                        x, weights, w_gate, w_up, w_down, plan)
+    """`_routed_chunks`, with a backward loop of its own."""
+    return _routed_chunks(x, weights, w_gate, w_up, w_down, plan, chunk=chunk,
+                          top_k=top_k, row_tile=row_tile, gate=gate)
 
 
 def _routed_fwd(x, weights, w_gate, w_up, w_down, plan, *static):
@@ -452,21 +593,10 @@ def _routed_fwd(x, weights, w_gate, w_up, w_down, plan, *static):
     return _routed(*inputs, plan, *static), (inputs, plan)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "top_k", "row_tile",
-                                             "gate"))
-def _routed_rows_vjp(inputs, plan, dy, **static):
-    """The cotangents of `_routed_rows`'s five inputs over one rung: that
-    rung's forward made again, and `dy` pulled back through it. Jitted here
-    for the same reason: one trace of each rung's backward a model."""
-    _, vjp = jax.vjp(lambda *inputs: _routed_rows(*inputs, plan, **static),
-                     *inputs)
-    return vjp(dy)
-
-
-def _routed_bwd(ladder, top_k, row_tile, gate, res, dy):
+def _routed_bwd(chunk, top_k, row_tile, gate, res, dy):
     inputs, plan = res
-    return _over_ladder(_routed_rows_vjp, ladder, plan, top_k, row_tile, gate,
-                        inputs, plan, dy) + (None,)
+    return _routed_chunks_vjp(inputs, plan, dy, chunk=chunk, top_k=top_k,
+                              row_tile=row_tile, gate=gate) + (None,)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -482,17 +612,15 @@ def moe_routed(x, w_router, w_gate, w_up, w_down, top_k, first_expert=0,
     w_gate, w_up (E, d, f) and w_down (E, f, d) of the E held; `score` and
     `scale` as `route_top_k` takes them. NaN throughout where the routing
     put more than `rows_bound` pairs here."""
-    n_held = w_gate.shape[0]
     weights, ids = route_top_k(x, w_router, top_k, score, scale)
-    plan = plan_dispatch(ids, n_held, first_expert, rows_bound, row_tile)
-    ladder = tuple(tiles * row_tile for tiles in prefix_ladder(
-        x.shape[0], top_k, n_held, w_router.shape[1],
-        plan.tile_expert.shape[0], row_tile))
+    plan = plan_dispatch(ids, w_gate.shape[0], first_expert, rows_bound,
+                         row_tile)
     gate = (pallas_stats.pallas_on(), pallas_stats.interpret())
-    args = (x, weights, w_gate, w_up, w_down, plan)
-    if len(ladder) == 1:    # too short to halve: plain autodiff, no recompute
-        combined = _routed_rows(*args, rows=ladder[0], top_k=top_k,
-                                row_tile=row_tile, gate=gate)
+    args = (x, weights, w_gate, w_up, w_down)
+    if plan.tile_expert.shape[0] <= CHUNK_TILES:    # plain autodiff, no loop
+        combined = _routed_rows(*args, plan, top_k=top_k, row_tile=row_tile,
+                                gate=gate)
     else:
-        combined = _routed(*args, ladder, top_k, row_tile, gate)
+        combined = _routed(*args, _whole_chunks(plan, CHUNK_TILES, row_tile),
+                           CHUNK_TILES, top_k, row_tile, gate)
     return jnp.where(plan.n_dropped > 0, jnp.nan, combined).astype(x.dtype)

@@ -27,7 +27,7 @@ import time
 
 import jax
 
-__all__ = ["note_dispatch", "note_fallback", "note_rung", "kernel_span",
+__all__ = ["note_dispatch", "note_fallback", "note_chunk", "kernel_span",
            "compiler_params", "use_pallas", "interpret", "pallas_on"]
 
 
@@ -84,12 +84,12 @@ def note_fallback(kernel, reason):
         _telem.inc("ops.pallas.fallback.%s.%s" % (kernel, reason))
 
 
-def note_rung(rows):
-    """Count one traced body of `ops/moe.py`'s ladder: the expert layer
-    over the first `rows` rows of its buffer (`ops.moe.ladder.<rows>`)."""
+def note_chunk(rows):
+    """Count one traced body of `ops/moe.py`'s loop over its buffer: the
+    expert layer over a chunk of `rows` rows (`ops.moe.chunk.<rows>`)."""
     from .. import telemetry as _telem
     if _telem.ENABLED:
-        _telem.inc("ops.moe.ladder.%d" % rows)
+        _telem.inc("ops.moe.chunk.%d" % rows)
 
 
 @contextlib.contextmanager

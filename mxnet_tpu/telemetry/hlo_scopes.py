@@ -15,7 +15,7 @@ and the scopes it lies under. `telemetry.note_step_program` keeps the map
 of the step that a profiler session saw (`telemetry.module_scopes()`), and
 `benchmark/metrics/scope_ms_per_step.py` joins it with the trace's
 per-instruction device time. `tools/moe_rungs.py` files a window's
-operations under the branch computations that `parse` gives.
+operations under the loop bodies that `parse` gives.
 (`tools/step_bytes.py` keeps a reading of its own, of the entry computation
 alone: it counts bytes from result types and operands, which `Instr` does
 not keep.)
@@ -78,6 +78,46 @@ def parse(text):
                 calls += [b.strip().lstrip("%")
                           for b in branches.group(1).split(",") if b.strip()]
         out[name] = Instr(opcode, op_name, computation, tuple(calls))
+    return _under_their_callers(out)
+
+
+def _under_their_callers(instrs):
+    """`instrs` with every `op_name` that lacks the stack it was called
+    under put behind its caller's. A jitted function that the step lowers
+    once carries the first call site's stack in most of its instructions,
+    but the gathers and scatter-adds in the body of a loop of `ops/moe.py`
+    come out as `while/body/rows_4096/scatter-add`: no `jit(...)`, no pass,
+    no `moe`. The instruction that calls their computation (the fusion,
+    then the `while`) knows where it lies, so a name that does not start
+    with `jit(` goes behind the nearest caller's that does, overlapped
+    where the one ends as the other begins (`.../while` and `while/body/
+    ...`), else in place of the caller's last part, the primitive."""
+    caller = {}
+    for name, instr in instrs.items():
+        for callee in instr.calls:
+            caller.setdefault(callee, name)
+
+    def outer(name):
+        """The nearest caller's `op_name` that starts with `jit(`."""
+        seen = set()
+        while name in instrs and name not in seen:
+            seen.add(name)
+            name = caller.get(instrs[name].computation)
+            if name is not None and instrs[name].op_name.startswith("jit("):
+                return instrs[name].op_name
+        return None
+
+    out = {}
+    for name, instr in instrs.items():
+        if instr.op_name and not instr.op_name.startswith("jit("):
+            above = outer(name)
+            if above is not None:
+                a, b = above.split("/"), instr.op_name.split("/")
+                n = next((n for n in range(min(len(a), len(b)), 0, -1)
+                          if a[-n:] == b[:n]), None)
+                instr = instr._replace(op_name="/".join(
+                    a + b[n:] if n else a[:-1] + b))
+        out[name] = instr
     return out
 
 
@@ -105,19 +145,18 @@ def path(op_name):
     - A `custom_vjp`'s backward keeps the scope it was called under, behind
       `transpose(jvp(...))`: it is `backward` under that scope.
     - What a `custom_vjp`'s backward makes again itself (`ops/moe.py`'s
-      `_routed_rows_vjp` rebuilds its rung's forward) is `backward`:
+      `_routed_chunks_vjp` rebuilds every chunk's forward) is `backward`:
       `recomputed` counts `jax.checkpoint`'s work alone.
     - A jitted function that the step lowers once and calls from several
-      places: where XLA inlines the calls (`ops/linear_attention.py`'s
-      `_inverse_pallas`, the flash entries: every call on a v5e), each
-      call site's instructions carry that site's whole stack
-      (`.../gdn/delta_chunk/jit(_inverse_pallas)/gdn_inverse/pallas_call`,
-      three times). Where it cannot (a rung's body under a `conditional`
-      in `ops/moe.py`), a few instructions of the body keep the inner
-      jit's own stack, `transpose(jvp(jit(_routed_rows)))/rows_43008/
-      scatter-add`: they keep `rows_<R>` and the direction and lose the
-      caller's scopes (0.4 ms a step of `qwen3_next_ep16_s4096`'s 70 under
-      `rows_*`, PERF.md).
+      places carries the first call site's stack: where XLA inlines the
+      calls (`ops/linear_attention.py`'s `_inverse_pallas`, the flash
+      entries: every call on a v5e), each call site's instructions carry
+      that site's whole stack (`.../gdn/delta_chunk/jit(_inverse_pallas)/
+      gdn_inverse/pallas_call`, three times). The gathers and scatter-adds
+      in the body of `ops/moe.py`'s loops come out with the body's own
+      stack alone (`while/body/rows_4096/scatter-add`); `parse` has put
+      them behind the `while` that calls them, so they keep their pass and
+      `moe` here.
     """
     parts = [p for p in op_name.split("/") if p]
     backward = any(p.startswith("transpose(") for p in parts)

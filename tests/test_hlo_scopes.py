@@ -116,6 +116,58 @@ def test_path_reads_an_op_name_as_pass_and_scopes(op_name, expected):
     assert expected[0] in hlo_scopes.PASSES
 
 
+RELATIVE = """HloModule jit_step_fn
+
+%fused_scatter (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  ROOT %add.1 = f32[8]{0} add(%p0, %p0), metadata={op_name="while/body/rows_4096/scatter-add"}
+}
+
+%body (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %get.1 = f32[8]{0} get-tuple-element(%arg), index=1
+  %fusion.7 = f32[8]{0} fusion(%get.1), kind=kLoop, calls=%fused_scatter, metadata={op_name="while/body/rows_4096/scatter-add"}
+  %fusion.8 = f32[8]{0} fusion(%fusion.7), kind=kLoop, calls=%fused_scatter, metadata={op_name="jit(step_fn)/jvp(forward)/moe/jit(_routed_chunks)/while/body/rows_4096/mul"}
+  %fusion.9 = f32[8]{0} fusion(%fusion.8), kind=kLoop, calls=%fused_scatter, metadata={op_name="transpose(jvp(jit(_rows)))/rows_4096/gather"}
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%get.1, %fusion.9)
+}
+
+%cond (arg.1: (s32[], f32[8])) -> pred[] {
+  %arg.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %zero = s32[] constant(0)
+  %tuple.2 = (s32[], f32[8]{0}) tuple(%zero, %x)
+  %while.3 = (s32[], f32[8]{0}) while(%tuple.2), condition=%cond, body=%body, metadata={op_name="jit(step_fn)/jvp(forward)/moe/jit(_routed_chunks)/while"}
+  ROOT %get.2 = f32[8]{0} get-tuple-element(%while.3), index=1
+}
+"""
+
+
+def test_a_name_without_its_callers_stack_goes_behind_the_callers():
+    """An `op_name` that lacks the stack it was called under (the gathers
+    and scatter-adds in the body of `ops/moe.py`'s loops on a v5e) is read
+    behind the `while` that calls its computation, the fusion's own
+    instructions too, overlapped at `while`; one that shares no part with
+    its caller goes in place of the caller's primitive; a full name and a
+    parameter's stay."""
+    parsed = hlo_scopes.parse(RELATIVE)
+    full = "jit(step_fn)/jvp(forward)/moe/jit(_routed_chunks)/while/body/"
+    assert parsed["fusion.7"].op_name == full + "rows_4096/scatter-add"
+    assert parsed["add.1"].op_name == full + "rows_4096/scatter-add"
+    assert parsed["fusion.8"].op_name == full + "rows_4096/mul"
+    assert parsed["fusion.9"].op_name == (
+        "jit(step_fn)/jvp(forward)/moe/jit(_routed_chunks)/"
+        "transpose(jvp(jit(_rows)))/rows_4096/gather")
+    assert parsed["x"].op_name == "x" and parsed["get.1"].op_name == ""
+    assert hlo_scopes.path(parsed["fusion.7"].op_name) == (
+        "forward", ("forward", "moe", "while", "body", "rows_4096"))
+    assert hlo_scopes.path(parsed["fusion.9"].op_name)[0] == "backward"
+
+
 def test_the_four_passes_come_out_of_the_toy_step(toy):
     _, parsed = toy
     by_pass = {}

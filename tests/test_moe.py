@@ -1,6 +1,6 @@
 """`ops.moe`: routing, the dispatch without dropped pairs, the grouped
 products `moe_gmm` / `moe_tgmm` in interpret mode against a loop over
-experts, the ladder of buffer prefixes against the layer over the whole
+experts, the loop over the buffer's chunks against the layer over the whole
 buffer, and the shares of a layer adding up to the uncut layer. Float32 on
 the CPU at toy sizes."""
 import os
@@ -140,17 +140,27 @@ def test_the_layer_says_so_where_the_bound_is_passed(rows_bound, over):
     assert bool(jnp.all(jnp.isfinite(out))) != over
 
 
-# ------------------------------------------------------------- the ladder
+# ------------------------------------------------------------- the chunks
 # 256 tokens, two experts each of 128, the first two held, tiles of 8 rows: a
-# buffer of 64 + 2 tiles, of which uniform routing wants 1 + 2
-LADDER_SHAPE = dict(T=256, d=32, f=16, E=2, n_experts=128, top_k=2, row_tile=8)
-LADDER = (17, 33, 66)
+# buffer of 64 + 2 tiles, walked in chunks of 24 (so padded to 72: three)
+CHUNKED_SHAPE = dict(T=256, d=32, f=16, E=2, n_experts=128, top_k=2,
+                     row_tile=8)
+CHUNK = 24
+
+
+@pytest.fixture
+def chunks_of_24(monkeypatch):
+    """Three chunks of the buffer's 66 tiles, the last a quarter padding and
+    reached where both experts are full; a chunk's scatter-adds go in up to
+    three pieces."""
+    monkeypatch.setattr(moe, "CHUNK_TILES", CHUNK)
 
 
 def _full_length(x, router, w_gate, w_up, w_down, top_k, rows_bound,
                  row_tile):
     """The layer over its whole buffer, whatever the routing filled: what
-    `moe_routed` was before the ladder, kept here as the reference."""
+    `moe_routed` was before it followed the routing, kept here as the
+    reference."""
     weights, ids = moe.route_top_k(x, router, top_k)
     plan = moe.plan_dispatch(ids, w_gate.shape[0], 0, rows_bound, row_tile)
     token = plan.row_pair // top_k
@@ -173,52 +183,58 @@ def _routed_to(on_first, on_second):
     choose held expert 0 and the first `on_second` held expert 1; a token's
     other choices are experts that are not held. The router copies the
     first d coordinates, and a token's two choices stand out in them."""
-    T, d, E = (LADDER_SHAPE[k] for k in ("T", "d", "E"))
+    T, d, E = (CHUNKED_SHAPE[k] for k in ("T", "d", "E"))
     x = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (T, d))
     t = onp.arange(T)
     first = onp.where(t < on_first, 0, E + t % (d - E))
     second = onp.where(t < on_second, 1, E + (t + 1) % (d - E))
     x = x.at[t, first].add(6.0).at[t, second].add(5.0)
-    return x, jnp.eye(d, LADDER_SHAPE["n_experts"])
+    return x, jnp.eye(d, CHUNKED_SHAPE["n_experts"])
 
 
-def _ladder_case(name):
+def _chunked_case(name):
     if name == "uniform":
-        shape = LADDER_SHAPE
+        shape = CHUNKED_SHAPE
         x = jax.random.normal(jax.random.PRNGKey(5), (shape["T"], shape["d"]))
         return x, jax.random.normal(jax.random.PRNGKey(6),
                                     (shape["d"], shape["n_experts"]))
     # tiles: expert 0's rows over 8, and expert 1's or its one empty tile
-    return _routed_to(*{"exactly_17": (16 * 8, 0), "17_and_one": (129, 0),
-                        "exactly_33": (256, 0), "33_and_one": (256, 9),
+    return _routed_to(*{"exactly_one": (23 * 8, 0), "one_and_a_tile": (185, 0),
+                        "two_in_part": (256, 9), "exactly_two": (256, 128),
                         "all_held": (256, 256)}[name])
 
 
+def _weights(key, E, d, f):
+    ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    return [0.3 * jax.random.normal(k, s) for k, s in zip(
+        ks, [(E, d, f), (E, d, f), (E, f, d)])]
+
+
 @pytest.mark.parametrize("interpret", ["1", "0"])
-@pytest.mark.parametrize("routing,n_used,rung", [
-    ("uniform", None, 17), ("exactly_17", 17, 17), ("17_and_one", 18, 33),
-    ("exactly_33", 33, 33), ("33_and_one", 34, 66), ("all_held", 64, 66)])
-def test_every_rung_is_the_layer_over_the_whole_buffer(
-        monkeypatch, routing, n_used, rung, interpret):
+@pytest.mark.parametrize("routing,n_used,trips", [
+    ("uniform", None, 1), ("exactly_one", 24, 1), ("one_and_a_tile", 25, 2),
+    ("two_in_part", 34, 2), ("exactly_two", 48, 2), ("all_held", 64, 3)])
+def test_the_loop_over_chunks_is_the_layer_over_the_whole_buffer(
+        monkeypatch, chunks_of_24, routing, n_used, trips, interpret):
     """Value and gradients to x, the router and the three weight tensors,
-    over the prefix that the routing picks, against the one computation
-    over all 66 tiles: through the kernels (interpreted) and through the
-    loop over experts in XLA."""
+    over as many chunks as the routing fills (less than one, exactly one,
+    one tile more, two in part and in full with expert 0's rows on both
+    sides of an edge, and all three with the last one reaching into the
+    padding),
+    against the one computation over all 66 tiles: through the kernels
+    (interpreted) and through the loop over experts in XLA."""
     monkeypatch.setenv("MXNET_FLASH_INTERPRET", interpret)
-    shape = LADDER_SHAPE
+    shape = CHUNKED_SHAPE
     E, d, f, top_k, row_tile = (shape[k] for k in
                                 ("E", "d", "f", "top_k", "row_tile"))
-    x, router = _ladder_case(routing)
-    ks = jax.random.split(jax.random.PRNGKey(4), 4)
-    weights = [0.3 * jax.random.normal(k, s) for k, s in zip(
-        ks, [(E, d, f), (E, d, f), (E, f, d)])]
-    target = jax.random.normal(ks[3], x.shape)
+    x, router = _chunked_case(routing)
+    weights = _weights(4, E, d, f)
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
     _, plan = _full_length(x, router, *weights, top_k, None, row_tile)
-    assert moe.prefix_ladder(shape["T"], top_k, E, shape["n_experts"],
-                             plan.tile_expert.shape[0], row_tile) == LADDER
+    assert plan.tile_expert.shape[0] == 66
     used = int(plan.n_used[0])
     assert n_used in (None, used) and int(plan.n_dropped) == 0
-    assert min(r for r in LADDER if r >= used) == rung
+    assert int(moe._trips(plan.n_used, CHUNK)) == trips
 
     def value_and_grads(layer):
         return jax.value_and_grad(
@@ -228,9 +244,8 @@ def test_every_rung_is_the_layer_over_the_whole_buffer(
         lambda *a: moe.moe_routed(*a, top_k, row_tile=row_tile))
     full, full_grads = value_and_grads(
         lambda *a: _full_length(*a, top_k, None, row_tile)[0])
-    # once a trace, and every rung of the ladder is traced with the first
-    assert all(telemetry.counter("ops.moe.ladder.%d" % (r * row_tile)).value
-               for r in LADDER)
+    # once a trace: the forward body and the backward one
+    assert telemetry.counter("ops.moe.chunk.%d" % (CHUNK * row_tile)).value
     # float32 both ways; sums over fewer rows are added up in another order:
     # some hundred terms, 1e-5 of the largest
     onp.testing.assert_allclose(mine, full, rtol=1e-5)
@@ -241,47 +256,101 @@ def test_every_rung_is_the_layer_over_the_whole_buffer(
                                     atol=1e-5 * largest)
 
 
-def test_the_ladder_comes_from_shapes_alone(monkeypatch):
-    """`qwen3_next_ep16_s4096`: 8,192 tokens, ten of 512 experts each, 32
-    held: uniform routing wants 40 + 32 tiles of the buffer's 672, and the
-    half of 336 holds four times that; a quarter would not. A buffer too
-    short to halve has one rung, and the layer then holds no conditional."""
-    assert moe.prefix_ladder(8192, 10, 32, 512, 672) == (336, 672)
-    assert moe.prefix_ladder(40, 1, 2, 4, 7, 8) == (7,)
-    assert moe.prefix_ladder(40, 3, 1, 16, 2) == (2,)
-    assert moe.prefix_ladder(256, 2, 2, 128, 67, 8) == (17, 34, 67)
-    picked = [int(moe._rung_index((136, 272, 544), jnp.array([n]), 8))
-              for n in (1, 17, 18, 34, 35, 68)]
-    assert picked == [0, 0, 1, 1, 2, 2]
+@pytest.mark.parametrize("counts,chunk", [((5, 17, 0, 8, 0), 2),
+                                          ((40, 3, 9), 3), ((1, 1, 30), 4)])
+def test_weight_gradients_are_summed_across_a_chunks_edge(counts, chunk):
+    """`moe_tgmm` chunk by chunk into the gradient it is handed against one
+    call over the whole buffer: an expert whose tiles lie on both sides of
+    a chunk's edge (the 17 rows over tiles 1-3 with an edge behind tile 1,
+    the 40 over five tiles and two edges, the 30 behind two lone tiles)
+    adds to the block its earlier tiles started; the experts a chunk does
+    not visit keep theirs; and every block is written, whatever the array
+    held before (it starts as NaN here, as unwritten memory may)."""
+    row_tile, K, N, E = 8, 24, 16, len(counts)
+    plan = moe._whole_chunks(_plan(counts, row_tile), chunk, row_tile)
+    R = plan.row_pair.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    lhs = jax.random.normal(ks[0], (R, K))
+    rhs = jax.random.normal(ks[1], (E, K, N))
+    dy = jax.random.normal(ks[2], (R, N))
+    _, whole = moe._pulled_back(lhs, rhs, dy, plan.tile_expert, plan.n_used,
+                                row_tile, True)
+    trips = int(moe._trips(plan.n_used, chunk))
+    edges = [c * chunk for c in range(1, trips)]
+    assert any(plan.tile_expert[e] == plan.tile_expert[e - 1] for e in edges)
+    summed = jnp.full(rhs.shape, jnp.nan)
+    for c in range(trips):
+        rows, continues = moe._chunk_of(plan, c, chunk, row_tile)
+        at = slice(c * chunk * row_tile, (c + 1) * chunk * row_tile)
+        _, summed = moe._pulled_back(
+            lhs[at], rhs, dy[at], rows.tile_expert, rows.n_used, row_tile,
+            True, summed, continues)
+    # one expert's rows summed in another order: 1e-5 of numbers of size 10
+    onp.testing.assert_allclose(summed, whole, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("chunk", [1, 24, 32])
+def test_the_trip_count_is_the_chunks_that_hold_the_tiles_in_use(chunk):
+    """`_trips`, which both loops take their bound from: ceil(n_used /
+    chunk) at every edge, and a chunk's own count of tiles in use adds up
+    to `n_used` over those trips and is never 0 in one of them."""
+    for n_used in (1, chunk - 1, chunk, chunk + 1, 2 * chunk, 2 * chunk + 1,
+                   5 * chunk - 1, 5 * chunk):
+        if n_used < 1:
+            continue
+        trips = int(moe._trips(jnp.array([n_used]), chunk))
+        assert trips == -(-n_used // chunk)
+        plan = moe.Dispatch(jnp.zeros(5 * chunk * 8, jnp.int32),
+                            jnp.zeros(5 * chunk * 8, bool),
+                            jnp.zeros(5 * chunk, jnp.int32),
+                            jnp.array([n_used]), jnp.int32(0))
+        inside = [int(moe._chunk_of(plan, c, chunk, 8)[0].n_used[0])
+                  for c in range(trips)]
+        assert sum(inside) == n_used and min(inside) >= 1
+
+
+def test_a_buffer_of_one_chunk_has_no_loop(monkeypatch, chunks_of_24):
+    """A buffer no longer than a chunk (every toy shape of the models'
+    tests, the benchmark's rehearsal) is the layer over all of it under
+    plain autodiff: no `while` but the dispatch's own (`searchsorted`) and
+    no `cond`; a longer one has a loop in each direction, still no `cond`,
+    and is padded to whole chunks."""
     monkeypatch.setenv("MXNET_FLASH_INTERPRET", "0")    # no kernel's `cond`
 
-    def conds(T, n_experts):
+    def loops_and_conds(T, n_experts):
         args = (jnp.zeros((T, 16)), jnp.zeros((16, n_experts)),
                 jnp.zeros((2, 16, 8)), jnp.zeros((2, 16, 8)),
                 jnp.zeros((2, 8, 16)))
         text = str(jax.make_jaxpr(jax.grad(lambda *a: moe.moe_routed(
             *a, top_k=1, row_tile=8).sum(), argnums=(0, 2)))(*args))
-        return text.count(" cond[")
-    assert conds(40, 4) == 0        # 5 + 2 tiles, 3 + 2 expected: one rung
-    assert conds(256, 32) == 2      # 32 + 2 tiles: forward and backward
+        dispatch = str(jax.make_jaxpr(lambda ids: moe.plan_dispatch(
+            ids, 2, row_tile=8))(jnp.zeros((T, 1), jnp.int32)))
+        return (text.count(" while[") - dispatch.count(" while["),
+                text.count(" cond["))
+    assert loops_and_conds(40, 4) == (0, 0)         # 5 + 2 tiles
+    assert loops_and_conds(176, 4) == (0, 0)        # 22 + 2: one chunk
+    # 23 + 2: two chunks, and in each direction the loop over them and the
+    # loop over the pieces of its scatter-add
+    assert loops_and_conds(184, 4) == (4, 0)
+    plan = moe._whole_chunks(_plan((150, 38), 8), CHUNK, 8)
+    assert plan.tile_expert.shape[0] == 48
+    assert plan.row_pair.shape == plan.row_valid.shape == (48 * 8,)
+    assert not onp.any(onp.asarray(plan.row_valid)[26 * 8:])
 
 
-@pytest.mark.parametrize("routing,over", [("exactly_17", False),
+@pytest.mark.parametrize("routing,over", [("exactly_one", False),
                                           ("all_held", True)])
-def test_a_passed_bound_is_nan_on_a_laddered_buffer(routing, over):
-    """A bound of 256 pairs under the ladder's shapes: 32 + 2 tiles and a
-    rung of 17. 128 pairs on held expert 0 fit that rung; 512 on the two
-    pass the bound, the buffer is then full (so the top rung runs) and the
-    layer is NaN throughout."""
-    shape = LADDER_SHAPE
-    E, d, f = shape["E"], shape["d"], shape["f"]
-    ks = jax.random.split(jax.random.PRNGKey(8), 3)
-    weights = [0.3 * jax.random.normal(k, s) for k, s in zip(
-        ks, [(E, d, f), (E, d, f), (E, f, d)])]
-    assert moe.prefix_ladder(256, 2, E, 128, 32 + E, 8) == (17, 34)
-    x, router = _ladder_case(routing)
-    out = moe.moe_routed(x, router, *weights, top_k=2, rows_bound=256,
-                         row_tile=8)
+def test_a_passed_bound_is_nan_on_a_chunked_buffer(chunks_of_24, routing,
+                                                   over):
+    """A bound of 256 pairs under the chunked shapes: 32 + 2 tiles, two
+    chunks of 24. 184 pairs on held expert 0 fit the first chunk; 512 on the
+    two pass the bound, the buffer is then full (so the loop reaches its
+    end) and the layer is NaN throughout."""
+    shape = CHUNKED_SHAPE
+    x, router = _chunked_case(routing)
+    out = moe.moe_routed(
+        x, router, *_weights(8, shape["E"], shape["d"], shape["f"]), top_k=2,
+        rows_bound=256, row_tile=8)
     assert bool(jnp.all(jnp.isnan(out))) == over
     assert bool(jnp.all(jnp.isfinite(out))) != over
 
@@ -387,12 +456,12 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     onp.testing.assert_allclose(total, whole, rtol=1e-5, atol=2e-5)
 
 
-def test_the_rungs_that_ran_are_read_from_a_module_and_its_events(
-        monkeypatch):
-    """`tools/moe_rungs.py` on the ladder's toy layer compiled here: both
-    conditionals found, forward and backward, every rung's instructions
-    filed under it by their `rows_<R>` scope; and from events of those
-    names, how often each rung ran and for how long."""
+def test_the_trips_a_loop_made_are_read_from_a_module_and_its_events(
+        monkeypatch, chunks_of_24):
+    """`tools/moe_rungs.py` on the chunked toy layer compiled here: both
+    loops found, forward and backward, every instruction of a body filed
+    under its loop by the `rows_<R>` scope; and from events of those names
+    in three steps, how many trips each step made and how long one took."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "moe_rungs", os.path.join(os.path.dirname(os.path.dirname(
@@ -400,27 +469,37 @@ def test_the_rungs_that_ran_are_read_from_a_module_and_its_events(
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
     monkeypatch.setenv("MXNET_FLASH_INTERPRET", "0")
-    shape = LADDER_SHAPE
+    shape = CHUNKED_SHAPE
     E, d, f = shape["E"], shape["d"], shape["f"]
-    x, router = _ladder_case("uniform")
+    x, router = _chunked_case("uniform")
     weights = [jnp.ones(s) for s in [(E, d, f), (E, d, f), (E, f, d)]]
-    text = jax.jit(jax.value_and_grad(lambda *a: moe.moe_routed(
-        *a, top_k=2, row_tile=8).sum(), argnums=(0, 2))).lower(
-            x, router, *weights).compile().as_text()
-    filed, direction = tool.rung_of_instruction(tool.hlo_scopes.parse(text))
-    assert sorted(direction.values()) == ["backward", "forward"]
-    assert {rows for _, rows in filed.values()} == {136, 264, 528}
-    forward = next(c for c, way in direction.items() if way == "forward")
-    names = {rows: [n for n, (c, r) in filed.items()
-                    if c == forward and r == rows] for rows in (136, 264)}
-    # three steps: the low rung twice, then the middle one; 2 and 5 ns an
-    # instruction
-    events = [(t, 2, n) for t in (0, 100) for n in names[136]]
-    events += [(200, 5, n) for n in names[264]]
-    report, rungs = tool.by_rung(events, filed, direction, 3, 8)
-    assert [r["conditional"] for r in report] == [forward]
-    assert report[0]["in_order"] == "17x2 33x1"
-    assert report[0]["runs_a_step"] == {"17": 2 / 3, "33": 1 / 3}
-    assert rungs["17"]["ms_a_step"] == pytest.approx(
-        2 * 2 * len(names[136]) * 1e-6 / 3)
-    assert rungs["33"]["runs_a_step"] == pytest.approx(1 / 3)
+
+    def forward(*a):
+        with jax.named_scope("forward"):
+            return moe.moe_routed(*a, top_k=2, row_tile=8).sum()
+    text = jax.jit(jax.value_and_grad(forward, argnums=(0, 2))).lower(
+        x, router, *weights).compile().as_text()
+    filed, loops, marks = tool.loop_of_instruction(
+        tool.hlo_scopes.parse(text))
+    assert sorted(loops.values()) == [("backward", 192), ("forward", 192)]
+    forward = next(l for l, (way, _) in loops.items() if way == "forward")
+    body = [n for n, l in filed.items() if l == forward]
+    # the XLA loop over experts: no kernel marks a trip, so all of it does
+    assert len(body) > 3 and marks[forward] == set(body)
+    # three steps that start at 0, 1000 and 2000 ns: two trips, two, three;
+    # 2 ns an instruction, and an instruction of no loop in between
+    events = [(step * 1000 + trip * 100 + i, 2, n)
+              for step, trips in enumerate((2, 2, 3)) for trip in range(trips)
+              for i, n in enumerate(body)] + [(1500, 7, "fusion.outside")]
+    report = tool.by_loop(events, [0, 1000, 2000], filed, loops, marks)
+    assert [r["loop"] for r in report] == [forward]
+    assert report[0]["pass"] == "forward" and report[0]["rows"] == 192
+    assert report[0]["in_order"] == "2x2 3x1"
+    assert report[0]["trips_a_step"] == pytest.approx(7 / 3)
+    assert report[0]["ms_a_trip"] == pytest.approx(2e-6 * len(body))
+    assert report[0]["ms_a_step"] == pytest.approx(7 * 2e-6 * len(body) / 3)
+    # the body's longest instructions, ms a trip each: 2 ns every one here
+    assert set(report[0]["body"]) <= set(body)
+    assert len(report[0]["body"]) == min(len(body), tool.LONGEST)
+    assert list(report[0]["body"].values()) == [pytest.approx(2e-6)] * len(
+        report[0]["body"])

@@ -285,13 +285,15 @@ def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
     """One expert layer at `qwen3_next_ep16_s4096`'s shapes (8,192 tokens of
     2,048, ten of 512 experts each, 32 held of width 512: a buffer of 86,016
     rows), value and gradient under `jax.checkpoint` as the decoder calls
-    it: TWO conditionals (the forward and the backward; the recomputed
-    forward is dead), a branch for each rung of (336, 672) tiles, every
-    branch with the kernels under their names (3 `moe_gmm` forward; 6 and 3
-    `moe_tgmm` backward, which makes its own forward again), and the
-    compiler's account of the temporaries not above that of the layer over
-    the whole buffer alone: the branches keep nothing for one another."""
+    it: TWO `while` loops that hold kernels (the forward and the backward;
+    the recomputed forward is dead) and no conditional, each body over one
+    chunk of the buffer with the kernels under their names (3 `moe_gmm`
+    forward; 6 and 3 `moe_tgmm` backward, which makes its own forward again
+    and writes the weight gradients into the buffers the loop carries: no
+    copy of one in the body), and the compiler's account of the
+    temporaries not above that of the layer over the whole buffer alone."""
     from mxnet_tpu.ops import moe, pallas_stats
+    from mxnet_tpu.telemetry import hlo_scopes
     monkeypatch.setattr(pallas_stats, "pallas_on", lambda: True)
     T, d, f, held, n_experts, top_k = 8192, 2048, 512, 32, 512, 10
 
@@ -306,8 +308,7 @@ def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
         weights, ids = moe.route_top_k(x, router, top_k)
         plan = moe.plan_dispatch(ids, held)
         return moe._routed_rows(x, weights, w_gate, w_up, w_down, plan,
-                                rows=plan.row_pair.shape[0], top_k=top_k,
-                                row_tile=moe.ROW_TILE, gate=None
+                                top_k=top_k, row_tile=moe.ROW_TILE, gate=None
                                 ).astype(x.dtype)
 
     def compiled(layer):
@@ -316,27 +317,32 @@ def test_mixture_layer_picks_a_prefix_of_its_buffer_on_the_device(
             lambda *a: jnp.sum((layer(*a[:5]) * a[5]).astype(jnp.float32)),
             argnums=(0, 1, 2, 3, 4)), *args)
 
-    laddered = compiled(lambda *a: moe.moe_routed(*a, top_k))
-    text = laddered.as_text()
-    branches = re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}",
-                          text)
-    assert len(branches) == 2, branches
+    chunked = compiled(lambda *a: moe.moe_routed(*a, top_k))
+    text = chunked.as_text()
+    assert " conditional(" not in text
+    instrs = hlo_scopes.parse(text)
+    held_by = {}
+    for name, instr in instrs.items():
+        held_by.setdefault(instr.computation, []).append(name)
     calls = []
-    for conditional in branches:
-        names = [n.strip() for n in conditional.split(",")]
-        assert len(names) == 2, names
-        for name in names:
-            body = text[text.index("\n%s (" % name):]
-            body = body[:body.index("\n}\n")]
-            calls.append(sorted(re.findall(
-                r"%(moe_t?gmm)[.\d]* = \S+ custom-call\(", body)))
+    for instr in instrs.values():
+        if instr.opcode != "while":
+            continue
+        body = [name for computation in instr.calls
+                for name in held_by[computation]]
+        kernels = sorted(re.match(r"moe_t?gmm", name).group() for name in body
+                         if instrs[name].opcode == "custom-call"
+                         and name.startswith("moe_"))
+        if kernels:
+            calls.append(kernels)
+            assert not any(instrs[name].opcode == "copy" and re.search(
+                r"%%%s = f32\[%d," % (re.escape(name), held), text)
+                for name in body)
     forward, backward = ["moe_gmm"] * 3, ["moe_gmm"] * 6 + ["moe_tgmm"] * 3
-    assert sorted(calls) == [forward] * 2 + [backward] * 2, calls
-    for rows in (43008, 86016):
-        assert "rows_%d/" % rows in text
+    assert sorted(calls) == [forward, backward], calls
+    assert "rows_%d/" % (moe.CHUNK_TILES * moe.ROW_TILE) in text
     alone = compiled(whole_buffer)
-    assert " conditional(" not in alone.as_text()
-    assert (laddered.memory_analysis().temp_size_in_bytes
+    assert (chunked.memory_analysis().temp_size_in_bytes
             <= alone.memory_analysis().temp_size_in_bytes)
 
 
@@ -414,14 +420,14 @@ def _digest(text):
 
 # what PR 34's tree gives (`git checkout c4da908`, this test copied over):
 # a PR that means to change BERT's or Qwen3-Next's step replaces the pair it
-# changes and says so; one that does not has moved a step it shares. PR 36
-# put the delta rule's chunk inverse into the kernels `gdn_inverse` and
-# `gdn_inverse_bwd`: Qwen3-Next's pair is that tree's, BERT's stay PR 34's
+# changes and says so; one that does not has moved a step it shares. PR 38
+# made the expert layer a loop over its buffer's chunks (`ops/moe.py`):
+# Qwen3-Next's pair is that tree's, BERT's stay PR 34's
 PARENT_STEPS = {
     ("bert_base", (128, 128)): ("191c8e56347535ff", "888006aa458dc3be"),
     ("bert_base", (32, 512)): ("ee54c9a0df876a52", "edf2a4a9d0901870"),
-    ("qwen3_next_80b_a3b_ep16", (2, 4096)): ("46f5b904029e80d0",
-                                             "cf6b0e2f01516336"),
+    ("qwen3_next_80b_a3b_ep16", (2, 4096)): ("a21bd5bd56405b7b",
+                                             "772e4c0967e40895"),
 }
 
 

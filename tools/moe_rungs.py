@@ -1,31 +1,38 @@
 #!/usr/bin/env python3
-"""Which rung of the expert layer's ladder ran, layer by layer, read on the
-chip from one traced window joined with the module the chip compiled.
+"""How many chunks of its dispatch buffer the expert layer walked, loop by
+loop, read on the chip from one traced window joined with the module the
+chip compiled.
 
     chiprun -- python tools/moe_rungs.py --workload qwen3_next_ep16_s4096
 
-`ops/moe.py` picks on the device, from what the routing filled, the prefix
-of its dispatch buffer that it works over; no value leaves the step to say
-which. But every instruction of a rung's body carries `rows_<R>` in its
-`op_name` (the body's `jax.named_scope`), each `lax.switch` is one
-`conditional` of the compiled module with a branch a rung, and the device
-trace names every instruction that ran. So: build the cell's own runner
-(its weights and batch from the seed), take its first three steps, trace a
-short window, take the step's compiled module as the step itself read it
-under the profiler's session (`mxnet_tpu.telemetry.module_scopes()`), and
-file each traced operation under the (conditional, rung) whose branch
-computation holds it. A container inside a branch (a `while`, a `call`) is
-filed nowhere: its own event covers operations that are filed already.
+`ops/moe.py` walks its dispatch buffer in chunks, for as many chunks as the
+routing filled, in one `lax.fori_loop` a direction whose bound is read on
+the device; no value leaves the step to say how many. But every instruction
+of a chunk's body carries `rows_<R>` in its `op_name` (the body's
+`jax.named_scope`), each loop is one `while` of the compiled module, and the
+device trace names every instruction that ran, once a trip. So: build the
+cell's own runner (its weights and batch from the seed), take its first
+three steps, trace a short window, take the step's compiled module as the
+step itself read it under the profiler's session
+(`mxnet_tpu.telemetry.module_scopes()`), and file each traced operation
+under the `while` whose body holds it. A container inside a body (a `while`,
+a `call`) is filed nowhere: its own event covers operations that are filed
+already. A loop's trips in a step are the events there of its body's
+kernels (`moe_gmm`, `moe_tgmm`) over the kernels the body holds; of a body
+without kernels (the XLA loop over experts, off the TPU), of all that is
+filed.
 
 Printed, and written to `chiprun_out/moe_rungs/<workload>_<seed>.json`: the
-trace-time counters `ops.moe.ladder.<R>` (a rung that compiled); for every
-conditional in the order it runs in a step (the forward passes of the
-layers, then the backward passes from the last layer down) how many times a
-step each rung ran, the device time a step under each `rows_<R>` scope and
-the rungs in the order the window took them; and the sums by rung. A tool
-for PERF.md's findings; no run of the benchmark calls it.
+trace-time counters `ops.moe.chunk.<R>` (a body that compiled); and for
+every loop in the order it runs in a step (the forward passes of the
+layers, then the backward passes from the last layer down) the trips a
+step, the device time a trip and a step under its `rows_<R>` scope, the
+trips in the order the window's steps took them, and the body's longest
+instructions with their time a trip. A tool for PERF.md's
+findings; no run of the benchmark calls it.
 """
 import argparse
+import bisect
 import collections
 import glob
 import json
@@ -40,14 +47,18 @@ sys.path.insert(0, ROOT)
 from mxnet_tpu.parallel.train_step import STEP_MODULE  # noqa: E402
 from mxnet_tpu.telemetry import hlo_scopes  # noqa: E402
 _SCOPE = re.compile(r"/rows_(\d+)/")
+_KERNEL = re.compile(r"moe_t?gmm(\.\d+)?$")
+LONGEST = 16     # of a body's instructions in the report
 
 
-def rung_of_instruction(instrs):
-    """({instruction: (conditional, rows)}, {conditional: "forward" |
-    "backward"}) of a compiled module as `mxnet_tpu.telemetry.hlo_scopes.
-    parse` gives it: every instruction of a branch computation of a
-    conditional whose branches carry `rows_<R>` scopes, and of what that
-    branch calls, filed under the branch's rung."""
+def loop_of_instruction(instrs):
+    """({instruction: loop}, {loop: (pass, rows)}, {loop: the instructions
+    that mark a trip}) of a compiled module as `mxnet_tpu.telemetry.
+    hlo_scopes.parse` gives it: every instruction of the body of a `while`
+    whose body carries a `rows_<R>` scope, and of what that body calls,
+    filed under the loop; `pass` as `hlo_scopes.path` reads the loop's own
+    `op_name`; a trip's marks are the body's kernels, or all that is filed
+    where it has none."""
     held = collections.defaultdict(list)    # computation -> its instructions
     for name, instr in instrs.items():
         held[instr.computation].append(name)
@@ -60,83 +71,80 @@ def rung_of_instruction(instrs):
             for callee in instrs[name].calls:
                 reach(callee, seen)
 
-    filed, direction = {}, {}
-    for conditional, instr in instrs.items():
-        if instr.opcode != "conditional":
+    inside = {}     # a loop with `rows_<R>` in its body -> what it reaches
+    for loop, instr in instrs.items():
+        if instr.opcode == "while":
+            reached = set()
+            for callee in instr.calls:
+                reach(callee, reached)
+            if any(_SCOPE.search(instrs[name].op_name)
+                   for computation in reached for name in held[computation]):
+                inside[loop] = reached
+    filed, loops, marks = {}, {}, {}
+    for loop, reached in inside.items():
+        # a loop inside a chunk's body (the pieces of its scatter-add) is
+        # the body's, not a loop of the layer
+        if any(instrs[loop].computation in other for other in inside.values()):
             continue
-        by_rows = {}
-        for branch in instr.calls:
-            inside = set()
-            reach(branch, inside)
-            body = [name for computation in inside
-                    for name in held[computation]]
-            rows = collections.Counter(
-                int(r) for name in body
-                for r in _SCOPE.findall(instrs[name].op_name))
-            if rows:
-                by_rows[rows.most_common(1)[0][0]] = body
-        if len(by_rows) < 2:    # an interpreted kernel's `pl.when`
-            continue
-        direction[conditional] = (
-            "backward" if "transpose(" in instr.op_name else "forward")
-        for rung, body in by_rows.items():
-            for name in body:
-                if instrs[name].opcode not in hlo_scopes.CONTAINERS:
-                    filed[name] = (conditional, rung)
-    return filed, direction
+        body = [name for computation in reached for name in held[computation]
+                if instrs[name].opcode not in hlo_scopes.CONTAINERS]
+        rows = collections.Counter(
+            int(r) for name in body
+            for r in _SCOPE.findall(instrs[name].op_name))
+        loops[loop] = (hlo_scopes.path(instrs[loop].op_name)[0],
+                       rows.most_common(1)[0][0])
+        filed.update((name, loop) for name in body)
+        marks[loop] = (frozenset(filter(_KERNEL.match, body))
+                       or frozenset(body))
+    return filed, loops, marks
 
 
-def _runs(rungs):
-    """[84, 84, 168] -> "84x2 168x1"."""
+def _runs(trips):
+    """[2, 2, 3] -> "2x2 3x1"."""
     out = []
-    for rung in rungs:
-        if out and out[-1][0] == rung:
+    for n in trips:
+        if out and out[-1][0] == n:
             out[-1][1] += 1
         else:
-            out.append([rung, 1])
-    return " ".join("%dx%d" % (rung, n) for rung, n in out)
+            out.append([n, 1])
+    return " ".join("%gx%d" % (n, times) for n, times in out)
 
 
-def by_rung(events, filed, direction, steps, row_tile):
+def by_loop(events, step_starts, filed, loops, marks):
     """events [(start_ns, duration_ns, instruction)] of one chip's `XLA
-    Ops` line -> the report's `conditionals` and `rungs`."""
-    per = collections.defaultdict(lambda: collections.defaultdict(
-        lambda: {"ns": 0, "starts": {}}))
+    Ops` line and the starts of the window's steps -> the report's `loops`,
+    in the order a step runs them."""
+    per = collections.defaultdict(lambda: {
+        "ns": 0, "starts": [], "by_name": collections.Counter()})
     for start, duration, name in events:
-        if name not in filed:
+        loop = filed.get(name)
+        if loop is None:
             continue
-        conditional, rows = filed[name]
-        slot = per[conditional][rows]
-        slot["ns"] += duration
-        slot["starts"].setdefault(name, []).append(start)
-    report, totals = [], collections.defaultdict(lambda: [0.0, 0.0])
-    for conditional, rungs in per.items():
-        taken = []
-        for rows, slot in rungs.items():
-            # an instruction of a branch runs once a run of the branch (one
-            # inside a loop of its own more often): the commonest count
-            runs = collections.Counter(
-                len(starts) for starts in slot["starts"].values())
-            starts = next(starts for starts in slot["starts"].values()
-                          if len(starts) == runs.most_common(1)[0][0])
-            taken += [(s, rows) for s in starts]
-            totals[rows][0] += len(starts) / max(steps, 1)
-            totals[rows][1] += slot["ns"] * 1e-6 / max(steps, 1)
-        taken.sort()
+        per[loop]["ns"] += duration
+        per[loop]["by_name"][name] += duration
+        if name in marks[loop]:
+            per[loop]["starts"].append(start)
+    steps = max(len(step_starts), 1)
+    report = []
+    for loop, slot in per.items():
+        in_steps = collections.Counter(
+            bisect.bisect_right(step_starts, start) for start in
+            slot["starts"])
+        trips = [in_steps[step] / len(marks[loop])
+                 for step in range(1, len(step_starts) + 1)]
+        ms = slot["ns"] * 1e-6
         report.append({
-            "conditional": conditional,
-            "pass": direction.get(conditional, ""),
-            "first_ns": taken[0][0],
-            "runs_a_step": {str(rows // row_tile): sum(
-                1 for _, r in taken if r == rows) / max(steps, 1)
-                for rows in sorted(rungs)},
-            "ms_a_step": {str(rows // row_tile): rungs[rows]["ns"] * 1e-6
-                          / max(steps, 1) for rows in sorted(rungs)},
-            "in_order": _runs([rows // row_tile for _, rows in taken])})
+            "loop": loop, "pass": loops[loop][0], "rows": loops[loop][1],
+            "first_ns": min(slot["starts"], default=0),
+            "trips_a_step": sum(trips) / steps,
+            "ms_a_trip": ms / sum(trips) if sum(trips) else None,
+            "ms_a_step": ms / steps,
+            "in_order": _runs(trips),
+            # the body's longest instructions, ms a trip each
+            "body": {name: ns * 1e-6 / sum(trips) for name, ns in
+                     slot["by_name"].most_common(LONGEST) if sum(trips)}})
     report.sort(key=lambda r: r.pop("first_ns"))
-    return report, {str(rows // row_tile): {"runs_a_step": runs,
-                                           "ms_a_step": ms}
-                    for rows, (runs, ms) in sorted(totals.items())}
+    return report
 
 
 def main():
@@ -184,8 +192,8 @@ def main():
     if instrs is None:
         raise SystemExit("moe_rungs: the traced window left no scope map of "
                          "%r" % STEP_MODULE)
-    filed, direction = rung_of_instruction(instrs)
-    events, steps = [], 0
+    filed, loops, marks = loop_of_instruction(instrs)
+    events, step_starts = [], []
     for plane in (data.planes if data is not None else ()):
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
             continue
@@ -195,18 +203,24 @@ def main():
                            trace_reduce.op_name_and_category(ev.name)[0])
                           for ev in line.events]
             elif line.name == "XLA Modules":
-                steps = sum(1 for ev in line.events
-                            if ev.name.startswith(STEP_MODULE))
+                step_starts = sorted(ev.start_ns for ev in line.events
+                                     if ev.name.startswith(STEP_MODULE))
         break       # the first chip
-    conditionals, rungs = by_rung(events, filed, direction, steps,
-                                  moe.ROW_TILE)
+    report = by_loop(events, step_starts, filed, loops, marks)
+    for loop in report:     # say what each of the longest is
+        loop["body"] = {"%s %s" % (name, "/".join(
+            instrs[name].op_name.split("/")[-2:])): ms
+            for name, ms in loop["body"].items()}
     counters = {k: v for k, v in telemetry.snapshot()["counters"].items()
-                if k.startswith("ops.moe.ladder.")}
+                if k.startswith("ops.moe.chunk.")}
     report = {"workload": opts.workload, "seed": opts.seed,
               "device": devices[0].device_kind, "losses": losses,
-              "window_steps": window["completed"], "traced_steps": steps,
+              "window_steps": window["completed"],
+              "traced_steps": len(step_starts),
               "instructions_filed": len(filed), "counters": counters,
-              "conditionals": conditionals, "rungs": rungs}
+              "loops": report,
+              "rows_ms_a_step": sum(r["ms_a_step"] for r in report),
+              "trips_a_step": sum(r["trips_a_step"] for r in report)}
     out_dir = os.path.join(ROOT, "chiprun_out", "moe_rungs")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "%s_%d.json" % (
