@@ -1,6 +1,7 @@
 """The toy causal language model as a user of `mxnet_tpu` writes it: the
 library's flash attention, causal, inside a functional loss for
-`parallel.ShardedTrainStep`."""
+`parallel.ShardedTrainStep`; its attention block under a scope of its own,
+which `metrics/toy_lm_attention_ms_per_step.json` reads."""
 import jax
 import jax.numpy as jnp
 
@@ -20,11 +21,12 @@ def loss_fn(cfg):
         x = params["word_embed"][tokens] + params["position_embed"][:S]
         for i in range(cfg["n_layers"]):
             layer = params["layers"][str(i)]
-            y = rms(x, layer["attn_norm"])
-            q, k, v = ((y @ layer["attn"]["w" + w]).reshape(B, S, H, -1)
-                       for w in "qkv")
-            o = flash_attention_bshd(q, k, v, causal=True)
-            x = x + o.reshape(B, S, -1) @ layer["attn"]["wo"]
+            with jax.named_scope("toy_lm_attention"):
+                y = rms(x, layer["attn_norm"])
+                q, k, v = ((y @ layer["attn"]["w" + w]).reshape(B, S, H, -1)
+                           for w in "qkv")
+                o = flash_attention_bshd(q, k, v, causal=True)
+                x = x + o.reshape(B, S, -1) @ layer["attn"]["wo"]
             y = rms(x, layer["ffn_norm"])
             x = x + jax.nn.gelu(y @ layer["ffn"]["w1"],
                                 approximate=True) @ layer["ffn"]["w2"]

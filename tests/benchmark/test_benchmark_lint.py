@@ -132,13 +132,14 @@ def test_per_layer_metrics_match_their_files_and_cells(bench):
     end_to_end = {m["name"] for m in bench["end_to_end"]}
     layers = {}
     for m in bench["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                           "layer", "moves"}
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
         assert one_line(m["layer"]) and m["moves"] in end_to_end
-        for w in m.get("workloads", cells):
+        # every metric names its cells, so that a later cell meets none that
+        # it would join unasked
+        assert m["workloads"] and set(m["workloads"]) <= cells, m["name"]
+        for w in m["workloads"]:
             assert m["moves"] in reports[w], (m["name"], w)
-        if "workloads" not in m:    # then every cell that reports `moves`
-            assert all(m["moves"] in reports[w] for w in cells)
         with open(os.path.join(BENCH_DIR, "metrics",
                                m["name"] + ".json")) as f:
             held = json.load(f)
@@ -228,7 +229,7 @@ def test_a_later_pr_adds_a_cell_by_files_alone(tmp_path, bench):
         "source": "device_trace", "layer": "Device", "moves": "tok_per_s",
         "workloads": ["bert_large_s512"]})
     for m in later["per_layer"]:
-        if m["name"] == "mfu.tok":
+        if m["name"] in ("mfu.tok", "compile_s"):
             m["workloads"].append("bert_large_s512")
     (root / "BENCHMARK.json").write_text(json.dumps(later))
 
@@ -252,20 +253,37 @@ TOY = os.path.join(FIXTURES, "toy_lm")
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
+# what a later PR writes into `BENCHMARK.json` for a token cell: the
+# metrics every token cell reports, among them all that the rules of the
+# reader tests give its program (its passes, the kernels it calls); its own
+# block metric comes with its files
+TOY_JOINS = [
+    "tok_per_s", "mfu.tok", "dispatch_ms_per_step.tok",
+    "compiles_in_window.tok", "device_idle_share.tok", "peak_hbm_gb.tok",
+    "step_hbm_gb.tok", "launch_ms_per_step.tok",
+    "dispatch_other_ms_per_step.tok", "setup_trace_s", "setup_lower_s",
+    "setup_xla_s", "compile_s", "programs_compiled",
+    "forward_ms_per_step.tok", "backward_ms_per_step.tok",
+    "optimizer_ms_per_step.tok", "scope_unmapped_share.tok",
+    "flash_fwd_ms_per_step", "flash_dq_ms_per_step", "flash_dkv_ms_per_step"]
+
+
 def copy_with_the_toy(tmp_path, bench):
-    """A copy of the benchmark with what `fixtures/toy_lm/` holds laid into
-    it: a toy causal language model's reference module (with its traffic
-    kind, its FLOPs count and its kernel's work), its program module, its
-    configuration, mix, limits and a roofline metric; and the entries of
-    `BENCHMARK.json`. Returns (root, the digests of the files that were
-    there)."""
+    """A copy of the benchmark and of its tests with what `fixtures/toy_lm/`
+    holds laid into it: a toy causal language model's reference module
+    (with its traffic kind, its FLOPs count and its kernel's work), its
+    program module, its configuration, mix, limits, a roofline metric and a
+    metric of its own scope; and the entries of `BENCHMARK.json`. Returns
+    (root, the digests of the files that were there)."""
     root = tmp_path / "repo"
-    shutil.copytree(BENCH_DIR, root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = _digests(root / "benchmark")
+    for top in ("benchmark", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(ROOT, top), root / top,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(root)
     shutil.copytree(TOY, root / "benchmark", dirs_exist_ok=True,
                     ignore=shutil.ignore_patterns("__pycache__"))
-    assert not set(_digests(TOY)) & set(before)     # files added, none laid over
+    added = {os.path.join("benchmark", k) for k in _digests(TOY)}
+    assert not added & set(before)      # files added, none laid over
     later = json.loads(json.dumps(bench))
     later["configs"].append({
         "name": "toy_lm", "source": "tests/benchmark/fixtures/toy_lm",
@@ -275,16 +293,15 @@ def copy_with_the_toy(tmp_path, bench):
     later["workloads"].append({
         "name": "toy_lm_b8", "config": "toy_lm", "traffic": "causal_b8_s128",
         "chips": 1, "why": "8 sequences of 128 tokens, next-token loss"})
-    with open(os.path.join(TOY, "metrics",
-                           "toy_lm_attention_roofline.json")) as f:
-        roofline = json.load(f)
-    later["per_layer"].append(dict(
-        {k: roofline[k] for k in ("name", "unit", "better", "source",
-                                  "layer", "moves")},
-        workloads=["toy_lm_b8"]))
+    for name in sorted(os.listdir(os.path.join(TOY, "metrics"))):
+        with open(os.path.join(TOY, "metrics", name)) as f:
+            own = json.load(f)
+        later["per_layer"].append(dict(
+            {k: own[k] for k in ("name", "unit", "better", "source",
+                                 "layer", "moves")},
+            workloads=["toy_lm_b8"]))
     for m in later["end_to_end"] + later["per_layer"]:
-        if m["name"] in ("tok_per_s", "mfu.tok", "compiles_in_window.tok",
-                         "launch_ms_per_step.tok"):
+        if m["name"] in TOY_JOINS:
             m["workloads"].append("toy_lm_b8")
     (root / "BENCHMARK.json").write_text(json.dumps(later))
     return root, before
@@ -354,7 +371,29 @@ def test_a_later_pr_adds_an_architecture_by_files_alone(tmp_path, bench):
         100 * least * 1e3 / (1e3 * 0.001 / 10))
     assert "toy_lm_attention: bound by" in got["notes"][0]
     assert "flash_roofline" not in got["names"]
-    after = _digests(root / "benchmark")
+    after = _digests(root)
+    assert {k: after[k] for k in before} == before
+
+
+def test_a_later_architecture_meets_the_cell_rules_by_entries_alone(
+        tmp_path, bench):
+    """The reader tests' checks of which cells list which metric, run in the
+    copy over its `BENCHMARK.json`: the toy's cell is held to what its own
+    program holds (its passes, the block it scopes, the kernels it calls)
+    and passes, with no file that was there edited. The tests hold no list
+    of cells for it to join."""
+    from test_benchmark_span_readers import RULED
+    root, before = copy_with_the_toy(tmp_path, bench)
+    tests = root / "tests" / "benchmark"
+    p = in_the_copy(root, [
+        "-m", "pytest", "-q", "-p", "no:cacheprovider", "-p", "no:randomly",
+        "%s::test_each_metric_is_reported_in_its_cells_and_in_no_other"
+        "[toy_lm_b8]" % (tests / "test_benchmark_scope_readers.py"),
+        "%s::test_each_new_metric_resolves_in_its_cells_alone"
+        % (tests / "test_benchmark_span_readers.py")])
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
+    assert "%d passed" % (1 + len(RULED)) in p.stdout, p.stdout[-1000:]
+    after = _digests(root)
     assert {k: after[k] for k in before} == before
 
 

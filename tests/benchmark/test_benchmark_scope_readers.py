@@ -2,14 +2,16 @@
 scope map of the step the program compiled (`metrics/scope_ms_per_step.py`):
 on made-up times and maps, on the pair recorded on the v5e
 (`tools/record_scoped_fixture.py`), and the cells that report its metrics,
-found through `harness.spec.Cell`."""
+found through `harness.spec.Cell` and held to a rule read from each cell's
+own program (`cell_rules.py`), never to a list of cells."""
 import gzip
 import json
 import os
 
 import pytest
 
-from bench_paths import BENCH_DIR, FIXTURES, ROOT, on_path
+import cell_rules
+from bench_paths import BENCH_DIR, FIXTURES, on_path
 
 on_path()
 from harness import readers  # noqa: E402
@@ -19,29 +21,6 @@ from mxnet_tpu import telemetry  # noqa: E402
 from mxnet_tpu.telemetry import hlo_scopes  # noqa: E402
 
 READER = "scope_ms_per_step.py"
-IMG = ["resnet50_b256", "resnet50_dp4_b1024"]
-BERT = ["bert_base_s128", "bert_base_s512"]
-QWEN, LAGUNA = "qwen3_next_ep16_s4096", "laguna_s_ep32_s4096"
-TOK = BERT + [QWEN, LAGUNA]
-CELLS = {
-    **{n + "_ms_per_step.img": IMG
-       for n in ("forward", "backward", "optimizer")},
-    **{n + "_ms_per_step.tok": TOK
-       for n in ("forward", "backward", "optimizer")},
-    "recompute_ms_per_step.tok": [QWEN, LAGUNA],
-    "scope_unmapped_share.img": IMG,
-    "scope_unmapped_share.tok": TOK,
-    "attention_layer_ms_per_step": TOK,
-    "window_attention_layer_ms_per_step": [LAGUNA],
-    "ffn_ms_per_step": BERT + [LAGUNA],
-    "head_ms_per_step": TOK,
-    "embedding_ms_per_step": BERT,
-    "moe_layer_ms_per_step": [QWEN, LAGUNA],
-    "moe_rows_ms_per_step": [QWEN, LAGUNA],
-    "gdn_layer_ms_per_step": [QWEN],
-    "gdn_chunk_ms_per_step": [QWEN],
-    "gdn_scan_ms_per_step": [QWEN],
-}
 PASSES = ["forward_ms_per_step.tok", "recompute_ms_per_step.tok",
           "backward_ms_per_step.tok", "optimizer_ms_per_step.tok"]
 
@@ -178,25 +157,55 @@ def test_a_program_from_before_the_map_reads_none(monkeypatch):
     assert "notes" not in run       # nothing was lost: nothing to say
 
 
-def test_each_metric_is_reported_in_its_cells_and_in_no_other():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    listed = {m["name"]: m["workloads"] for m in bench["per_layer"]
-              if m["name"] in CELLS}
-    assert listed == CELLS
-    for w in bench["workloads"]:
-        cell = Cell(w["name"])
-        mine = {m["name"] for m in cell.per_layer
-                if m.get("reader_file") == READER}
-        assert mine == {n for n, cells in CELLS.items()
-                        if w["name"] in cells}, w["name"]
-        for m in cell.per_layer:
-            if m.get("reader_file") == READER:
-                assert m["source"] == "device_trace" and m["moves"] in (
-                    "img_per_s", "tok_per_s")
-                assert set(m["params"]) <= {"pass", "scopes",
-                                            "share_unmapped"}
-                assert m["params"].get("pass", "forward") in hlo_scopes.PASSES
+def by_rule(cell, monkeypatch):
+    """The metrics of this reader that `cell` reports by the rule: those
+    that move an end-to-end metric it reports and that read a number over
+    its step's own scope map with every instruction timed. A pass is there
+    where some instruction lies in it (`recomputed` only where the step
+    recomputes a layer), a block where some instruction lies under one of
+    the metric's scopes, and `scope_unmapped_share` of its kind always."""
+    name, held = cell_rules.scope_map(cell)
+    monkeypatch.setattr(telemetry, "module_scopes", lambda: {name: held})
+    run = cell_rules.every_operation_timed(
+        ["op/" + instr for instr in held], name)
+    return cell_rules.read_by_rule(cell, READER, run)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  cell_rules.benchmark()["workloads"]])
+def test_each_metric_is_reported_in_its_cells_and_in_no_other(cell,
+                                                              monkeypatch):
+    assert cell_rules.listed(cell, READER) == by_rule(cell, monkeypatch)
+    for m in Cell(cell).per_layer:
+        if m.get("reader_file") == READER:
+            assert m["source"] == "device_trace" and m["moves"] in (
+                "img_per_s", "tok_per_s")
+            assert set(m["params"]) <= {"pass", "scopes", "share_unmapped"}
+            assert m["params"].get("pass", "forward") in hlo_scopes.PASSES
+
+
+def test_the_rule_finds_the_cells_that_are_there(monkeypatch):
+    """What the six cells listed before the rule was read from their
+    programs, which the rule gives them again."""
+    passes = {"forward", "backward", "optimizer"}
+    img = {p + "_ms_per_step.img" for p in passes} | {
+        "scope_unmapped_share.img"}
+    tok = {p + "_ms_per_step.tok" for p in passes} | {
+        "scope_unmapped_share.tok", "attention_layer_ms_per_step",
+        "head_ms_per_step"}
+    bert = tok | {"ffn_ms_per_step", "embedding_ms_per_step"}
+    experts = tok | {"recompute_ms_per_step.tok", "moe_layer_ms_per_step",
+                     "moe_rows_ms_per_step"}
+    expected = {
+        "resnet50_b256": img, "resnet50_dp4_b1024": img,
+        "bert_base_s128": bert, "bert_base_s512": bert,
+        "qwen3_next_ep16_s4096": experts | {
+            "gdn_layer_ms_per_step", "gdn_chunk_ms_per_step",
+            "gdn_scan_ms_per_step"},
+        "laguna_s_ep32_s4096": experts | {
+            "window_attention_layer_ms_per_step", "ffn_ms_per_step"}}
+    for cell, names in expected.items():
+        assert by_rule(cell, monkeypatch) == names, cell
 
 
 # ------------------------------------------------- the pair recorded on a v5e

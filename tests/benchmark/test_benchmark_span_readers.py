@@ -1,13 +1,17 @@
 """The readers of the program's own spans and of its named kernels, on
-made-up runs; the metrics that use them, found through `harness.spec.Cell`;
-and one rehearsal that prints them."""
+made-up runs; the metrics that use them, found through `harness.spec.Cell`
+and held to a rule read from `BENCHMARK.json`, the configurations and each
+cell's own program (`cell_rules.py`), never to a list of cells; and one
+rehearsal that prints them."""
+import functools
 import gzip
 import json
 import os
 
 import pytest
 
-from bench_paths import BENCH_DIR, FIXTURES, ROOT, on_path
+import cell_rules
+from bench_paths import BENCH_DIR, FIXTURES, on_path
 from test_benchmark_run import REHEARSAL, run as run_benchmark
 
 on_path()
@@ -23,37 +27,37 @@ TOK_PHASES = ["launch_ms_per_step.tok", "dispatch_other_ms_per_step.tok"]
 KERNELS = ["flash_fwd_ms_per_step", "flash_dq_ms_per_step",
            "flash_dkv_ms_per_step"]
 SETUP = ["setup_trace_s", "setup_lower_s", "setup_xla_s"]
+SETUP_COUNTS = ["compile_s", "programs_compiled"]
+NAMED = "named_op_ms_per_step.py"
+BENCH = cell_rules.benchmark()
+# every metric of the reader of named kernels: a later one is held too
+NAMED_OPS = [m["name"] for m in BENCH["per_layer"]
+             if cell_rules.metric_file(m["name"]).get("reader_file") == NAMED]
+RULED = IMG_PHASES + TOK_PHASES + SETUP + SETUP_COUNTS + NAMED_OPS
 
 
-def cells_by_rule():
-    """{cell: the metrics above that it reports}, from `BENCHMARK.json` and
-    the configurations' files and from no list of names, so that a later
-    cell needs no edit here. A cell that reports `img_per_s` reports the
-    `.img` phases, `stage` on a mesh only; one that reports `tok_per_s` the
-    `.tok` phases, and the three kernels where its configuration is BERT's
-    program; every cell the three set-up spans."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    rates = {w: m["name"] for m in bench["end_to_end"]
-             if m["name"] in ("img_per_s", "tok_per_s")
-             for w in m["workloads"]}
-    files = {c["name"]: c["file"] for c in bench["configs"]}
-    out = {}
-    for w in bench["workloads"]:
-        with open(os.path.join(ROOT, files[w["config"]])) as f:
-            cfg = json.load(f)
-        names = list(SETUP)
-        if rates.get(w["name"]) == "img_per_s":
-            names += [m for m in IMG_PHASES
-                      if "stage" not in m or w["chips"] > 1]
-        if rates.get(w["name"]) == "tok_per_s":
-            names += TOK_PHASES
-            names += KERNELS if cfg.get("program") == "bert" else []
-        out[w["name"]] = names
-    return out
-
-
-CELLS = cells_by_rule()
+@functools.lru_cache(maxsize=None)
+def by_rule(cell):
+    """The metrics above that `cell` reports: every cell the set-up's
+    spans and counts; one that reports `img_per_s` the `.img` phases,
+    `stage` on a mesh only; one that reports `tok_per_s` the `.tok` phases;
+    and a metric of named kernels where the cell reports what it moves and
+    its step, lowered for the TPU at the cell's sizes, calls a kernel of
+    that name."""
+    spec = Cell(cell)
+    reported = {m["name"] for m in spec.end_to_end}
+    names = set(SETUP + SETUP_COUNTS)
+    if "img_per_s" in reported:
+        names |= {m for m in IMG_PHASES
+                  if "stage" not in m or spec.chips > 1}
+    if "tok_per_s" in reported:
+        names |= set(TOK_PHASES)
+    if any(cell_rules.metric_file(m)["moves"] in reported
+           for m in NAMED_OPS):
+        run = cell_rules.every_operation_timed(
+            "mosaic/%s.0" % k for k in cell_rules.kernels(cell))
+        names |= cell_rules.read_by_rule(cell, NAMED, run)
+    return names
 
 
 def metric(name, **params):
@@ -200,29 +204,37 @@ def test_the_trace_recorded_with_named_kernels():
         assert inside.count("train_step.launch") == 1
 
 
-@pytest.mark.parametrize("name", IMG_PHASES + TOK_PHASES + KERNELS + SETUP)
+@pytest.mark.parametrize("name", RULED)
 def test_each_new_metric_resolves_in_its_cells_alone(name):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"]
-                    if m["name"] == name]
+    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
+    cells = [w["name"] for w in BENCH["workloads"]]
     assert sorted(entry["workloads"]) == sorted(
-        cell for cell, names in CELLS.items() if name in names)
-    for cell in CELLS:
+        cell for cell in cells if name in by_rule(cell))
+    for cell in cells:
         held = [m for m in Cell(cell).per_layer if m["name"] == name]
-        assert len(held) == (name in CELLS[cell])
+        assert len(held) == (name in by_rule(cell))
         for m in held:
-            assert os.path.exists(os.path.join(BENCH_DIR, "metrics",
-                                               m["reader_file"]))
+            if "reader_file" in m:
+                assert os.path.exists(os.path.join(BENCH_DIR, "metrics",
+                                                   m["reader_file"]))
+            else:
+                assert m["reader"] in readers.READERS
             assert {k: m[k] for k in entry if k != "workloads"} == {
                 k: v for k, v in entry.items() if k != "workloads"}
 
 
 def test_the_rule_finds_the_cells_that_are_there():
-    assert set(CELLS["resnet50_b256"]) == set(
-        m for m in IMG_PHASES if "stage" not in m) | set(SETUP)
-    assert set(CELLS["resnet50_dp4_b1024"]) == set(IMG_PHASES + SETUP)
-    assert set(CELLS["bert_base_s128"]) == set(TOK_PHASES + KERNELS + SETUP)
-    assert CELLS["bert_base_s512"] == CELLS["bert_base_s128"]
+    everywhere = set(SETUP + SETUP_COUNTS)
+    assert by_rule("resnet50_b256") == everywhere | set(
+        m for m in IMG_PHASES if "stage" not in m)
+    assert by_rule("resnet50_dp4_b1024") == everywhere | set(IMG_PHASES)
+    assert by_rule("bert_base_s128") == everywhere | set(TOK_PHASES + KERNELS)
+    assert by_rule("bert_base_s512") == by_rule("bert_base_s128")
+    # the decoders' causal calls are the same three kernels; their windowed
+    # calls are `swa_*`, which `flash_*` does not match
+    decoder = everywhere | set(TOK_PHASES + KERNELS) | {"moe_gmm_ms_per_step"}
+    assert by_rule("qwen3_next_ep16_s4096") == decoder | {"gdn_ms_per_step"}
+    assert by_rule("laguna_s_ep32_s4096") == decoder | {"swa_ms_per_step"}
 
 
 def test_a_traced_rehearsal_prints_the_phases_and_the_set_up_times():
